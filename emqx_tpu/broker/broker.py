@@ -582,7 +582,7 @@ class Broker:
         """Capped outbox append — the single fallback path for deliveries
         with no live connection.  Overflow evicts oldest-first, counted
         in ``broker.outbox.dropped`` and logged once per client (a silent
-        drop here cost a round of debugging — VERDICT lineage)."""
+        drop here cost a round of debugging)."""
         box = self.outbox.setdefault(clientid, [])
         box.extend(pubs)
         over = len(box) - self.OUTBOX_MAX

@@ -17,7 +17,7 @@ Both are refcounted: inserting the same key twice needs two deletes before
 edges disappear (mirrors emqx_trie's edge counting so concurrent routes
 sharing prefixes survive unrelated deletes).
 
-These are also the **CPU baseline** for BASELINE.md's denominator: match
+These are also the **CPU baseline**, the benchmark's denominator: match
 throughput here is what the TPU kernel is judged against.
 """
 

@@ -401,13 +401,13 @@ SCHEMA: Dict[str, Field] = {
     "tpu.batch_size": Field(2048, int, lambda v: v >= 1),
     "tpu.batch_deadline": Field(0.0002, duration),
     "tpu.active_slots": Field(16, int),
-    # 128 keeps the 10M fan-out tail on device (round-5 measurement in
-    # BASELINE.md: 32 spilled 11-12% of topics to host re-runs)
+    # 128 keeps the 10M fan-out tail on device (32 spilled 11-12% of
+    # topics to host re-runs on the depth-8 Zipf workload)
     "tpu.max_matches": Field(128, int),
     "tpu.mirror_refresh_interval": Field(0.05, duration),
-    # bound on device bring-up (first XLA compile is ~20-40s; a WEDGED
-    # device tunnel would otherwise hang node start forever — on timeout
-    # the node serves from the host trie)
+    # bound on device bring-up (mirror upload + the first XLA compiles;
+    # a device that never answers would otherwise hang node start
+    # forever — on timeout the node serves from the host trie)
     "tpu.start_timeout": Field(180.0, duration),
     # host-table implementation behind the device mirror: the C++
     # incremental NFA scales to 10M filters; python is the debug twin
@@ -582,9 +582,10 @@ SCHEMA: Dict[str, Field] = {
     # pre-compile the next pow2 table shapes in the background before
     # growth reaches them (the resize then serves from the cache)
     "match.segments.prewarm": Field(True, _bool),
-    # persistent XLA compilation cache under "<segments dir>/xla_cache"
-    # (effective only with match.segments.enable): even the FIRST
-    # cold-start compile after a process restart is a disk hit
+    # persistent XLA compilation cache on every tpu.enable start (with
+    # or without segments), where JAX_COMPILATION_CACHE_DIR says or at
+    # "<repo root>/.jax_cache": even the FIRST cold-start compile after
+    # a process restart is a disk hit
     "match.segments.xla_cache": Field(True, _bool),
 }
 

@@ -3,7 +3,7 @@
 //
 // Round-1 profiling showed the per-word Python dict loop in
 // emqx_tpu/ops/compiler.py::encode_topics consuming ~82% of the
-// per-batch serving budget (VERDICT.md weak item 3).  The reference's
+// per-batch serving budget.  The reference's
 // equivalent work — emqx_topic:words/1 binary splitting [U] — is
 // BEAM-native; ours is this translation unit, loaded via ctypes
 // (pybind11 is not in the image).

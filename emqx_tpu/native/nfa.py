@@ -267,7 +267,7 @@ class NativeNfa:
         return (int(s[0]), int(s[1]), self.depth)
 
     def memory_bytes(self) -> Dict[str, int]:
-        """Device-array footprint (the HBM math for BASELINE.md)."""
+        """Device-array footprint (the HBM math)."""
         s = self._sizes()
         return {
             "node_tab": int(s[0]) * 4 * 4,

@@ -35,17 +35,28 @@ from .transport.listener import Listener, Listeners
 log = logging.getLogger(__name__)
 
 
-def enable_xla_cache(path: str) -> bool:
-    """Point JAX's persistent compilation cache at ``path`` (under the
-    segments dir): a process restart finds every previously-compiled
-    serve executable on disk, so even the FIRST cold-start compile is
-    a cache hit instead of an XLA run.  Returns True when the cache is
-    active; best-effort — a jax without the knobs (or no jax at all)
+#: where the persistent compilation cache lives when nothing outside
+#: places it: one fixed path inside the checkout (the path is part of
+#: the cache key's lookup — a directory that moves never hits)
+XLA_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
+def enable_xla_cache() -> bool:
+    """Turn JAX's persistent compilation cache on: a process restart
+    finds every previously-compiled serve executable on disk, so even
+    the FIRST cold-start compile is a cache hit instead of an XLA run.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set the cache is placed from
+    outside — JAX reads the variable itself and this sets NO directory;
+    otherwise it is :data:`XLA_CACHE_DIR`.  Returns True when the cache
+    is active; best-effort — a jax without the knobs (or no jax at all)
     degrades to in-memory compiles, never a startup failure."""
     try:
         import jax
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            os.makedirs(XLA_CACHE_DIR, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", XLA_CACHE_DIR)
     except Exception:
         log.exception("persistent XLA compilation cache unavailable; "
                       "cold-start compiles stay in-memory")
@@ -1036,12 +1047,12 @@ class BrokerNode:
         from .broker.match_service import MatchService
 
         cfg = self.config
+        if cfg.get("match.segments.xla_cache"):
+            enable_xla_cache()
         seg_dir = ""
         if cfg.get("match.segments.enable"):
             seg_dir = cfg.get("match.segments.dir") or os.path.join(
                 cfg.get("node.data_dir") or "data", "segments")
-            if cfg.get("match.segments.xla_cache"):
-                enable_xla_cache(os.path.join(seg_dir, "xla_cache"))
         try:
             self.match_service = MatchService(
                 self.broker,

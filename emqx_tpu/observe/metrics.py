@@ -93,6 +93,9 @@ TPU_METRIC_NAMES: List[str] = [
     "tpu.mirror.delta_applied", "tpu.mirror.recompile",
     "tpu.match.hint_served", "tpu.match.hint_stale", "tpu.match.bypass",
     "tpu.match.hint_evicted",
+    # waiters whose prefetch outlasted tpu.prefetch_timeout (a compile
+    # or a stalled device): those publishes walked the host trie
+    "tpu.match.prefetch_timeout",
 ]
 
 # -- batched fanout pipeline (broker/fanout.py) + broker drop accounting.

@@ -5,9 +5,9 @@
 supports only ``take_along_axis``-shaped 2D gathers (input/indices/
 output the same shape), so arbitrary table lookups — the heart of the
 walk — cannot lower (``ValueError: Shape mismatch in input, indices and
-output``, recorded in BASELINE.md).  Rather than fight the gather unit,
-this module removes gathers entirely: for a small table the trie walk
-IS dense linear algebra, and the MXU is the fastest unit on the chip.
+output``, pinned by tests/test_chip_compile.py).  Rather than fight
+the gather unit, this module removes gathers entirely: for a small
+table the trie walk IS dense linear algebra, and the MXU is the fastest unit on the chip.
 
 **The reformulation.**  Active-state sets become multi-hot rows
 ``active (B, S)`` instead of id lists, and one step of the walk is:
@@ -59,7 +59,8 @@ __all__ = ["DenseTable", "build_dense", "dense_match", "supports_dense",
 # efficiency).  Either engine on a small hot table beats the monolithic
 # 150k-filter table's gather walk ~4x/topic (8.2 → 1.9-2.4 µs) — the
 # tier win is mostly table smallness; dense adds exactness (no spill)
-# and the extra 1.4-1.8x under this cap.  See BASELINE.md.
+# and the extra 1.4-1.8x under this cap (a remote attach that is gone;
+# not measured on the attached chip yet).
 DENSE_STATE_CAP = 512
 _LABEL_NONE = -7            # never equals a word id (those are >= 0)
 

@@ -305,7 +305,7 @@ class DeviceNfa:
         new S (no h2d traffic for the surviving prefix), swap in the
         rehashed edge table when it moved, then scatter the tracked
         dirty rows — replacing the whole-table ``device_put`` the old
-        resize path paid (25–107 s at 10M filters, BENCH_r03/r05)."""
+        resize path paid (25 s at 10M filters, BENCH_r05)."""
         node, edge, seeds = self._arrs
         target_s, target_hb, _d = p.shape_key
         if int(node.shape[0]) != p.delta.node_grown_from:

@@ -2,11 +2,10 @@
 
 Behavioral reference: ``emqx_trie:insert/1`` / ``delete/1`` [U]
 (SURVEY.md §2.1) are O(filter); the round-1 ``compile_filters`` was
-O(table) per change — this module closes that gap (VERDICT.md next-round
-item 1).  The design follows the mria bootstrap-then-replay-rlog pattern
-(SURVEY.md §5.4): the host arrays here are the authoritative mirror, the
-device twin (:class:`~emqx_tpu.ops.device_table.DeviceNfa`) consumes
-bounded deltas.
+O(table) per change — this module closes that gap.  The design follows
+the mria bootstrap-then-replay-rlog pattern (SURVEY.md §5.4): the host
+arrays here are the authoritative mirror, the device twin
+(:class:`~emqx_tpu.ops.device_table.DeviceNfa`) consumes bounded deltas.
 
 Layout is byte-identical to :class:`~emqx_tpu.ops.compiler.NfaTable`
 (same node_tab / cuckoo edge_tab / seeds contract, same kernel), plus:

@@ -4,8 +4,9 @@ The match kernel (:func:`~emqx_tpu.ops.match_kernel.nfa_match`) compiles
 one executable per ``(B, D, S, Hb, A, K, flat_cap, compact)`` bucket;
 table shapes are padded to powers of two exactly so growth RARELY
 changes them — but when growth does cross a pow2 boundary, the next
-dispatch stalls 9–19 s on an XLA compile at 10M filters (BENCH_r03/r05)
-and the serve plane browns out to the host path for the whole window.
+dispatch stalls for seconds on an XLA compile (3–11 s per shape at a
+1M-filter table for the v5e compiler, tests/test_chip_compile.py) and
+the serve plane browns out to the host path for the whole window.
 
 This cache closes that window two ways:
 
@@ -235,47 +236,57 @@ class MatchKernelCache:
                 self._done.notify_all()
 
     def _lower(self, k: Key):
+        if k[10] is not None:
+            if self.mesh_lower is None:
+                raise RuntimeError(
+                    "mesh-keyed compile requested but no mesh_lower "
+                    "hook is installed")
+            return self.mesh_lower(k)
+        fn, args, static = self.lowering(k)
+        return fn.lower(*args, **static).compile()
+
+    @staticmethod
+    def lowering(k: Key, sharding: Any = None):
+        """``(jitted fn, operand ShapeDtypeStructs, static kwargs)`` of
+        the single-device executable ``k`` names — what :meth:`_lower`
+        compiles.  ``sharding`` places every operand (the chip compile
+        rehearsal in tests/test_chip_compile.py passes a described
+        device, so it compiles exactly the served program)."""
         import jax
         import jax.numpy as jnp
 
         from .compiler import BUCKET_SLOTS
         from .match_kernel import nfa_match, nfa_match_donated
 
-        b, d, s, hb, a, m, compact, flat_cap, donate, backend, mesh = k
-        if mesh is not None:
-            if self.mesh_lower is None:
-                raise RuntimeError(
-                    "mesh-keyed compile requested but no mesh_lower "
-                    "hook is installed")
-            return self.mesh_lower(k)
+        b, d, s, hb, a, m, compact, flat_cap, donate, backend, _mesh = k
         i32 = jnp.int32
-        sd = jax.ShapeDtypeStruct
+
+        def sd(shape, dtype=i32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
         batch = (
-            sd((b, d), i32),                      # words
-            sd((b,), i32),                        # lens
+            sd((b, d)),                           # words
+            sd((b,)),                             # lens
             sd((b,), jnp.bool_),                  # is_sys
-            sd((s, 4), i32),                      # node_tab
+            sd((s, 4)),                           # node_tab
         )
-        if backend == "join":
-            from .join_match import (
-                OVERLAY_CAP, join_match, join_match_donated,
-                relation_capacity,
-            )
+        static = dict(active_slots=a, max_matches=m,
+                      compact_output=compact, flat_cap=flat_cap)
+        if backend in ("join", "join-pallas"):
+            from .join_match import OVERLAY_CAP, relation_capacity
 
             e_cap = relation_capacity(hb)
-            fn = join_match_donated if donate else join_match
-            lowered = fn.lower(
-                *batch,
-                sd((s + 1,), i32),                # state_start
-                sd((e_cap,), i32),                # edge_word
-                sd((e_cap,), i32),                # edge_next
-                sd((OVERLAY_CAP, 3), i32),        # overlay
-                active_slots=a, max_matches=m,
-                compact_output=compact, flat_cap=flat_cap,
+            relation = (
+                sd((s + 1,)),                     # state_start
+                sd((e_cap,)),                     # edge_word
+                sd((e_cap,)),                     # edge_next
+                sd((OVERLAY_CAP, 3)),             # overlay
             )
-            return lowered.compile()
-        if backend == "join-pallas":
-            from .join_match import OVERLAY_CAP, relation_capacity
+            if backend == "join":
+                from .join_match import join_match, join_match_donated
+
+                fn = join_match_donated if donate else join_match
+                return fn, batch + relation, static
             from .pallas_match import (
                 pallas_join_match_flat, pallas_join_match_flat_donated,
             )
@@ -284,29 +295,17 @@ class MatchKernelCache:
                 raise ValueError(
                     "join-pallas backend is flat-output only "
                     "(flat_cap > 0 required)")
-            e_cap = relation_capacity(hb)
             fn = (pallas_join_match_flat_donated if donate
                   else pallas_join_match_flat)
-            lowered = fn.lower(
-                *batch,
-                sd((s + 1,), i32),                # state_start
-                sd((e_cap,), i32),                # edge_word
-                sd((e_cap,), i32),                # edge_next
-                sd((OVERLAY_CAP, 3), i32),        # overlay
-                depth=d, active_slots=a, max_matches=m,
-                flat_cap=flat_cap,
-                interpret=(jax.default_backend() != "tpu"),
-            )
-            return lowered.compile()
+            del static["compact_output"]
+            static.update(depth=d,
+                          interpret=(jax.default_backend() != "tpu"))
+            return fn, batch + relation, static
         fn = nfa_match_donated if donate else nfa_match
-        lowered = fn.lower(
-            *batch,
-            sd((hb, BUCKET_SLOTS * 4), i32),      # edge_tab
-            sd((2,), i32),                        # seeds
-            active_slots=a, max_matches=m,
-            compact_output=compact, flat_cap=flat_cap,
-        )
-        return lowered.compile()
+        return fn, batch + (
+            sd((hb, BUCKET_SLOTS * 4)),           # edge_tab
+            sd((2,)),                             # seeds
+        ), static
 
     def info(self) -> dict:
         with self._lock:

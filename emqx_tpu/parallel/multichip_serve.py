@@ -40,7 +40,7 @@ root token hashes to it, so 8 chips hold 8× the filters:
 
 Shard subtables are **native** (``native/nfa.cpp``) when the toolchain
 built the .so — per-shard capacity then matches the single-chip native
-table (10M filters, BENCH_r03/r05), putting ``tp × 10M`` within one
+table (10M filters, BENCH_r05), putting ``tp × 10M`` within one
 mesh.  Every subtable (and the micro-table) interns the SAME word
 sequence, so all vocabs stay identical to the shared encode vocab by
 construction (ids assign append-only).  The Python ``IncrementalNfa``
@@ -117,11 +117,11 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import faultinject as _fi
 from .. import topic as T
-from ._shard_compat import shard_map
 from .sharded_match import CompactFanoutResult, decode_compact_rows
 
 log = logging.getLogger(__name__)
@@ -831,23 +831,40 @@ class MultichipMatcher:
                     edge_stk = _scatter_stacked(
                         edge_stk, jnp.full(idx.shape, t, jnp.int32),
                         jnp.asarray(idx), jnp.asarray(rows))
-            aid_stk = jnp.asarray(self._stacked_aid_maps(shape[2]))
+            # (the donated scatters alias their operand, so node_stk /
+            # edge_stk keep the placement _restack gave them)
+            aid_stk = self._put_shards(self._stacked_aid_maps(shape[2]))
             if not mdelta.empty:
                 # the micro-table is small and replicated: a dirty
                 # micro ships as a full (fresh-array) upload
                 mn, me, ms = self._table_arrays(self._micro)
-                micro_node = jnp.asarray(mn)
-                micro_edge = jnp.asarray(me)
-                micro_seeds = jnp.asarray(ms)
+                micro_node = self._put_replicated(mn)
+                micro_edge = self._put_replicated(me)
+                micro_seeds = self._put_replicated(ms)
             if not mdelta.empty or wo_changed:
-                micro_amap = jnp.asarray(
+                micro_amap = self._put_replicated(
                     self._padded_micro_amap(shape[5]))
-                word_owner = jnp.asarray(self._word_owner)
+                word_owner = self._put_replicated(self._word_owner)
             self._arrs = (node_stk, edge_stk, seeds_stk, aid_stk,
                           micro_node, micro_edge, micro_seeds,
                           micro_amap, word_owner)
         self.applies += 1
         return True
+
+    def _put_shards(self, stacked):
+        """Upload a stacked ``(tp, ...)`` host array with shard ``t``
+        on the mesh's ``tp``-th device column — the layout the step's
+        ``in_specs`` name.  A bare ``jnp.asarray`` lands the whole
+        stack on device 0 and leaves every dispatch to re-shard it
+        (no error anywhere; chip_smoke.py --chips 4 asserts four
+        holders)."""
+        spec = P("tp", *([None] * (np.ndim(stacked) - 1)))
+        return jax.device_put(stacked, NamedSharding(self.mesh, spec))
+
+    def _put_replicated(self, arr):
+        """Upload a small table every mesh device reads (micro-table,
+        ``word_owner``): one copy per device, placed once."""
+        return jax.device_put(arr, NamedSharding(self.mesh, P()))
 
     @staticmethod
     def _table_shape(sub) -> Tuple[int, int]:
@@ -920,15 +937,16 @@ class MultichipMatcher:
             nodes.append(tab)
             edges.append(edge)
             seeds.append(sd)
-        node_stk = jnp.asarray(np.stack(nodes))
-        edge_stk = jnp.asarray(np.stack(edges))
-        seeds_stk = jnp.asarray(np.stack(seeds))
-        aid_stk = jnp.asarray(self._stacked_aid_maps(acap))
+        node_stk = self._put_shards(np.stack(nodes))
+        edge_stk = self._put_shards(np.stack(edges))
+        seeds_stk = self._put_shards(np.stack(seeds))
+        aid_stk = self._put_shards(self._stacked_aid_maps(acap))
         mn, me, ms = self._table_arrays(self._micro)
         arrs = (node_stk, edge_stk, seeds_stk, aid_stk,
-                jnp.asarray(mn), jnp.asarray(me), jnp.asarray(ms),
-                jnp.asarray(self._padded_micro_amap(am)),
-                jnp.asarray(self._word_owner))
+                self._put_replicated(mn), self._put_replicated(me),
+                self._put_replicated(ms),
+                self._put_replicated(self._padded_micro_amap(am)),
+                self._put_replicated(self._word_owner))
         with self._lock:
             self._arrs = arrs
             self._stacked_shape = shape

@@ -49,8 +49,8 @@ from typing import Dict, List, NamedTuple, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ._shard_compat import shard_map
 
 from .. import topic as T
 from ..ops.incremental import IncrementalNfa

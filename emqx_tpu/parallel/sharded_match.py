@@ -26,8 +26,8 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ._shard_compat import shard_map
 
 from ..ops.compiler import NfaTable
 from ..ops.match_kernel import nfa_match

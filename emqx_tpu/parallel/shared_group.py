@@ -26,8 +26,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ._shard_compat import shard_map
 
 __all__ = ["build_shared_selector", "make_group_masks", "host_pick"]
 

@@ -38,8 +38,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ._shard_compat import shard_map
 
 from ..ops.match_kernel import nfa_match
 from .sharded_match import or_accept_rows

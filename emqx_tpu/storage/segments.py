@@ -1,6 +1,6 @@
 """Persistent compacted table segments — cold start without the rebuild.
 
-At 10M filters the match table costs 64 s to build (BENCH_r03/r05); the
+At 10M filters the match table costs 64 s to build (BENCH_r05); the
 broker should instead cold-start from a compacted on-disk segment in
 seconds and replay only the delta tail against the live router — the
 mria "bootstrap from a checkpoint, then replay the rlog" pattern

@@ -129,7 +129,7 @@ class Table:
         self._wal.write(json.dumps(rec, separators=(",", ":")) + "\n")
         self._wal.flush()
         # durability: fsync per append (default), or rate-limited with a
-        # bounded loss window (VERDICT.md round-2 weak item 6)
+        # bounded loss window
         if self.fsync_interval_s <= 0:
             os.fsync(self._wal.fileno())
         else:

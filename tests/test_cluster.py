@@ -347,7 +347,7 @@ def test_config_sync_survives_origin_restart():
 
 
 def test_retained_replicates_and_survives_node_loss():
-    """VERDICT r4 item 5 (retained half): a retained message stored on
+    """Retained half: a retained message stored on
     node A is replicated into B's OWN retainer (emqx_retainer_mnesia
     replicated-table semantics) and still serves subscribe-replay on B
     after A dies."""
@@ -410,7 +410,7 @@ def test_retained_delete_propagates_tombstone():
 
 
 def test_durable_session_promoted_after_node_loss():
-    """VERDICT r4 item 5 (session half): a persistent session created on
+    """Session half: a persistent session created on
     A — subscriptions and queued QoS1 messages — is promoted from B's
     replica when A dies and the client reconnects to B."""
 
@@ -548,7 +548,7 @@ def test_replica_promotion_survives_full_restart(tmp_path):
 
 
 def test_reuseport_shared_port_across_cluster_nodes():
-    """SO_REUSEPORT connection-plane scale-out (VERDICT r4 item 3): two
+    """SO_REUSEPORT connection-plane scale-out: two
     clustered broker nodes bind the SAME MQTT port; the kernel spreads
     accepted connections across them and cross-node routing makes
     placement transparent to clients."""

@@ -1,6 +1,6 @@
 """DTLS 1.2 PSK transport: sans-IO handshake/record tests plus the
 endpoint's stateless-cookie and sweep behavior (the esockd-dtls analog
-for the UDP gateways, VERDICT r4 item 7)."""
+for the UDP gateways)."""
 
 import pytest
 

@@ -1,6 +1,6 @@
 """Native topic encoder ≡ pure-Python fallback, byte for byte.
 
-The encoder is the serving-path front (VERDICT.md weak item 3); parity
+The encoder is the serving-path front; parity
 here is what lets the native path replace the Python loop safely.
 """
 

@@ -613,7 +613,7 @@ def test_broker_feeds_sidecar_mirror_end_to_end():
 def test_sidecar_overflow_fails_open_to_host_trie():
     """Force active-set overflow (A=2, heavy '+' fan-in) and match-count
     overflow (K=4): spilled rows must be re-run on the host trie so the
-    combined answer is exactly the oracle's (VERDICT.md weak item 1)."""
+    combined answer is exactly the oracle's."""
 
     async def main():
         server, sidecar, port = await start_sidecar(
@@ -656,7 +656,7 @@ def test_sidecar_overflow_fails_open_to_host_trie():
 
 def test_sidecar_incremental_no_reupload_under_churn():
     """Steady-state filter churn must ride the delta path: no device
-    re-uploads, no table rebuilds (VERDICT.md round-1 item 1)."""
+    re-uploads, no table rebuilds."""
 
     async def main():
         server, sidecar, port = await start_sidecar(rebuild_debounce_s=0.005)

@@ -430,23 +430,52 @@ def test_flag_off_structures_inert():
     assert ms._table_gen == 0 and ms._mut_count == 0
 
 
-def test_xla_cache_dir_configured_under_segments_dir(tmp_path,
-                                                    monkeypatch):
-    """match.segments.xla_cache (ROADMAP table-lifecycle leftover (d)):
-    the persistent XLA compilation cache lands under the segments dir
-    so even the FIRST cold-start compile is a disk hit."""
+@pytest.mark.parametrize("env_dir,cwd", [
+    ("/some/dir", None),     # placed from outside: code sets NO directory
+    (None, None),            # unset: the fixed path inside the checkout
+    (None, "elsewhere"),     # ... from a different cwd too
+])
+def test_xla_cache_placed_by_env_else_fixed_path(tmp_path, monkeypatch,
+                                                 env_dir, cwd):
+    """enable_xla_cache(): JAX_COMPILATION_CACHE_DIR set -> JAX reads
+    it and no code path updates jax_compilation_cache_dir; unset -> one
+    fixed <repo root>/.jax_cache whatever the cwd (a cache directory
+    that moves never hits)."""
     import jax
 
-    from emqx_tpu.node import enable_xla_cache
+    import emqx_tpu.node as node_mod
 
+    repo_root = os.path.dirname(os.path.dirname(
+        os.path.abspath(node_mod.__file__)))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    if cwd is not None:
+        os.makedirs(os.path.join(str(tmp_path), cwd))
+        monkeypatch.chdir(os.path.join(str(tmp_path), cwd))
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda k, v: (updates.append((k, v)), real_update(k, v))[1])
     prev = jax.config.jax_compilation_cache_dir
     try:
-        path = os.path.join(str(tmp_path), "segments", "xla_cache")
-        assert enable_xla_cache(path)
-        assert jax.config.jax_compilation_cache_dir == path
-        assert os.path.isdir(path)
+        assert node_mod.enable_xla_cache()
+        dirs = [v for k, v in updates if k == "jax_compilation_cache_dir"]
+        if env_dir is not None:
+            assert dirs == []
+            assert jax.config.jax_compilation_cache_dir == prev
+        else:
+            want = os.path.join(repo_root, ".jax_cache")
+            assert dirs == [want]
+            assert jax.config.jax_compilation_cache_dir == want
+            assert os.path.isdir(want)
+        # the two min-size/min-time knobs apply either way
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+        assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
     finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
+        real_update("jax_compilation_cache_dir", prev)
 
 
 def test_xla_cache_config_key_registered():
@@ -456,45 +485,44 @@ def test_xla_cache_config_key_registered():
     assert field.default is True
 
 
-def test_node_wires_xla_cache_only_with_segments_enabled(monkeypatch):
-    """The node start path calls enable_xla_cache iff segments AND the
-    xla_cache key are on, rooted under the segments dir."""
+@pytest.mark.parametrize("overrides,called", [
+    # tpu.enable off: nothing runs (the early return)
+    ({"tpu.enable": False, "match.segments.enable": True}, False),
+    # every tpu.enable start turns the cache on, segments or not
+    # (the key is spelled out: tests/conftest.py's env layer turns its
+    # default-true off for the suite)
+    ({"tpu.enable": True, "match.segments.enable": False,
+      "match.segments.xla_cache": True}, True),
+    ({"tpu.enable": True, "match.segments.enable": True,
+      "match.segments.xla_cache": True,
+      "match.segments.dir": "/tmp/segdir"}, True),
+    # the key (name and default kept) still switches it off
+    ({"tpu.enable": True, "match.segments.enable": False,
+      "match.segments.xla_cache": False}, False),
+    ({"tpu.enable": True, "match.segments.enable": True,
+      "match.segments.xla_cache": False,
+      "match.segments.dir": "/tmp/segdir"}, False),
+])
+def test_node_wires_xla_cache_on_every_tpu_start(monkeypatch, overrides,
+                                                 called):
+    """The node start path calls enable_xla_cache() (no path argument:
+    the cache is never derived from the segments dir) iff tpu.enable
+    and match.segments.xla_cache are on."""
     import emqx_tpu.node as node_mod
     from emqx_tpu.config import Config
 
     calls = []
     monkeypatch.setattr(node_mod, "enable_xla_cache",
-                        lambda p: calls.append(p) or True)
+                        lambda *a: calls.append(a) or True)
+    defaults = Config()
 
     class _Cfg:
-        def __init__(self, overrides):
-            self._c = Config()
-            self._o = overrides
-
         def get(self, key):
-            return self._o.get(key, self._c.get(key))
+            if key == "tpu.start_timeout":
+                return 0.001
+            return overrides.get(key, defaults.get(key))
 
-    async def probe(overrides):
-        calls.clear()
-        n = node_mod.BrokerNode.__new__(node_mod.BrokerNode)
-        n.config = _Cfg(overrides)
-        await n._start_match_service()
-
-    # tpu.enable off: nothing runs (the early return)
-    run(probe({"tpu.enable": False, "match.segments.enable": True}))
-    assert calls == []
-    # segments off: no cache dir either
-    run(probe({"tpu.enable": True, "match.segments.enable": False,
-               "tpu.start_timeout": 0.001}))
-    assert calls == []
-    # segments on + xla_cache off: skipped
-    run(probe({"tpu.enable": True, "match.segments.enable": True,
-               "match.segments.xla_cache": False,
-               "match.segments.dir": "/tmp/segdir",
-               "tpu.start_timeout": 0.001}))
-    assert calls == []
-    # segments on + xla_cache on (default): rooted under segments dir
-    run(probe({"tpu.enable": True, "match.segments.enable": True,
-               "match.segments.dir": "/tmp/segdir",
-               "tpu.start_timeout": 0.001}))
-    assert calls == [os.path.join("/tmp/segdir", "xla_cache")]
+    n = node_mod.BrokerNode.__new__(node_mod.BrokerNode)
+    n.config = _Cfg()
+    run(n._start_match_service())
+    assert calls == ([()] if called else [])

@@ -1,5 +1,5 @@
 """In-process TPU match service: the broker's own publish path rides the
-device kernel (VERDICT.md round-1 weak item 4 / next-round item 5).
+device kernel.
 
 Covers: router-delta mirror sync, hint production/consumption, fail-open
 on staleness, rule co-batching, and an e2e TCP publish storm where
@@ -110,7 +110,7 @@ def test_scoped_hint_invalidation():
     """Round-3 churn semantics: a router mutation only kills the hints it
     can actually make wrong.  Exact adds and any deletes resolve live via
     routes_with_wild; only a NEW wildcard filter matching the topic
-    invalidates (VERDICT.md round-2 item 3)."""
+    invalidates."""
 
     async def main():
         node = make_node()
@@ -463,7 +463,7 @@ def test_depth_bucketed_batch_parity():
 
 
 def test_hint_cache_lru_eviction_no_thrash():
-    """VERDICT r3 weak 9: a working set just over hint_cap must not
+    """A working set just over hint_cap must not
     flip the cache between full and empty.  Eviction takes only the
     least-recently-served entries, so the hot head of a Zipf working
     set keeps its hints (and its device duty cycle) while the cold
@@ -513,7 +513,7 @@ def test_hint_cache_lru_eviction_no_thrash():
     run(main())
 
 def test_rules_only_hot_set_survives_lru_eviction():
-    """VERDICT r4 weak 8: `hint_rules` hits must refresh LRU recency
+    """`hint_rules` hits must refresh LRU recency
     exactly like `hint_routes` does — a rules-only working set (topics
     matched by rule FROM-filters but with no subscribers) is hot, and
     must not age out of the cache under a cold tail."""

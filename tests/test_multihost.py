@@ -87,7 +87,7 @@ def test_leftover_devices_fold_into_dcn_axis():
 
 
 def test_two_process_jax_distributed_collectives():
-    """VERDICT r4 item 4: REAL two-process ``jax.distributed`` — spawn 2
+    """REAL two-process ``jax.distributed`` — spawn 2
     OS processes, bootstrap the coordination service on localhost, build
     the hybrid ICI x DCN mesh, and run psum / global-sum / ppermute
     collectives ACROSS processes.  All numeric assertions run inside the

@@ -1,5 +1,5 @@
-"""OCSP stapling cache against a mocked responder (VERDICT r4 item 8;
-emqx_ocsp_cache analog).  A throwaway CA + server cert are generated
+"""OCSP stapling cache against a mocked responder (emqx_ocsp_cache
+analog).  A throwaway CA + server cert are generated
 in-test; the responder is an injected fetch callable building real
 RFC 6960 DER responses with the CA key."""
 

@@ -1,4 +1,4 @@
-"""Two-tier hot/cold match table (VERDICT r4 item 2): routing
+"""Two-tier hot/cold match table: routing
 correctness and merged-answer parity vs the host oracle, with the
 pallas tier in interpret mode on the CPU mesh."""
 
