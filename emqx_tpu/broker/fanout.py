@@ -87,7 +87,7 @@ from typing import Any, Deque, Dict, List, Optional
 
 from .. import faultinject as _fi
 from .. import topic as T
-from ..observe.flightrec import STAGES as _FR_STAGES
+from ..observe.span import stage_span
 from .broker import DeliverResult
 from .message import Message
 
@@ -95,10 +95,6 @@ log = logging.getLogger(__name__)
 
 __all__ = ["FanoutPipeline"]
 
-# packed flight-recorder stage ids (observe/flightrec.py STAGES)
-_SID_QUEUE = _FR_STAGES.index("fanout_queue")
-_SID_DELIVER = _FR_STAGES.index("deliver")
-_SID_FLUSH = _FR_STAGES.index("flush")
 
 
 class FanoutPipeline:
@@ -168,22 +164,27 @@ class FanoutPipeline:
         # lifetime accounting (also mirrored into metrics when attached)
         self.batches = 0
         self.msgs = 0
-        # stage-level latency observatory (observe/hist.py): direct
-        # histogram references, None = zero-call recording sites.  All
-        # four are written by the drain loop (main plane, one writer).
+        # stage-level latency observatory: the three stage spans
+        # (observe/span.py: histogram + the "fanout" ring of the flight
+        # recorder) and two e2e histograms (observe/hist.py), None =
+        # zero-call recording sites.  These stages follow obs.hist
+        # .enable, ring and all: their stamps ride the message path.
+        # All are written by the drain loop (main plane, one writer).
         self.hists = hists
-        self._h_queue = self._h_deliver = None
-        self._h_flush = self._h_e2e = self._h_e2e_leg = None
+        self.flightrec = flightrec
+        self._sp_queue = self._sp_deliver = self._sp_flush = None
+        self._h_e2e = self._h_e2e_leg = None
         # per-leg e2e sampling knob (obs.hist.e2e_per_leg_sample):
         # 0 = off (the leg histogram's recording site is zero-call,
         # spy-asserted), N = record every Nth delivery leg — the
         # per-subscriber skew signal without the per-delivery cost
         self.e2e_per_leg_sample = int(e2e_per_leg_sample)
         self._leg_ctr = 0
+        ring = flightrec.ring("fanout") if flightrec is not None else None
         if hists is not None:
-            self._h_queue = hists.hist("obs.stage.fanout_queue")
-            self._h_deliver = hists.hist("obs.stage.deliver")
-            self._h_flush = hists.hist("obs.stage.flush")
+            self._sp_queue = stage_span("fanout_queue", hists, ring)
+            self._sp_deliver = stage_span("deliver", hists, ring)
+            self._sp_flush = stage_span("flush", hists, ring)
             self._h_e2e = hists.hist("obs.e2e.publish_deliver")
             if self.e2e_per_leg_sample > 0:
                 self._h_e2e_leg = hists.hist("obs.e2e.publish_deliver_leg")
@@ -192,9 +193,6 @@ class FanoutPipeline:
         # — per-batch oldest-wait without a parallel timestamp deque
         # (deferred re-queues and cancel-requeues stay approximate)
         self._q_head_ns = 0
-        self.flightrec = flightrec
-        self._ring = (flightrec.ring("fanout")
-                      if flightrec is not None else None)
 
     # ------------------------------------------------------------------
     # lifecycle
@@ -332,7 +330,7 @@ class FanoutPipeline:
                 if self.metrics is not None:
                     self.metrics.inc("broker.fanout.shape_bypass")
                 return False
-        if self._h_queue is not None and not self._q:
+        if self._sp_queue is not None and not self._q:
             self._q_head_ns = time.perf_counter_ns()
         self._q.append(msg)
         self._track(msg)
@@ -423,16 +421,13 @@ class FanoutPipeline:
             n = min(len(self._q), bound)
             popleft = self._q.popleft
             batch = [popleft() for _ in range(n)]
-            if self._h_queue is not None:
+            if self._sp_queue is not None:
                 # fanout_queue span: oldest queue wait for this batch
                 # (head stamp → pop), re-armed for the remaining queue
                 now_ns = time.perf_counter_ns()
                 head = self._q_head_ns
                 if head:
-                    self._h_queue.record(now_ns - head)
-                    if self._ring is not None:
-                        self._ring.push(_SID_QUEUE, head,
-                                        now_ns - head, n)
+                    self._sp_queue.rec(head, now_ns, n)
                 self._q_head_ns = now_ns if self._q else 0
             if self._q:
                 self._wake.set()
@@ -660,7 +655,7 @@ class FanoutPipeline:
         bmetrics = broker.metrics
         h_e2e = self._h_e2e
         h_leg = self._h_e2e_leg
-        t4 = time.perf_counter_ns() if self._h_deliver is not None else 0
+        t4 = time.perf_counter_ns() if self._sp_deliver is not None else 0
         now_wall = (time.time()
                     if h_e2e is not None or h_leg is not None else 0.0)
         for clientid, effs in plan.items():
@@ -709,17 +704,14 @@ class FanoutPipeline:
             for d in dropped:
                 hooks.run("message.dropped", (d, "queue_full"))
         # -- stage 5: bulk flush — ONE emit per client per batch
-        t5 = time.perf_counter_ns() if self._h_deliver is not None else 0
+        t5 = time.perf_counter_ns() if self._sp_deliver is not None else 0
         emit = broker.emit
         for clientid, pubs in out.items():
             emit(clientid, pubs)
-        if self._h_deliver is not None:
+        if self._sp_deliver is not None:
             t6 = time.perf_counter_ns()
-            self._h_deliver.record(t5 - t4)
-            self._h_flush.record(t6 - t5)
-            if self._ring is not None:
-                self._ring.push(_SID_DELIVER, t4, t5 - t4, len(msgs))
-                self._ring.push(_SID_FLUSH, t5, t6 - t5, len(out))
+            self._sp_deliver.rec(t4, t5, len(msgs))
+            self._sp_flush.rec(t5, t6, len(out))
 
     # ------------------------------------------------------------------
 

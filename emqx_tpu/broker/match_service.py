@@ -182,10 +182,11 @@ from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Set, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation as _Annot
 
 from .. import faultinject as _fi
 from .. import topic as T
-from ..observe.flightrec import STAGES as _FR_STAGES
+from ..observe.span import stage_span
 from ..ops.kernel_cache import CompileMiss
 from .trie import FilterTrie
 
@@ -194,17 +195,34 @@ log = logging.getLogger(__name__)
 __all__ = ["MatchService"]
 
 
-# packed flight-recorder stage ids (observe/flightrec.py STAGES)
-_SID_WAIT = _FR_STAGES.index("match_wait")
-_SID_ENCODE = _FR_STAGES.index("match_encode")
-_SID_DISPATCH = _FR_STAGES.index("match_dispatch")
-_SID_READBACK = _FR_STAGES.index("match_readback")
+_now_ns = time.perf_counter_ns      # the clock of every stage span
 
 
 class _StaleRace(RuntimeError):
     """A benign serving race (aid reused mid-flight): the batch answer
     can't be trusted, but the device itself is healthy — falls back to
     the CPU path WITHOUT counting against the circuit breaker."""
+
+
+class _Cycle:
+    """The books of one popped batch.  ``seq`` is shared by every span
+    of the batch on every plane (ring events' ``seq``, the profiler
+    annotations' ``seq``).  The serial serve paths close the books:
+    ``t0`` is the cycle's start, ``spanned`` the running sum of the
+    stage spans recorded inside it, ``t_in``/``t_out`` the stamps of
+    the worker function's first and last line (written by the worker:
+    the loop is parked on its ``await`` meanwhile), ``t_ep`` where the
+    loop-side epilogue began, ``t_mint`` where ``_mint_hints``
+    returned.  Every waiter's future resolves to its batch's cycle, so
+    that ``prefetch`` can time its own resumption from ``t_mint``."""
+
+    __slots__ = ("seq", "n", "gen", "t0", "spanned", "t_in", "t_out",
+                 "t_ep", "t_mint")
+
+    def __init__(self, seq: int, n: int, gen: int, t0: int) -> None:
+        self.seq, self.n, self.gen, self.t0 = seq, n, gen, t0
+        self.spanned = self.t_in = self.t_out = 0
+        self.t_ep = self.t_mint = 0
 
 
 def _bucket(n: int, minimum: int = 64) -> int:
@@ -569,27 +587,39 @@ class MatchService:
         self._probe_child: Any = None
         self._last_brownout = 0
 
-        # stage-level latency observatory (observe/hist.py): direct
-        # histogram references, None = zero-call recording sites.  The
-        # match_* histograms are written by the (single in-flight)
-        # worker-thread stages; match_wait by the serve loop — one
-        # writer per histogram, merged at read time.
+        # stage spans (observe/span.py): one handle per per-batch stage
+        # over its histogram (observe/hist.py, None with obs.hist.enable
+        # off) and its writer's ring of the always-on flight recorder
+        # (observe/flightrec.py, which also takes the breaker/brownout
+        # dump triggers); a handle is None where both are off and its
+        # site is one identity test.  One writer per handle: encode,
+        # dispatch and readback are written by the (single in-flight)
+        # worker-thread stages, everything else by the serve loop.
         self.hists = hists
-        self._h_wait = self._h_encode = None
-        self._h_dispatch = self._h_readback = None
+        self.flightrec = flightrec
+        ring_loop = ring_disp = ring_rb = None
+        if flightrec is not None:
+            ring_loop = flightrec.ring("match.serve")
+            ring_disp = flightrec.ring("match.encode")
+            ring_rb = flightrec.ring("match.readback")
+        # per waiter, so the histogram itself is the handle: match_wait
+        # (it also gates the enqueue stamp that rides the waiter
+        # tuples; one ring event a batch beside it) and match_resume
+        self._h_wait = self._h_resume = None
         if hists is not None:
             self._h_wait = hists.hist("obs.stage.match_wait")
-            self._h_encode = hists.hist("obs.stage.match_encode")
-            self._h_dispatch = hists.hist("obs.stage.match_dispatch")
-            self._h_readback = hists.hist("obs.stage.match_readback")
-        # always-on flight recorder (observe/flightrec.py): per-writer
-        # event rings + the breaker/brownout dump triggers
-        self.flightrec = flightrec
-        self._ring_loop = self._ring_disp = self._ring_rb = None
-        if flightrec is not None:
-            self._ring_loop = flightrec.ring("match.serve")
-            self._ring_disp = flightrec.ring("match.encode")
-            self._ring_rb = flightrec.ring("match.readback")
+            self._h_resume = hists.hist("obs.stage.match_resume")
+        self._sp_wait_batch = stage_span("match_wait", None, ring_loop)
+        self._sp_encode = stage_span("match_encode", hists, ring_disp)
+        self._sp_dispatch = stage_span("match_dispatch", hists, ring_disp)
+        self._sp_readback = stage_span("match_readback", hists, ring_rb)
+        # the cycle's books (serial serve paths), all on the loop
+        self._sp_cycle = stage_span("match_cycle", hists, ring_loop)
+        self._sp_window = stage_span("match_window", hists, ring_loop)
+        self._sp_hop_out = stage_span("match_hop_out", hists, ring_loop)
+        self._sp_hop_back = stage_span("match_hop_back", hists, ring_loop)
+        self._sp_epilogue = stage_span("match_epilogue", hists, ring_loop)
+        self._seq = 0       # batch sequence number: one per popped batch
 
         self.router.listeners.append(self._on_router_mutation)
 
@@ -1592,16 +1622,22 @@ class MatchService:
             # accounting below is mode-gated, so the extra element is
             # invisible outside the histogram record
             if self._h_wait is not None:
-                self._pending.append((topic, fut, time.perf_counter_ns()))
+                self._pending.append((topic, fut, _now_ns()))
             else:
                 self._pending.append((topic, fut))
             self._batch_wake.set()
             try:
-                await asyncio.wait_for(fut, self.prefetch_timeout_s)
+                cyc = await asyncio.wait_for(fut, self.prefetch_timeout_s)
             except Exception:
                 # timeout/cancel: publish falls back to the host path
                 self._note_prefetch_timeout(1)
                 log.debug("prefetch for %r timed out", topic, exc_info=True)
+                return
+            if cyc is not None and self._h_resume is not None:
+                # match_resume: the batch's hints were minted → this
+                # waiter runs again (a waiter resolved empty-handed
+                # got None and records nothing)
+                self._h_resume.record(_now_ns() - cyc.t_mint)
             return
         self._note_arrival(topic)
         if not self._usable():
@@ -1626,16 +1662,19 @@ class MatchService:
         if self._h_wait is not None:
             self._pending.append((topic, fut2,
                                   loop.time() + self.deadline_s,
-                                  time.perf_counter_ns()))
+                                  _now_ns()))
         else:
             self._pending.append(
                 (topic, fut2, loop.time() + self.deadline_s))
         self._batch_wake.set()
         try:
-            await asyncio.wait_for(fut2, self.prefetch_timeout_s)
+            cyc = await asyncio.wait_for(fut2, self.prefetch_timeout_s)
         except Exception:
             self._note_prefetch_timeout(1)
             log.debug("prefetch for %r timed out", topic, exc_info=True)
+            return
+        if cyc is not None and self._h_resume is not None:
+            self._h_resume.record(_now_ns() - cyc.t_mint)
 
     def _note_prefetch_timeout(self, n: int) -> None:
         if n and self.metrics is not None:
@@ -1678,7 +1717,7 @@ class MatchService:
                 continue
             fut = loop.create_future()
             if self._h_wait is not None:
-                ts = time.perf_counter_ns()
+                ts = _now_ns()
                 self._pending.append(
                     (topic, fut, deadline_t, ts) if deadline
                     else (topic, fut, ts))
@@ -1846,7 +1885,8 @@ class MatchService:
         rows = [ids[o:o + c].tolist() for o, c in zip(offs, nk[:n])]
         return rows, np.flatnonzero(sp[:n]).tolist(), nbytes, trips
 
-    def _encode_dispatch(self, inc, dev, topics, groups, donate):
+    def _encode_dispatch(self, inc, dev, topics, groups, donate,
+                         cyc=None):
         """WORKER-THREAD stage: encode every depth group and dispatch
         its kernel — both OFF the event loop (the encode of a 2048
         batch held the loop ~2.3 ms per dispatch; vocab dict reads are
@@ -1857,12 +1897,18 @@ class MatchService:
         group 1's answers stream back and — in pipeline mode — batch
         N+1 encodes while batch N computes.  ``donate`` hands the
         operand buffers to the kernel (pipeline mode; nothing reads
-        them after dispatch)."""
+        them after dispatch).  ``cyc`` is the batch's books (``seq`` for
+        the spans; this function's first- and last-line stamps for the
+        loop's hop spans)."""
+        t_in = _now_ns()
         from ..ops import encode_batch
 
         handles = []
         enc_ns = disp_ns = 0
         gen = self._table_gen
+        seq = 0
+        if cyc is not None:
+            cyc.t_in, seq = t_in, cyc.seq
         multichip = getattr(dev, "is_multichip", False)
         # autotune reservoir: a slice of what this dispatch actually
         # serves (deque append is GIL-atomic; readers tolerate skew)
@@ -1870,30 +1916,37 @@ class MatchService:
         for idx, d in groups:
             be = "hash" if multichip else \
                 self._backend_for(_bucket(len(idx)), d)
-            t0 = time.perf_counter_ns()
-            if multichip:
-                # the shard partition's SHARED vocab assigns different
-                # word ids than the service table — encode there, then
-                # fan the batch over the mesh (rows come back already
-                # translated to service accept ids)
-                enc = dev.encode([topics[i] for i in idx],
-                                 batch=_bucket(len(idx)), depth=d)
-                t1 = time.perf_counter_ns()
-                res = dev.dispatch(
-                    enc, block_compile=(dev.kernel_cache is None))
-                t2 = time.perf_counter_ns()
-            else:
-                enc = encode_batch(inc, [topics[i] for i in idx],
-                                   batch=_bucket(len(idx)), depth=d)
-                t1 = time.perf_counter_ns()
-                res = dev.match(
-                    *enc, flat_cap=self.FLAT_MULT * enc[0].shape[0],
-                    # serving never parks behind XLA: an uncompiled
-                    # shape raises CompileMiss (CPU trie answers, shape
-                    # warms in the background) instead of stalling
-                    block_compile=(dev.kernel_cache is None),
-                    donate_inputs=donate, backend=be)
-                t2 = time.perf_counter_ns()
+            n = len(idx)
+            # each stage also goes into a running profiler trace under
+            # its own start stamp (t_ns): the anchors that join the
+            # spans' clock to the device timeline
+            t0 = _now_ns()
+            with _Annot("emqx.match.encode", seq=seq, n=n, t_ns=t0):
+                if multichip:
+                    # the shard partition's SHARED vocab assigns
+                    # different word ids than the service table — encode
+                    # there, then fan the batch over the mesh (rows come
+                    # back already translated to service accept ids)
+                    enc = dev.encode([topics[i] for i in idx],
+                                     batch=_bucket(n), depth=d)
+                else:
+                    enc = encode_batch(inc, [topics[i] for i in idx],
+                                       batch=_bucket(n), depth=d)
+            t1 = _now_ns()
+            with _Annot("emqx.match.dispatch", seq=seq, n=n, t_ns=t1):
+                if multichip:
+                    res = dev.dispatch(
+                        enc, block_compile=(dev.kernel_cache is None))
+                else:
+                    res = dev.match(
+                        *enc, flat_cap=self.FLAT_MULT * enc[0].shape[0],
+                        # serving never parks behind XLA: an uncompiled
+                        # shape raises CompileMiss (CPU trie answers,
+                        # shape warms in the background) instead of
+                        # stalling
+                        block_compile=(dev.kernel_cache is None),
+                        donate_inputs=donate, backend=be)
+            t2 = _now_ns()
             if be in ("join", "join-pallas") and self.metrics is not None:
                 # this worker is the single in-flight encode stage, so
                 # the counter has one writer (same as the histograms)
@@ -1902,63 +1955,62 @@ class MatchService:
             disp_ns += t2 - t1
             # stage spans: this worker is the single in-flight encode
             # stage, so it is the sole writer of these two histograms
-            # and its flight-recorder ring
-            if self._h_encode is not None:
-                self._h_encode.record(t1 - t0)
-                self._h_dispatch.record(t2 - t1)
-            if self._ring_disp is not None:
-                self._ring_disp.push(_SID_ENCODE, t0, t1 - t0,
-                                     len(idx), gen)
-                self._ring_disp.push(_SID_DISPATCH, t1, t2 - t1,
-                                     len(idx), gen)
-            handles.append((res, len(idx)))
+            # and its flight-recorder ring (both handles or neither)
+            if self._sp_encode is not None:
+                self._sp_encode.rec(t0, t1, n, gen, seq)
+                self._sp_dispatch.rec(t1, t2, n, gen, seq)
+            handles.append((res, n))
+        if cyc is not None:
+            cyc.t_out = _now_ns()
         return handles, enc_ns, disp_ns
 
-    def _readback_groups(self, handles, dev, proportional):
+    def _readback_groups(self, handles, dev, proportional, cyc=None):
         """WORKER-THREAD stage: block on every group's d2h.  Serial
         (flag-off) mode reads the full flat slab exactly as PR 10 did
         unless ``match.readback.mode`` asks for the ragged contract;
         ``proportional`` (pipeline mode) rides the two-phase contract
         in the configured transfer shape.  Returns ``([(rows,
         spilled)...], total d2h bytes, readback ns, d2h round
-        trips)``."""
+        trips)``.  ``cyc`` as in :meth:`_encode_dispatch`."""
+        t0 = _now_ns()
         out = []
         nbytes = 0
-        t0 = time.perf_counter_ns()
-        total = 0
+        total = sum(n for _res, n in handles)
         trips = 0
+        seq = 0
+        if cyc is not None:
+            cyc.t_in, seq = t0, cyc.seq
         multichip = getattr(dev, "is_multichip", False)
-        for res, n in handles:
-            if multichip:
-                # dense compact contract off the mesh: d2h is already
-                # matches-proportional in BOTH serve modes, one
-                # device_get round trip
-                rows, sp, b = dev.readback(res, n)
-                t = 1
-            elif proportional or self.readback_mode != "chunked":
-                rows, sp, b, t = self._readback_rows_twophase(
-                    res, n, dev.max_matches, mode=self.readback_mode,
-                    auto_slack=self.readback_auto_slack)
-            else:
-                rows, sp = self._readback_rows(res, n, dev.max_matches)
-                # the slab cost: the flat id buffer + counts and both
-                # overflow vectors (what device_get above shipped) in
-                # one round trip
-                b = 4 * int(res.matches.size + 3 * res.n_matches.size)
-                t = 1
-            nbytes += b
-            total += n
-            trips += t
-            out.append((rows, sp))
-        rb_ns = time.perf_counter_ns() - t0
+        with _Annot("emqx.match.readback", seq=seq, n=total, t_ns=t0):
+            for res, n in handles:
+                if multichip:
+                    # dense compact contract off the mesh: d2h is
+                    # already matches-proportional in BOTH serve modes,
+                    # one device_get round trip
+                    rows, sp, b = dev.readback(res, n)
+                    t = 1
+                elif proportional or self.readback_mode != "chunked":
+                    rows, sp, b, t = self._readback_rows_twophase(
+                        res, n, dev.max_matches, mode=self.readback_mode,
+                        auto_slack=self.readback_auto_slack)
+                else:
+                    rows, sp = self._readback_rows(res, n, dev.max_matches)
+                    # the slab cost: the flat id buffer + counts and
+                    # both overflow vectors (what device_get above
+                    # shipped) in one round trip
+                    b = 4 * int(res.matches.size + 3 * res.n_matches.size)
+                    t = 1
+                nbytes += b
+                trips += t
+                out.append((rows, sp))
+        t1 = _now_ns()
         # single writer: the flag-off serve loop's to_thread hop OR the
         # pipelined readback child — never both in one mode
-        if self._h_readback is not None:
-            self._h_readback.record(rb_ns)
-        if self._ring_rb is not None:
-            self._ring_rb.push(_SID_READBACK, t0, rb_ns, total,
-                               self._table_gen)
-        return out, nbytes, rb_ns, trips
+        if self._sp_readback is not None:
+            self._sp_readback.rec(t0, t1, total, self._table_gen, seq)
+        if cyc is not None:
+            cyc.t_out = _now_ns()
+        return out, nbytes, t1 - t0, trips
 
     def _depth_groups(self, topics: List[str]) -> List[Tuple[List[int], int]]:
         """Partition batch indices into (indices, kernel_depth) groups.
@@ -1994,24 +2046,54 @@ class MatchService:
                 self._batch_wake.clear()
                 if not self._pending:
                     continue
+                t_wake = _now_ns()      # the cycle starts: woken with work
                 await asyncio.sleep(self.batch_window_s)
                 pending, self._pending = self._pending[: self.max_batch], \
                     self._pending[self.max_batch:]
                 if self._pending:
                     self._batch_wake.set()
-                await self._serve_batch(pending)
+                await self._serve_batch(pending, t_wake)
         finally:
             self._fail_over_waiters()
 
-    def _rec_wait(self, pending: List[Any]) -> None:
-        """Record each popped waiter's queue wait (enqueue → dispatch
-        start) + one flight-recorder event per batch.  Only reachable
+    def _open_cycle(self, pending: List[Any], t_wake: int) -> _Cycle:
+        """A batch was popped: give it its ``seq``, record its waiters'
+        queue waits and, where the serve loop stamped its wake-up
+        (``t_wake``), the batching window ``t_wake`` → now."""
+        now = _now_ns()
+        self._seq += 1
+        cyc = _Cycle(self._seq, len(pending), self._table_gen,
+                     t_wake or now)
+        if self._h_wait is not None:
+            self._rec_wait(pending, now, cyc)
+        if t_wake:
+            cyc.spanned = now - t_wake
+            if self._sp_window is not None:
+                self._sp_window.rec(t_wake, now, cyc.n, cyc.gen, cyc.seq)
+        return cyc
+
+    def _close_cycle(self, cyc: _Cycle) -> None:
+        """The serial paths' last word on a batch, whichever way it
+        went: the loop-side epilogue (where the batch got that far), the
+        whole cycle, and the two counters whose ratio says how much of
+        the cycle lay inside a stage span."""
+        end = cyc.t_mint or _now_ns()
+        if cyc.t_ep:
+            cyc.spanned += end - cyc.t_ep
+        if self._sp_cycle is not None:      # the loop's handles: all or none
+            if cyc.t_ep:
+                self._sp_epilogue.rec(cyc.t_ep, end, cyc.n, cyc.gen, cyc.seq)
+            self._sp_cycle.rec(cyc.t0, end, cyc.n, cyc.gen, cyc.seq)
+        if self.metrics is not None:
+            self.metrics.inc("tpu.match.cycle_ns", end - cyc.t0)
+            self.metrics.inc("tpu.match.cycle_spanned_ns", cyc.spanned)
+
+    def _rec_wait(self, pending: List[Any], now_ns: int,
+                  cyc: _Cycle) -> None:
+        """Record each popped waiter's queue wait (enqueue → its batch
+        is popped) + one flight-recorder event per batch.  Only reachable
         with histograms on — the stamps ride the waiter tuples' tail."""
-        h = self._h_wait
-        if h is None or not pending:
-            return
-        now_ns = time.perf_counter_ns()
-        rec = h.record
+        rec = self._h_wait.record
         oldest = now_ns
         n = 0
         for p in pending:
@@ -2026,16 +2108,16 @@ class MatchService:
             n += 1
             if ts < oldest:
                 oldest = ts
-        if n and self._ring_loop is not None:
-            self._ring_loop.push(_SID_WAIT, oldest, now_ns - oldest,
-                                 n, self._table_gen)
+        if n and self._sp_wait_batch is not None:
+            self._sp_wait_batch.rec(oldest, now_ns, n, cyc.gen, cyc.seq)
 
-    async def _serve_batch(self, pending: List[Any]) -> None:
+    async def _serve_batch(self, pending: List[Any],
+                           t_wake: int = 0) -> None:
         """Fixed-window dispatch: device rows → hints, any failure
         resolves the waiters empty-handed (host trie serves)."""
-        self._rec_wait(pending)
+        cyc = self._open_cycle(pending, t_wake)
         if self.pipeline:
-            await self._pipeline_dispatch(pending, deadline_mode=False)
+            await self._pipeline_dispatch(pending, False, cyc)
             return
         topics = [p[0] for p in pending]
         # the hint's provenance is the epoch the DEVICE table
@@ -2048,8 +2130,8 @@ class MatchService:
                 # ordinary churn (the mirror lags the router past the
                 # staleness bound): counted below, not a device fault
                 raise _StaleRace("mirror stale")
-            rows = await self._dispatch_guarded(topics)
-            self._mint_hints(pending, rows, epoch, rule_gen)
+            rows = await self._dispatch_guarded(topics, cyc)
+            self._mint_hints(pending, rows, epoch, rule_gen, cyc)
         except Exception as e:
             if not isinstance(e, (_StaleRace, CompileMiss)):
                 self._warn_device_failure("device batch", e)
@@ -2062,6 +2144,8 @@ class MatchService:
             # the host trie: the same accounting as _fail_over_waiters
             if n and self.metrics is not None:
                 self.metrics.inc("broker.match.cpu_fallback", n)
+        finally:
+            self._close_cycle(cyc)
 
     def _warn_device_failure(self, what: str, e: BaseException) -> None:
         """The serve plane is fail-open by design — the host trie
@@ -2104,15 +2188,23 @@ class MatchService:
             elif act == "hang":
                 await _fi._injector.hang()
 
-    async def _dispatch_guarded(self, topics: List[str]) -> List[Any]:
+    async def _dispatch_guarded(self, topics: List[str],
+                                cyc: Optional[_Cycle] = None) -> List[Any]:
         await self._fault_gate()
-        return await self._device_serve(topics)
+        return await self._device_serve(topics, cyc)
 
-    async def _device_serve(self, topics: List[str]) -> List[Any]:
+    async def _device_serve(self, topics: List[str],
+                            cyc: Optional[_Cycle] = None) -> List[Any]:
         """Encode + kernel dispatch + readback + spill/deep merge for one
         batch; returns one aid row per topic.  Raises :class:`_StaleRace`
         when a freed accept id was handed out mid-flight (benign — the
-        answer is untrusted but the device is healthy)."""
+        answer is untrusted but the device is healthy).
+
+        The batch's books (``cyc``) get the two thread hops each way —
+        ``to_thread`` called → the worker's first line, its last line →
+        this coroutine runs again (the first hop back holds the readback
+        chaos gate) — and the start of the loop-side epilogue, which the
+        caller's ``_close_cycle`` ends."""
         # aid-reuse guard: if a freed accept id is handed out
         # again while this batch is in flight, the device rows
         # may name it under its OLD filter — translating through
@@ -2124,19 +2216,37 @@ class MatchService:
         reuses0 = inc.aid_reuses
         gen0 = self._table_gen
         groups = self._depth_groups(topics)
+        if cyc is None:     # a direct call: books nobody closes
+            cyc = _Cycle(0, len(topics), gen0, 0)
+        t_call = _now_ns()
         handles, enc_ns, disp_ns = await asyncio.to_thread(
-            self._encode_dispatch, inc, dev, topics, groups, False
+            self._encode_dispatch, inc, dev, topics, groups, False, cyc
         )
         await self._readback_gate()
+        t_mid = _now_ns()
+        self._rec_hops(cyc, t_call, t_mid)
         results, nbytes, rb_ns, trips = await asyncio.to_thread(
-            self._readback_groups, handles, dev, False
+            self._readback_groups, handles, dev, False, cyc
         )
-        self._note_split((enc_ns + disp_ns) / 1e9, rb_ns / 1e9)
-        if self.metrics is not None:
-            self.metrics.inc("tpu.match.readback_bytes", nbytes)
-            self.metrics.inc("tpu.match.readback_roundtrips", trips)
-        return self._collect_rows(topics, groups, results,
-                                  inc, reuses0, gen0)
+        t_ep = cyc.t_ep = _now_ns()
+        self._rec_hops(cyc, t_mid, t_ep)
+        cyc.spanned += enc_ns + disp_ns + rb_ns
+        with _Annot("emqx.match.epilogue", seq=cyc.seq, n=cyc.n, t_ns=t_ep):
+            self._note_split((enc_ns + disp_ns) / 1e9, rb_ns / 1e9)
+            if self.metrics is not None:
+                self.metrics.inc("tpu.match.readback_bytes", nbytes)
+                self.metrics.inc("tpu.match.readback_roundtrips", trips)
+            return self._collect_rows(topics, groups, results,
+                                      inc, reuses0, gen0)
+
+    def _rec_hops(self, cyc: _Cycle, t_call: int, t_back: int) -> None:
+        """One worker hop, seen from the loop: ``t_call`` (``to_thread``
+        called) → the worker's first line, and its last line →
+        ``t_back`` (this loop runs the caller again)."""
+        cyc.spanned += (cyc.t_in - t_call) + (t_back - cyc.t_out)
+        if self._sp_hop_out is not None:    # the loop's handles: all or none
+            self._sp_hop_out.rec(t_call, cyc.t_in, cyc.n, cyc.gen, cyc.seq)
+            self._sp_hop_back.rec(cyc.t_out, t_back, cyc.n, cyc.gen, cyc.seq)
 
     def _collect_rows(self, topics: List[str], groups, results,
                       inc, reuses0: int, gen0: int) -> List[Any]:
@@ -2199,21 +2309,31 @@ class MatchService:
         return rows
 
     def _mint_hints(self, pending: List[Any], rows: List[Any],
-                    epoch: int, rule_gen: int) -> None:
-        for p, row in zip(pending, rows):
-            topic, fut = p[0], p[1]
-            # pop-then-insert: a refreshed hint is ACTIVE — plain
-            # assignment would keep its stale dict position and
-            # let the post-insert prune evict it ahead of colder
-            # entries, wasting the device work just spent on it
-            self._hints.pop(topic, None)
-            self._hints[topic] = (epoch, rule_gen,
-                                  *self._split_row(row))
-            if not fut.done():
-                fut.set_result(None)
-        self._evict()
-        if self.deadline and self.metrics is not None:
-            self._count_misses(pending)
+                    epoch: int, rule_gen: int,
+                    cyc: Optional[_Cycle] = None) -> None:
+        """Park the device's rows in the hint cache and wake the
+        waiters.  Each future resolves to the batch's books (``cyc``;
+        no consumer reads the result but ``prefetch``'s own
+        ``match_resume`` span, which starts at ``t_mint``)."""
+        t0 = _now_ns()
+        with _Annot("emqx.match.epilogue", seq=cyc.seq if cyc else 0,
+                    n=len(pending), t_ns=t0):
+            for p, row in zip(pending, rows):
+                topic, fut = p[0], p[1]
+                # pop-then-insert: a refreshed hint is ACTIVE — plain
+                # assignment would keep its stale dict position and
+                # let the post-insert prune evict it ahead of colder
+                # entries, wasting the device work just spent on it
+                self._hints.pop(topic, None)
+                self._hints[topic] = (epoch, rule_gen,
+                                      *self._split_row(row))
+                if not fut.done():
+                    fut.set_result(cyc)
+            self._evict()
+            if self.deadline and self.metrics is not None:
+                self._count_misses(pending)
+        if cyc is not None:
+            cyc.t_mint = _now_ns()
 
     def _evict(self) -> None:
         # evict AFTER insert, least-recently-SERVED first (dict
@@ -2303,6 +2423,7 @@ class MatchService:
             while True:
                 await self._batch_wake.wait()
                 self._batch_wake.clear()
+                t_wake = _now_ns()      # a cycle starts: woken with work
                 while self._pending:
                     if not self._device_ok():
                         # breaker open / brownout stage 3 / mirror gone
@@ -2331,7 +2452,9 @@ class MatchService:
                         # partial batch forced out by the budget — the
                         # deadline doing its job, not an anomaly
                         self.metrics.inc("broker.match.deadline_dispatch")
-                    await self._serve_batch_deadline(self._pop_batch(bound))
+                    await self._serve_batch_deadline(
+                        self._pop_batch(bound), t_wake)
+                    t_wake = _now_ns()  # the next cycle, if work is left
         finally:
             self._fail_over_waiters()
 
@@ -2397,15 +2520,16 @@ class MatchService:
         self._pending = rest
         return take
 
-    async def _serve_batch_deadline(self, pending: List[Any]) -> None:
+    async def _serve_batch_deadline(self, pending: List[Any],
+                                    t_wake: int = 0) -> None:
         """One deadline-mode dispatch: chaos seam + per-dispatch timeout
         around the kernel call; ANY failure answers the whole batch from
         the CPU tables immediately and feeds the circuit breaker."""
         if not pending:
             return
-        self._rec_wait(pending)
+        cyc = self._open_cycle(pending, t_wake)
         if self.pipeline:
-            await self._pipeline_dispatch(pending, deadline_mode=True)
+            await self._pipeline_dispatch(pending, True, cyc)
             return
         topics = [p[0] for p in pending]
         epoch = self._synced_epoch
@@ -2413,31 +2537,32 @@ class MatchService:
         t0 = time.monotonic()
         try:
             rows = await asyncio.wait_for(
-                self._dispatch_guarded(topics), self.dispatch_timeout_s)
+                self._dispatch_guarded(topics, cyc),
+                self.dispatch_timeout_s)
         except asyncio.CancelledError:
             # loop death mid-dispatch: the finally-failover resolves
             self._pending = pending + self._pending
             raise
         except _StaleRace:
             self._cpu_serve(pending)    # benign race: no breaker strike
-            return
         except CompileMiss:
             # fresh padded shape not compiled yet: the CPU trie answers
             # NOW while the kernel cache warms it in the background —
             # the device is healthy, so no breaker strike
             self._cpu_serve(pending)
-            return
         except Exception:
             log.debug("deadline dispatch failed; CPU trie serves the "
                       "batch", exc_info=True)
             self._breaker_note_failure()
             self._cpu_serve(pending)
-            return
-        self._breaker_note_ok()
-        # EWMA dispatch-time estimate drives the partial-flush trigger
-        dt = time.monotonic() - t0
-        self._est_dispatch_s = self._est_dispatch_s * 0.7 + dt * 0.3
-        self._mint_hints(pending, rows, epoch, rule_gen)
+        else:
+            self._breaker_note_ok()
+            # EWMA dispatch-time estimate drives the partial-flush trigger
+            dt = time.monotonic() - t0
+            self._est_dispatch_s = self._est_dispatch_s * 0.7 + dt * 0.3
+            self._mint_hints(pending, rows, epoch, rule_gen, cyc)
+        finally:
+            self._close_cycle(cyc)
 
     def _cpu_serve(self, pending: List[Any]) -> None:
         """Answer a batch from the CPU tables (host NFA walk + deep
@@ -2479,14 +2604,18 @@ class MatchService:
     # ------------------------------------------------------------------
 
     async def _pipeline_dispatch(self, pending: List[Any],
-                                 deadline_mode: bool) -> None:
+                                 deadline_mode: bool,
+                                 cyc: Optional[_Cycle] = None) -> None:
         """Pipeline-mode front half of a serve batch: encode + dispatch
         in a worker thread (donated operand buffers), then hand the
         in-flight slot to the ``match.readback`` child and return — the
         serve loop goes straight back to batching (and encoding batch
         N+1) while this batch computes on device.  Every slot carries
         the aid-reuse/table-gen guards it dispatched against, so a swap
-        or reuse landing mid-flight discards exactly the stale slot."""
+        or reuse landing mid-flight discards exactly the stale slot.
+        ``cyc`` rides the slot for its ``seq`` alone: the overlapped
+        stages of this path share the stage spans, not the closed
+        books of the serial one."""
         if not pending:
             return
         topics = [p[0] for p in pending]
@@ -2503,7 +2632,7 @@ class MatchService:
             await self._fault_gate()
             groups = self._depth_groups(topics)
             dispatch = asyncio.to_thread(
-                self._encode_dispatch, inc, dev, topics, groups, True)
+                self._encode_dispatch, inc, dev, topics, groups, True, cyc)
             if deadline_mode:
                 handles, enc_ns, disp_ns = await asyncio.wait_for(
                     dispatch, self.dispatch_timeout_s)
@@ -2511,7 +2640,7 @@ class MatchService:
                 handles, enc_ns, disp_ns = await dispatch
             slot = (pending, topics, groups, handles, inc, dev,
                     reuses0, gen0, epoch, rule_gen, t0, deadline_mode,
-                    enc_ns + disp_ns)
+                    enc_ns + disp_ns, cyc)
             await self._inflight_q.put(slot)   # backpressure at depth
             self._inflight_n += 1
             self._set_inflight_metric()
@@ -2554,13 +2683,13 @@ class MatchService:
         slot's batch from the CPU tables.  The finally backstop keeps
         the kill path from stranding waiters on the prefetch timeout."""
         (pending, topics, groups, handles, inc, dev, reuses0, gen0,
-         epoch, rule_gen, t0, deadline_mode, dispatch_ns) = slot
+         epoch, rule_gen, t0, deadline_mode, dispatch_ns, cyc) = slot
         try:
             try:
                 await self._readback_gate()
                 results, nbytes, rb_ns, trips = await asyncio.wait_for(
                     asyncio.to_thread(
-                        self._readback_groups, handles, dev, True),
+                        self._readback_groups, handles, dev, True, cyc),
                     self.dispatch_timeout_s)
                 self._note_split(dispatch_ns / 1e9, rb_ns / 1e9)
                 if self.metrics is not None:
@@ -2592,7 +2721,7 @@ class MatchService:
                 dt = time.monotonic() - t0
                 self._est_dispatch_s = (
                     self._est_dispatch_s * 0.7 + dt * 0.3)
-            self._mint_hints(pending, rows, epoch, rule_gen)
+            self._mint_hints(pending, rows, epoch, rule_gen, cyc)
         finally:
             for p in pending:
                 if not p[1].done():

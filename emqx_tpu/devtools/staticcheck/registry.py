@@ -49,7 +49,8 @@ class Registries:
                  fault_points: Set[str],
                  hook_points: Optional[Set[str]] = None,
                  hist_names: Optional[Set[str]] = None,
-                 dump_reasons: Optional[Set[str]] = None) -> None:
+                 dump_reasons: Optional[Set[str]] = None,
+                 stage_names: Optional[Set[str]] = None) -> None:
         self.metric_names = metric_names
         self.config_keys = config_keys
         self.fault_points = fault_points
@@ -57,6 +58,8 @@ class Registries:
         self.hist_names = hist_names if hist_names is not None else set()
         self.dump_reasons = (dump_reasons if dump_reasons is not None
                              else set())
+        self.stage_names = (stage_names if stage_names is not None
+                            else set())
 
     @classmethod
     def load(cls, package_root: Optional[str] = None) -> "Registries":
@@ -81,12 +84,16 @@ class Registries:
             dump_reasons=cls._named_list(
                 os.path.join(package_root, "observe", "flightrec.py"),
                 "DUMP_REASONS"),
+            stage_names=cls._named_list(
+                os.path.join(package_root, "observe", "flightrec.py"),
+                "STAGES"),
         )
 
     @staticmethod
     def _named_list(path: str, varname: str) -> Set[str]:
         """String elements of a top-level ``varname = [...]`` (or
-        tuple) assignment — the HIST_NAMES / DUMP_REASONS shape."""
+        tuple) assignment — the HIST_NAMES / DUMP_REASONS / STAGES
+        shape."""
         for node in _parse(path).body:
             if isinstance(node, (ast.Assign, ast.AnnAssign)):
                 targets = (node.targets if isinstance(node, ast.Assign)

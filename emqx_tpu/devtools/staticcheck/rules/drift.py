@@ -32,7 +32,12 @@ cold path nothing exercises (metrics/config) or silently never fires
   at a COLD setup site nothing in tier-1 may exercise;
 * ``flightrec.dump("reason")`` → the ``DUMP_REASONS`` tuple in
   ``observe/flightrec.py`` — an undeclared reason raises at the
-  trigger site, which is the breaker-trip / escalation path.
+  trigger site, which is the breaker-trip / escalation path;
+* ``stage_span("stage", hists, ring)`` → the ``STAGES`` tuple in
+  ``observe/flightrec.py`` AND ``obs.stage.<stage>`` in ``HIST_NAMES``
+  — ``observe/span.py`` resolves a stage's ring id and its histogram
+  from the one name, at a set-up site that may run only with the
+  device attached.
 
 Dynamic names (f-strings, variables) are skipped except for the alarm
 prefix check; the registries are extracted statically (``registry.py``).
@@ -107,6 +112,9 @@ class RegistryDrift(Rule):
         if ctx.relpath in self._REGISTRY_FILES:
             return
         func = node.func
+        if terminal_name(func) == "stage_span":
+            self._check_stage(node, ctx)
+            return
         if not isinstance(func, ast.Attribute):
             return
         method = func.attr
@@ -190,6 +198,26 @@ class RegistryDrift(Rule):
                 f"histogram {name!r} is not registered in HIST_NAMES "
                 "(emqx_tpu/observe/hist.py) — HistSet.hist raises "
                 "KeyError at this (cold, setup-time) lookup",
+            )
+
+    def _check_stage(self, node: ast.Call, ctx: FileContext) -> None:
+        stage = str_arg(node)
+        if stage is None:
+            return
+        if stage not in self.registries.stage_names:
+            ctx.report(
+                self.name, node,
+                f"stage {stage!r} is not declared in STAGES "
+                "(emqx_tpu/observe/flightrec.py) — stage_span raises "
+                "ValueError at this (cold, setup-time) lookup",
+            )
+        elif f"obs.stage.{stage}" not in self.registries.hist_names:
+            ctx.report(
+                self.name, node,
+                f"stage {stage!r} has no histogram "
+                f"'obs.stage.{stage}' in HIST_NAMES (emqx_tpu/observe/"
+                "hist.py) — stage_span raises KeyError wherever "
+                "histograms are on",
             )
 
     def _check_dump_reason(self, node: ast.Call, ctx: FileContext) -> None:

@@ -242,11 +242,20 @@ class BrokerNode:
 
         self.hists = HistSet("main") if cfg.get("obs.hist.enable") \
             else None
+        # a main-loop connection's per-publish stage histograms,
+        # resolved once here and handed to every protocol
+        # make_protocol builds
+        self._conn_hists = None
         if self.hists is not None:
             # sync publish path spans: traffic bypassing the fanout
             # pipeline (shape gate, fanout off) records into the same
             # deliver/flush/e2e histograms the batched drain writes
             self.broker.attach_hists(self.hists)
+            self._conn_hists = (
+                self.hists.hist("obs.stage.ingest_parse"),
+                self.hists.hist("obs.stage.ingest_queue"),
+                self.hists.hist("obs.stage.intercept"),
+                self.hists.hist("obs.stage.handle_publish"))
         self.flightrec = FlightRecorder(
             self.tracing.dir,
             depth=cfg.get("obs.flightrec.depth"),
@@ -635,8 +644,9 @@ class BrokerNode:
             coalesce=bool(self.config.get("broker.fanout.enable")),
             wheel=self.timer_wheel,
         )
-        if self.hists is not None:
-            proto._h_parse = self.hists.hist("obs.stage.ingest_parse")
+        if self._conn_hists is not None:
+            (proto._h_parse, proto._h_queue, proto._h_intercept,
+             proto._h_handle) = self._conn_hists
         channel.conn = proto
         self._register_on_connect(channel, proto)
         self._all_conns.add(proto)

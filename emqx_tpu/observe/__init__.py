@@ -14,6 +14,7 @@ from .topic_metrics import TopicMetrics
 from .sys_topics import SysBroker
 from .hist import LatencyHistogram, HistSet, HIST_NAMES
 from .flightrec import FlightRecorder, DUMP_REASONS
+from .span import Span, stage_span
 
 __all__ = [
     "TopicMetrics",
@@ -21,4 +22,5 @@ __all__ = [
     "Alarms", "Alarm", "SysBroker",
     "LatencyHistogram", "HistSet", "HIST_NAMES",
     "FlightRecorder", "DUMP_REASONS",
+    "Span", "stage_span",
 ]

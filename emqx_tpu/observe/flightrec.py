@@ -9,16 +9,23 @@ history for free:
   the match readback child, ...) writes stage events into its own
   preallocated **ring buffer** (:class:`Ring`, default depth 4096,
   ``obs.flightrec.depth``) — an event is a packed
-  ``(stage id, start ns, duration ns, batch size, slot gen)`` tuple
-  slot-assigned into the ring, single writer per ring, no locks, no
-  growth;
+  ``(stage id, start ns, duration ns, batch size, slot gen, seq)``
+  tuple slot-assigned into the ring, single writer per ring, no locks,
+  no growth.  ``seq`` is the match batcher's batch sequence number:
+  one per popped batch, shared by every span of that batch's cycle on
+  every plane (0 on planes that have no batch cycle);
 * on a trigger — breaker trip, brownout escalation,
   ``supervisor_degraded``, or the mgmt REST/CLI manual trigger — the
   recorder **snapshots every ring without pausing writers** and writes
   a Chrome trace-event JSON file (``trace/flightrec-<reason>-<ts>.json``
   in the TraceManager dir) that opens directly in Perfetto
   (https://ui.perfetto.dev): one named track per plane, one duration
-  slice per event, batch size + slot gen in the args;
+  slice per event, batch size + slot gen + ``seq`` in the args, and a
+  ``clock`` entry (``perf_counter_ns`` and ``time_ns`` read together)
+  that ties the events' clock to the wall clock.  The same
+  ``perf_counter_ns`` stamps ride the ``emqx.match.*`` annotations the
+  match workers write into a ``jax.profiler`` trace (``t_ns``), so the
+  ring's events can be laid on the device timeline;
 * the write is **atomic** (temp file + ``os.replace`` in the same
   directory): a kill mid-dump leaves the previous state on disk and no
   torn file — asserted in tests/test_chaos_delivery.py;
@@ -50,9 +57,15 @@ DUMP_REASONS = (
 )
 
 #: packed stage ids: index into this tuple == the event's stage id
+#: (additions at the end only); every stage has the histogram
+#: ``obs.stage.<stage>`` — ``observe/span.py`` resolves both from the
+#: one name
 STAGES = (
     "ingest_parse", "fanout_queue", "match_wait", "match_encode",
     "match_dispatch", "match_readback", "deliver", "flush",
+    "match_cycle", "match_window", "match_hop_out", "match_hop_back",
+    "match_epilogue", "match_resume",
+    "ingest_queue", "intercept", "handle_publish",
 )
 
 
@@ -78,9 +91,9 @@ class Ring:
         self.idx = 0
 
     def push(self, sid: int, start_ns: int, dur_ns: int,
-             batch: int = 0, gen: int = 0) -> None:
+             batch: int = 0, gen: int = 0, seq: int = 0) -> None:
         i = self.idx
-        self.buf[i & self._mask] = (sid, start_ns, dur_ns, batch, gen)
+        self.buf[i & self._mask] = (sid, start_ns, dur_ns, batch, gen, seq)
         self.idx = i + 1
 
     def snapshot(self) -> List[Tuple]:
@@ -125,14 +138,14 @@ class FlightRecorder:
                 "ph": "M", "name": "thread_name", "pid": 1, "tid": tid,
                 "args": {"name": plane},
             })
-            for sid, start_ns, dur_ns, batch, gen in ring.snapshot():
+            for sid, start_ns, dur_ns, batch, gen, seq in ring.snapshot():
                 events.append({
                     "name": (STAGES[sid] if 0 <= sid < len(STAGES)
                              else f"stage{sid}"),
                     "cat": plane, "ph": "X", "pid": 1, "tid": tid,
                     "ts": start_ns / 1e3,      # trace-event µs
                     "dur": dur_ns / 1e3,
-                    "args": {"batch": batch, "gen": gen},
+                    "args": {"batch": batch, "gen": gen, "seq": seq},
                 })
         # metadata events (ph M) first, then slices in ts order — the
         # chaos tests assert the ordering, and Perfetto renders faster
@@ -143,6 +156,9 @@ class FlightRecorder:
             "reason": reason,
             "note": note,
             "wall_time": time.time(),
+            # the events' clock beside the wall clock, read together
+            "clock": {"perf_counter_ns": time.perf_counter_ns(),
+                      "time_ns": time.time_ns()},
         }
 
     def dump(self, reason: str, note: Optional[str] = None) -> Optional[str]:

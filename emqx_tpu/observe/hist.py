@@ -22,43 +22,85 @@ definition:
   like ``METRIC_NAMES``: a typo'd name raises at the cold lookup site,
   never silently records into nowhere).
 
-The stage names map the serve path end to end (see README §span map):
+The stage names map the serve path end to end (see README §span map).
+Per publish, on the connection's loop (histogram only, no ring event):
 
-========================  ==================================================
+============================  ==============================================
 ``obs.stage.ingest_parse``    one ``Parser.feed`` call per transport read
+``obs.stage.ingest_queue``    parsed PUBLISH queued for the connection's
+                              worker → the worker has it (intercept mode)
+``obs.stage.intercept``       the async pre-``handle_in`` stage of one
+                              PUBLISH (match prefetch, async authz,
+                              exhook); contains match_wait, the rest of
+                              the batch's cycle and match_resume
+``obs.stage.match_resume``    the batch's hints minted → the waiter's
+                              ``prefetch`` runs again (device-answered
+                              waiters only)
+``obs.stage.handle_publish``  ``channel.handle_in`` + actions + flush of
+                              one PUBLISH; parent of deliver and flush
+``obs.stage.deliver``         ``Session.deliver`` of one publish's routes
+                              (sync path); on the fanout path stage 4,
+                              grouped per chunk, with a ring event
+``obs.stage.flush``           ``emit`` of one publish (sync path); on the
+                              fanout path stage 5, coalesced per chunk,
+                              with a ring event
+============================  ==============================================
+
+Per batch (histogram + one flight-recorder ring event; the events of
+one popped batch share its ``seq``):
+
+============================  ==============================================
 ``obs.stage.fanout_queue``    fanout-batch queue wait (oldest message, per
                               batch pop)
-``obs.stage.match_wait``      prefetch waiter enqueue → serve-loop dispatch
+``obs.stage.match_wait``      prefetch waiter enqueue → its batch is popped
+                              (per waiter; the queue seen from the waiter:
+                              OVERLAPS match_window and the previous cycle,
+                              never add it to the cycle's stages)
+``obs.stage.match_cycle``     serve loop woken with work → hints minted (or
+                              the failure path resolved the waiters); the
+                              parent of the seven stages below, which tile
+                              it end to end
+``obs.stage.match_window``    the same start → the batch is popped (the
+                              batching window's sleep and its overshoot)
+``obs.stage.match_hop_out``   ``asyncio.to_thread`` called → first line of
+                              the worker; two a batch
+``obs.stage.match_hop_back``  worker's last line → the loop runs again; two
+                              a batch (the first holds the readback chaos
+                              gate)
 ``obs.stage.match_encode``    ``encode_batch`` per depth group (worker
                               thread)
 ``obs.stage.match_dispatch``  kernel dispatch per depth group (worker
                               thread)
 ``obs.stage.match_readback``  d2h readback per batch (worker thread /
                               readback child)
-``obs.stage.deliver``         fanout stage 4 — grouped ``Session.deliver``
-                              per chunk
-``obs.stage.flush``           fanout stage 5 — coalesced ``emit`` per chunk
-``obs.e2e.publish_deliver``   publish timestamp → delivery (sampled once
-                              per session per chunk on the batched path;
-                              per-leg via SlowSubs when enabled)
-``obs.e2e.publish_deliver_leg``  per-LEG publish→deliver variant, every
-                              Nth delivery leg (the per-subscriber skew
-                              signal; ``obs.hist.e2e_per_leg_sample``,
-                              0 = off and the site is zero-call)
-========================  ==================================================
+``obs.stage.match_epilogue``  rows stitched, hints minted, cache evicted
+                              (loop)
+============================  ==============================================
+
+``obs.e2e.publish_deliver`` is publish timestamp → delivery (sampled
+once per session per chunk on the batched path; per-leg via SlowSubs
+when enabled); ``obs.e2e.publish_deliver_leg`` the per-LEG variant,
+every Nth delivery leg (``obs.hist.e2e_per_leg_sample``, 0 = off and
+the site is zero-call).  The four synchronous match stages (encode,
+dispatch, readback, epilogue) are also written into a running
+``jax.profiler`` trace as ``emqx.match.<stage>`` annotations carrying
+``seq``, ``n`` and ``t_ns`` (the span's own ``perf_counter_ns`` start).
 
 **Zero cost when off** (the ``_injector is None`` idiom): recording
-sites hold a direct histogram reference that is ``None`` when
-``obs.hist.enable`` is off — the hot path pays one attribute load and
-an identity test, no function call (spy-asserted in
-tests/test_observe.py).
+sites hold a direct handle (the histogram where the stage is per
+publish, a :class:`~emqx_tpu.observe.span.Span` over the histogram and
+a ring where it is per batch) that is ``None`` when ``obs.hist.enable``
+is off and the site feeds no ring — the hot path pays one attribute
+load and an identity test, no function call (spy-asserted in
+tests/test_observe.py and tests/test_stage_spans.py).
 """
 
 from __future__ import annotations
 
 from typing import Dict, Iterable, List, Optional
 
-__all__ = ["LatencyHistogram", "HistSet", "HIST_NAMES"]
+__all__ = ["LatencyHistogram", "HistSet", "HIST_NAMES",
+           "SUB_BITS", "N_BUCKETS", "bucket_bounds"]
 
 #: the fixed histogram registry — additions only, drift-checked by the
 #: staticcheck ``registry-drift`` rule against literal ``.hist("...")``
@@ -74,6 +116,15 @@ HIST_NAMES: List[str] = [
     "obs.stage.flush",
     "obs.e2e.publish_deliver",
     "obs.e2e.publish_deliver_leg",
+    "obs.stage.match_cycle",
+    "obs.stage.match_window",
+    "obs.stage.match_hop_out",
+    "obs.stage.match_hop_back",
+    "obs.stage.match_epilogue",
+    "obs.stage.match_resume",
+    "obs.stage.ingest_queue",
+    "obs.stage.intercept",
+    "obs.stage.handle_publish",
 ]
 
 # -- bucket geometry --------------------------------------------------------
@@ -85,6 +136,11 @@ _SUB_BITS = 4
 _SUB = 1 << _SUB_BITS                       # 16
 _MAX_EXP = 45
 _N_BUCKETS = (_MAX_EXP - _SUB_BITS + 1) * _SUB + _SUB   # 688
+#: the layout under public names: whoever computes a percentile from a
+#: snapshot of ``counts`` outside this module (a delta of two snapshots,
+#: say) is held to it by tests/test_observe.py
+SUB_BITS = _SUB_BITS
+N_BUCKETS = _N_BUCKETS
 
 
 def _bucket_of(ns: int) -> int:
@@ -95,7 +151,7 @@ def _bucket_of(ns: int) -> int:
     return idx if idx < _N_BUCKETS else _N_BUCKETS - 1
 
 
-def _bucket_bounds(idx: int) -> tuple:
+def bucket_bounds(idx: int) -> tuple:
     """(lower, width) in ns of bucket ``idx`` — the inverse of
     :func:`_bucket_of` up to sub-bucket resolution."""
     if idx < _SUB:
@@ -124,7 +180,16 @@ class LatencyHistogram:
     # -- write side (single writer) ------------------------------------
 
     def record(self, dur_ns: int) -> None:
-        self.counts[_bucket_of(dur_ns)] += 1
+        # _bucket_of, inlined: this is every span's hot path, a dozen
+        # times a publish, and a call costs as much as the arithmetic
+        if dur_ns < _SUB:
+            idx = dur_ns if dur_ns >= 0 else 0
+        else:
+            k = dur_ns.bit_length() - 1 - _SUB_BITS
+            idx = (k << _SUB_BITS) + (dur_ns >> k)
+            if idx >= _N_BUCKETS:
+                idx = _N_BUCKETS - 1
+        self.counts[idx] += 1
 
     def record_s(self, dur_s: float) -> None:
         """Seconds-flavored :meth:`record` for wall-clock deltas."""
@@ -192,11 +257,11 @@ class LatencyHistogram:
             if not c:
                 continue
             if cum + c > rank:
-                lower, width = _bucket_bounds(idx)
+                lower, width = bucket_bounds(idx)
                 frac = (rank - cum + 0.5) / c
                 return lower + width * min(max(frac, 0.0), 1.0)
             cum += c
-        lower, width = _bucket_bounds(_N_BUCKETS - 1)  # pragma: no cover
+        lower, width = bucket_bounds(_N_BUCKETS - 1)  # pragma: no cover
         return float(lower + width)
 
     def percentile_ms(self, q: float) -> float:
@@ -205,7 +270,7 @@ class LatencyHistogram:
     def max_ms(self) -> float:
         for idx in range(_N_BUCKETS - 1, -1, -1):
             if self.counts[idx]:
-                lower, width = _bucket_bounds(idx)
+                lower, width = bucket_bounds(idx)
                 return (lower + width) / 1e6
         return 0.0
 

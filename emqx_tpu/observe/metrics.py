@@ -168,6 +168,12 @@ MATCH_SERVE_METRIC_NAMES: List[str] = [
     "broker.match.brownout_level", "broker.match.pipeline_inflight",
     "tpu.match.readback_bytes", "tpu.match.readback_roundtrips",
     "tpu.match.backend_join_dispatches", "tpu.match.autotune_picks",
+    # the serial serve paths' books on a batch (inc, by amount, once a
+    # cycle): cycle_ns sums the obs.stage.match_cycle spans,
+    # cycle_spanned_ns what of them lay inside a stage span (window,
+    # hops, encode, dispatch, readback, epilogue) — the ratio guards
+    # against a new unspanned hole in the cycle
+    "tpu.match.cycle_ns", "tpu.match.cycle_spanned_ns",
 ]
 
 # -- multichip serve backend (parallel/multichip_serve.py, opt-in via

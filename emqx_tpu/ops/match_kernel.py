@@ -273,69 +273,80 @@ def nfa_walk(
     active = jnp.zeros((B, 1), jnp.int32)                  # {root}
     accept_cols = []
     spills = []
+    # Phases carry ``jax.named_scope`` names (metadata only: the compiled
+    # program is the same) so that a profiler trace's device ops can be
+    # summed by phase: ``nfa.level<t>`` with ``node_gather``,
+    # ``edge_lookup`` and ``topk`` inside it, then ``nfa.epilogue``.
     for t in range(D + 1):
-        valid = active >= 0
-        sa = jnp.maximum(active, 0)        # safe gather index
-        node = node_tab[sa]                # (B, w_t, 4) wide gather
-        plus_child = node[..., 0]
-        hash_accept = node[..., 1]
-        end_accept = node[..., 2]
+        with jax.named_scope(f"nfa.level{t}"):
+            valid = active >= 0
+            sa = jnp.maximum(active, 0)        # safe gather index
+            with jax.named_scope("node_gather"):
+                node = node_tab[sa]            # (B, w_t, 4) wide gather
+            plus_child = node[..., 0]
+            hash_accept = node[..., 1]
+            end_accept = node[..., 2]
 
-        # --- fire accepts -------------------------------------------------
-        hacc = jnp.where(valid, hash_accept, -1)
-        if t == 0:
-            # root-level wildcard suppression for $-topics (active == {root})
-            hacc = jnp.where(is_sys[:, None], -1, hacc)
-        at_end = (t == lens)[:, None]
-        eacc = jnp.where(valid & at_end, end_accept, -1)
-        accept_cols.append(jnp.concatenate([hacc, eacc], axis=1))
+            # --- fire accepts ---------------------------------------------
+            hacc = jnp.where(valid, hash_accept, -1)
+            if t == 0:
+                # root-level wildcard suppression for $-topics
+                # (active == {root})
+                hacc = jnp.where(is_sys[:, None], -1, hacc)
+            at_end = (t == lens)[:, None]
+            eacc = jnp.where(valid & at_end, end_accept, -1)
+            accept_cols.append(jnp.concatenate([hacc, eacc], axis=1))
 
-        if t == D:
-            break
+            if t == D:
+                break
 
-        # --- transition ---------------------------------------------------
-        w = jnp.broadcast_to(words[:, t][:, None], active.shape)
-        lit = edge_lookup(active, w)
-        lit = jnp.where(valid, lit, -1)
-        plus = jnp.where(valid, plus_child, -1)
-        if t == 0:
-            plus = jnp.where(is_sys[:, None], -1, plus)
-        cand = jnp.concatenate([lit, plus], axis=1)        # (B, 2·w_t)
-        cand = jnp.where((t < lens)[:, None], cand, -1)
-        w_next = min(cand.shape[1], A)
-        if cand.shape[1] <= A:
-            active = cand                  # lossless: no compaction needed
+            # --- transition -----------------------------------------------
+            w = jnp.broadcast_to(words[:, t][:, None], active.shape)
+            with jax.named_scope("edge_lookup"):
+                lit = edge_lookup(active, w)
+            lit = jnp.where(valid, lit, -1)
+            plus = jnp.where(valid, plus_child, -1)
+            if t == 0:
+                plus = jnp.where(is_sys[:, None], -1, plus)
+            cand = jnp.concatenate([lit, plus], axis=1)    # (B, 2·w_t)
+            cand = jnp.where((t < lens)[:, None], cand, -1)
+            w_next = min(cand.shape[1], A)
+            if cand.shape[1] <= A:
+                active = cand              # lossless: no compaction needed
+            else:
+                with jax.named_scope("topk"):
+                    active, _ = jax.lax.top_k(cand, w_next)  # valids first
+                n_cand = jnp.sum((cand >= 0).astype(jnp.int32), axis=1)
+                n_kept = jnp.sum((active >= 0).astype(jnp.int32), axis=1)
+                spills.append(n_cand - n_kept)             # (B,) per row
+
+    with jax.named_scope("nfa.epilogue"):
+        flat = jnp.concatenate(accept_cols, axis=1)        # (B, Σ 2·w_t)
+        n = jnp.sum((flat >= 0).astype(jnp.int32), axis=1)
+        aover = (
+            jnp.sum(jnp.stack(spills), axis=0) if spills
+            else jnp.zeros((B,), jnp.int32)
+        )
+        row_meta = None
+        if flat_cap:
+            # flat mode: the fused compaction epilogue — readback shrinks
+            # from B·K·4 bytes to ~avg_fanout·4 bytes per topic: d2h is
+            # the slower direction of the host link, and every byte of it
+            # sits on the serving path.
+            matches, mover, row_meta = flat_epilogue(
+                flat, n, aover, K, flat_cap)
+        elif compact_output:
+            matches = _compact(flat, K)                    # valids first
+            mover = (n > K).astype(jnp.int32)
         else:
-            active, _ = jax.lax.top_k(cand, w_next)        # valids first
-            n_cand = jnp.sum((cand >= 0).astype(jnp.int32), axis=1)
-            n_kept = jnp.sum((active >= 0).astype(jnp.int32), axis=1)
-            spills.append(n_cand - n_kept)                 # (B,) per row
-
-    flat = jnp.concatenate(accept_cols, axis=1)            # (B, Σ 2·w_t)
-    n = jnp.sum((flat >= 0).astype(jnp.int32), axis=1)
-    aover = (
-        jnp.sum(jnp.stack(spills), axis=0) if spills
-        else jnp.zeros((B,), jnp.int32)
-    )
-    row_meta = None
-    if flat_cap:
-        # flat mode: the fused compaction epilogue — readback shrinks
-        # from B·K·4 bytes to ~avg_fanout·4 bytes per topic: d2h is
-        # the slower direction of the host link, and every byte of it
-        # sits on the serving path.
-        matches, mover, row_meta = flat_epilogue(
-            flat, n, aover, K, flat_cap)
-    elif compact_output:
-        matches = _compact(flat, K)                        # valids first
-        mover = (n > K).astype(jnp.int32)
-    else:
-        # raw mode: all Σ2·w_t accept slots, valids scattered (-1 holes).
-        # Structurally nothing truncates (the walk cannot fire more
-        # accepts than it has slots), so only active-set spill remains a
-        # fail-open cause — the right mode for high-fan-out tables where
-        # a fixed K would overflow (hosts mask row >= 0 to decode).
-        matches = flat
-        mover = jnp.zeros((B,), jnp.int32)
+            # raw mode: all Σ2·w_t accept slots, valids scattered (-1
+            # holes).  Structurally nothing truncates (the walk cannot
+            # fire more accepts than it has slots), so only active-set
+            # spill remains a fail-open cause — the right mode for
+            # high-fan-out tables where a fixed K would overflow (hosts
+            # mask row >= 0 to decode).
+            matches = flat
+            mover = jnp.zeros((B,), jnp.int32)
     return MatchResult(
         matches=matches,
         n_matches=n,

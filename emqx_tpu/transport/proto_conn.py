@@ -51,11 +51,19 @@ class MqttProtocol(asyncio.Protocol):
     # HIGH and resumes once the worker drains below LOW
     QUEUE_HIGH_WATER = 256
     QUEUE_LOW_WATER = 64
-    # ingest_parse stage histogram (observe/hist.py): the node's
-    # factory points this at its plane's histogram (shard conns get
-    # their shard's instance — each is written only by its own loop);
-    # None keeps the parse path at zero recording calls
+    # per-publish stage histograms (observe/hist.py; these stages feed
+    # no ring, so the handle is the histogram itself): the node's
+    # factory points them at its plane's instances (shard conns get
+    # their shard's ingest_parse — each is written only by its own
+    # loop); None keeps a site at one identity test.  ingest_parse: one
+    # Parser.feed per transport read.  The other three are the
+    # intercept-mode worker's, PUBLISH only: the packet's wait in the
+    # ordered queue, the async advisory stage, and the handle_in +
+    # actions + flush that follow it.
     _h_parse = None
+    _h_queue = None
+    _h_intercept = None
+    _h_handle = None
 
     def __init__(
         self,
@@ -162,11 +170,18 @@ class MqttProtocol(asyncio.Protocol):
         except F.FrameError as e:
             self._frame_error(e)
             return
+        t1 = 0
         if h_parse is not None:
             # one record per transport read: wire bytes → packet objects
-            h_parse.record(time.perf_counter_ns() - t0)
+            t1 = time.perf_counter_ns()
+            h_parse.record(t1 - t0)
         if self._queue is not None:
+            stamp = self._h_queue is not None
             for pkt in pkts:
+                if stamp and pkt.type == P.PUBLISH:
+                    # ingest_queue starts where the parse ended; the
+                    # stamp rides the packet to the worker
+                    pkt._queued_ns = t1 or time.perf_counter_ns()
                 self._queue.put_nowait(pkt)
             # backpressure the SOCKET, not just the worker: while the
             # async advisory stage is slow, unread bytes must park in
@@ -361,6 +376,11 @@ class MqttProtocol(asyncio.Protocol):
     async def _worker_loop(self) -> None:
         while not self._closed:
             pkt = await self._queue.get()
+            is_pub = pkt.type == P.PUBLISH
+            if is_pub and self._h_queue is not None:
+                t_q = getattr(pkt, "_queued_ns", 0)
+                if t_q:
+                    self._h_queue.record(time.perf_counter_ns() - t_q)
             if self._paused_read_queue \
                     and self._queue.qsize() <= self.QUEUE_LOW_WATER:
                 self._paused_read_queue = False
@@ -379,10 +399,16 @@ class MqttProtocol(asyncio.Protocol):
                     ok, wait = self._msg_bucket.consume(1.0)
                     if not ok:
                         await asyncio.sleep(wait)
+                t_h = 0     # handle_publish starts where intercept ended
                 if self.intercept is not None and pkt.type in (
                     P.CONNECT, P.PUBLISH, P.SUBSCRIBE, P.UNSUBSCRIBE
                 ):
+                    h = self._h_intercept if is_pub else None
+                    t0 = time.perf_counter_ns() if h is not None else 0
                     actions = await self.intercept(self.channel, pkt)
+                    if h is not None:
+                        t_h = time.perf_counter_ns()
+                        h.record(t_h - t0)
                     if self._closed or self.channel.state == "disconnected":
                         return
                     if actions is not None:
@@ -393,11 +419,16 @@ class MqttProtocol(asyncio.Protocol):
                         finally:
                             self._flush_writes()
                         continue
+                h = self._h_handle if is_pub else None
+                if h is not None and not t_h:
+                    t_h = time.perf_counter_ns()
                 self._batching = self.coalesce
                 try:
                     self._run_actions(self.channel.handle_in(pkt))
                 finally:
                     self._flush_writes()
+                if h is not None:
+                    h.record(time.perf_counter_ns() - t_h)
             except asyncio.CancelledError:
                 return  # connection closing: the worker exits with it
             except Exception:

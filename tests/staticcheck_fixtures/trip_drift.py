@@ -1,4 +1,4 @@
-"""Must TRIP registry-drift on all eight surfaces (checked against the
+"""Must TRIP registry-drift on all nine surfaces (checked against the
 real registries in observe/metrics.py / config.py / faultinject.py /
 broker/hooks.py / observe/hist.py / observe/flightrec.py)."""
 
@@ -19,3 +19,4 @@ def g(hooks):
 def h(hists, flightrec):
     hists.hist("obs.stage.not_a_real_stage")
     flightrec.dump("not_a_declared_reason")
+    stage_span("not_a_real_stage", hists, flightrec.ring("x"))

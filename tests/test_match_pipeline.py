@@ -247,7 +247,7 @@ def test_inflight_slot_swap_or_reuse_discards_via_guards(mutate):
         slot = (pending, topics, groups, handles, ms.inc, ms.dev,
                 ms.inc.aid_reuses, ms._table_gen, ms._synced_epoch,
                 ms._synced_rule_gen, loop.time(), True,
-                enc_ns + disp_ns)
+                enc_ns + disp_ns, None)
         # the swap/reuse lands while the slot is in flight
         if mutate == "gen":
             ms._table_gen += 1
@@ -574,7 +574,7 @@ def test_midflight_swap_discards_ragged_slot():
         slot = (pending, topics, groups, handles, ms.inc, ms.dev,
                 ms.inc.aid_reuses, ms._table_gen, ms._synced_epoch,
                 ms._synced_rule_gen, loop.time(), True,
-                enc_ns + disp_ns)
+                enc_ns + disp_ns, None)
         ms._table_gen += 1          # the swap lands mid-flight
         await ms._finish_slot(slot)
         for _t, fut, _d in pending:

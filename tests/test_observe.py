@@ -633,10 +633,10 @@ def test_obs_hist_disable_wires_none_everywhere():
             assert node.hist_sets() == []
             assert node.hist_percentiles() == {}
             fp = node.fanout_pipeline
-            assert fp._h_queue is None and fp._h_e2e is None
+            assert fp._sp_queue is None and fp._h_e2e is None
             ms = node.match_service
             if ms is not None:   # device may be absent on CI
-                assert ms._h_wait is None and ms._h_encode is None
+                assert ms._h_wait is None and ms._h_resume is None
             # the flight recorder stays ALWAYS on regardless
             assert node.flightrec is not None
             assert node.supervisor.flightrec is node.flightrec
@@ -660,7 +660,7 @@ def test_obs_hist_enabled_by_default_and_wired():
         await node.start()
         try:
             assert node.hists is not None
-            assert node.fanout_pipeline._h_queue is not None
+            assert node.fanout_pipeline._sp_queue is not None
             pct = node.hist_percentiles()
             from emqx_tpu.observe.hist import HIST_NAMES
             assert set(pct) == set(HIST_NAMES)

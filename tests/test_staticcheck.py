@@ -135,7 +135,7 @@ def test_waiver_file_has_no_silent_suppressions():
     ("no-swallowed-exceptions", "trip_exceptions.py",
      "ok_exceptions.py", 3),
     ("await-under-lock", "trip_locks.py", "ok_locks.py", 3),
-    ("registry-drift", "trip_drift.py", "ok_drift.py", 9),
+    ("registry-drift", "trip_drift.py", "ok_drift.py", 10),
     ("unawaited-coroutine", "trip_coroutines.py", "ok_coroutines.py", 3),
     # device-plane dataflow rules (ISSUE 19): reuse after a donated
     # dispatch trips (rebind/result-only/branch-dispatch pass), a
@@ -265,6 +265,7 @@ def test_registries_extract_from_tree():
     assert "obs.e2e.publish_deliver" in reg.hist_names
     assert "breaker_trip" in reg.dump_reasons
     assert "supervisor_degraded" in reg.dump_reasons
+    assert "match_cycle" in reg.stage_names
     assert "obs.flightrec.dumps" in reg.metric_names
     assert "obs.hist.enable" in reg.config_keys
 
@@ -286,6 +287,10 @@ def test_registries_match_runtime_tables():
     from emqx_tpu.observe.hist import HIST_NAMES
     assert reg.hist_names == set(HIST_NAMES)
     assert reg.dump_reasons == set(DUMP_REASONS)
+    # observe/span.py resolves both sinks from a stage's one name
+    from emqx_tpu.observe.flightrec import STAGES
+    assert reg.stage_names == set(STAGES)
+    assert {f"obs.stage.{s}" for s in STAGES} <= set(HIST_NAMES)
 
 
 # ---------------------------------------------------------------------------
