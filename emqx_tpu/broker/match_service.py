@@ -1026,6 +1026,8 @@ class MatchService:
         # (fresh operands each pass — donation consumes them).  Under
         # backend routing every family auto can pick must warm, or the
         # first re-routed batch stalls exactly like an unwarmed shape.
+        # The serial path's one-output twin is a third executable: warm
+        # what the serve path will ask for (``packed``).
         donates = (False, True) if self.pipeline else (False,)
         backends = (("hash", "join") if self.backend == "auto"
                     else (self.backend,))
@@ -1034,7 +1036,8 @@ class MatchService:
                 words, lens, is_sys = encode_batch(self.inc, [], batch=64)
                 self.dev.match(words, lens, is_sys,
                                flat_cap=self.FLAT_MULT * 64,
-                               donate_inputs=donate, backend=be)
+                               donate_inputs=donate, backend=be,
+                               packed=self._packed_serve)
                 if self.short_depth and self.short_depth < self.depth:
                     # pre-pay the short-depth kernel shape too, or the
                     # first split batch stalls the loop on an XLA compile
@@ -1042,7 +1045,8 @@ class MatchService:
                                             depth=self.short_depth)
                     self.dev.match(w, l, sy,
                                    flat_cap=self.FLAT_MULT * 64,
-                                   donate_inputs=donate, backend=be)
+                                   donate_inputs=donate, backend=be,
+                                   packed=self._packed_serve)
 
     # ------------------------------------------------------------------
     # multichip serve backend (opt-in, match.multichip.enable)
@@ -1811,28 +1815,65 @@ class MatchService:
     # fan-out tail
     from ..ops.match_kernel import SERVE_FLAT_MULT as FLAT_MULT
 
+    @property
+    def _packed_serve(self) -> bool:
+        """The serial slab readback is what reads this service's
+        dispatches (no pipeline, ``match.readback.mode`` chunked): it
+        needs the whole answer and nothing else, so they ask the device
+        table for the one-output program."""
+        return not self.pipeline and self.readback_mode == "chunked"
+
     def _device_rows(self, enc, n: int):
         B = enc[0].shape[0]
-        res = self.dev.match(*enc, flat_cap=self.FLAT_MULT * B)
+        res = self.dev.match(*enc, flat_cap=self.FLAT_MULT * B,
+                             packed=self._packed_serve)
         return self._readback_rows(res, n, self.dev.max_matches)
 
     @staticmethod
     def _readback_rows(res, n: int, k: int):
+        """The serial readback's d2h: ``(rows, spilled row indices)`` of
+        the batch's first ``n`` rows, from either answer
+        ``DeviceNfa.match`` gives: the packed array (``row_meta`` then
+        the flat ids, ``flat_cap`` = FLAT_MULT·B: ONE buffer to fetch)
+        or a ``MatchResult`` (four).  :meth:`_readback_cost` says which
+        was paid."""
         import jax
 
-        from ..ops.match_kernel import decode_flat
-
-        # fetch the kernel's own outputs and OR the spill flags on host:
-        # res.spilled_rows() would build NEW lazy device ops here, i.e.
-        # an extra dispatch round trip per batch on the readback path
-        matches, counts, aover, mover = jax.device_get(
-            (res.matches, res.n_matches, res.active_overflow,
-             res.match_overflow)
+        from ..ops.match_kernel import (
+            MatchResult, decode_flat, decode_row_meta,
         )
-        sp = (aover > 0) | (mover > 0)
-        rows = [seg.tolist()
-                for seg in decode_flat(matches, counts, k)[:n]]
+
+        if not isinstance(res, MatchResult):
+            packed = jax.device_get(res)
+            B = packed.size // (1 + MatchService.FLAT_MULT)
+            # row_meta's count is min(n, K) and its flag is
+            # active_overflow | match_overflow: what the four-array
+            # decode below computes on the host
+            nk, sp = decode_row_meta(packed[:B])
+            matches = packed[B:]
+        else:
+            # fetch the kernel's own outputs and OR the spill flags on
+            # host: res.spilled_rows() would build NEW lazy device ops
+            # here, i.e. an extra dispatch round trip per batch on the
+            # readback path
+            matches, nk, aover, mover = jax.device_get(
+                (res.matches, res.n_matches, res.active_overflow,
+                 res.match_overflow)
+            )
+            sp = (aover > 0) | (mover > 0)
+        rows = [seg.tolist() for seg in decode_flat(matches, nk, k)[:n]]
         return rows, np.flatnonzero(sp[:n]).tolist()
+
+    @staticmethod
+    def _readback_cost(res) -> Tuple[int, int]:
+        """``(d2h bytes, device buffers fetched)`` :meth:`_readback_rows`
+        pays for ``res``: the packed array, or the flat id buffer, the
+        counts and both overflow vectors."""
+        from ..ops.match_kernel import MatchResult
+
+        if not isinstance(res, MatchResult):
+            return 4 * int(res.size), 1
+        return 4 * int(res.matches.size + 3 * res.n_matches.size), 4
 
     @staticmethod
     def _readback_rows_twophase(res, n: int, k: int,
@@ -1945,7 +1986,8 @@ class MatchService:
                         # shape warms in the background) instead of
                         # stalling
                         block_compile=(dev.kernel_cache is None),
-                        donate_inputs=donate, backend=be)
+                        donate_inputs=donate, backend=be,
+                        packed=self._packed_serve)
             t2 = _now_ns()
             if be in ("join", "join-pallas") and self.metrics is not None:
                 # this worker is the single in-flight encode stage, so
@@ -1965,11 +2007,21 @@ class MatchService:
         return handles, enc_ns, disp_ns
 
     def _readback_groups(self, handles, dev, proportional, cyc=None):
-        """WORKER-THREAD stage: block on every group's d2h.  Serial
-        (flag-off) mode reads the full flat slab exactly as PR 10 did
-        unless ``match.readback.mode`` asks for the ragged contract;
-        ``proportional`` (pipeline mode) rides the two-phase contract
-        in the configured transfer shape.  Returns ``([(rows,
+        """WORKER-THREAD stage: block on every group's d2h.  What each
+        mode ships, in how many device buffers (= d2h transfers; the
+        copies one ``device_get`` call names start together, so on the
+        attached chip ready buffers cost 0.46 ms for one and 0.54 ms
+        for four, not four latencies: PERF.md §6, PR 31): serial
+        (flag-off) mode the whole flat slab, as the one-output
+        program's packed array (4·(B + flat_cap) bytes, 1 buffer) or,
+        from a ``MatchResult`` (kernel cache, Pallas walk), as four of
+        its fields (4·(flat_cap + 3·B) bytes, 4 buffers), unless
+        ``match.readback.mode`` asks for the ragged contract;
+        ``proportional`` (pipeline mode) the two-phase contract in the
+        configured transfer shape (``row_meta``, then 4·Σcounts bytes
+        in popcount(Σcounts) chunks, or ≤ 1 padded chunk ragged, each
+        a dispatch and a fetch of its own); the mesh its dense compact
+        rows in one call.  Returns ``([(rows,
         spilled)...], total d2h bytes, readback ns, d2h round
         trips)``.  ``cyc`` as in :meth:`_encode_dispatch`."""
         t0 = _now_ns()
@@ -1995,11 +2047,7 @@ class MatchService:
                         auto_slack=self.readback_auto_slack)
                 else:
                     rows, sp = self._readback_rows(res, n, dev.max_matches)
-                    # the slab cost: the flat id buffer + counts and
-                    # both overflow vectors (what device_get above
-                    # shipped) in one round trip
-                    b = 4 * int(res.matches.size + 3 * res.n_matches.size)
-                    t = 1
+                    b, t = self._readback_cost(res)
                 nbytes += b
                 trips += t
                 out.append((rows, sp))
@@ -2866,8 +2914,7 @@ class MatchService:
             mc.readback(res, 1)
             return
         enc = encode_batch(self.inc, ["probe/health"], batch=64)
-        res = self.dev.match(*enc, flat_cap=self.FLAT_MULT * 64)
-        self._readback_rows(res, 1, self.dev.max_matches)
+        self._device_rows(enc, 1)
 
     def info(self) -> dict:
         return {

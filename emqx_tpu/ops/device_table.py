@@ -23,14 +23,16 @@ from __future__ import annotations
 
 import threading
 from functools import partial
-from typing import NamedTuple, Optional, Sequence, Tuple
+from typing import NamedTuple, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .incremental import IncrementalNfa, NfaDelta
-from .match_kernel import MatchResult, nfa_match, nfa_match_donated
+from .match_kernel import (
+    MatchResult, nfa_match, nfa_match_donated, nfa_match_packed,
+)
 
 __all__ = ["DeviceNfa", "PendingSync", "SCATTER_CHUNK"]
 
@@ -460,7 +462,8 @@ class DeviceNfa:
     def match(self, words, lens, is_sys, *,
               flat_cap: int = 0, block_compile: bool = True,
               donate_inputs: bool = False,
-              backend: Optional[str] = None) -> MatchResult:
+              backend: Optional[str] = None,
+              packed: bool = False) -> Union[MatchResult, jax.Array]:
         """Run the kernel on already-encoded operands.  Dispatch happens
         under the device lock; the returned arrays are futures — callers
         block (np.asarray) outside any lock.  ``flat_cap`` > 0 selects
@@ -478,7 +481,15 @@ class DeviceNfa:
         mirrored — both kernels answer identically; "join-pallas" walks
         the same relation with the fused Pallas kernel and falls back
         to "join" when the shape doesn't fit its tiling contract —
-        flat output only, batch a multiple of its tile)."""
+        flat output only, batch a multiple of its tile).  ``packed``
+        (flat mode, operands not donated) asks for the whole answer as
+        ONE ``(B + flat_cap,)`` array, ``row_meta`` then the flat ids
+        (:func:`~emqx_tpu.ops.match_kernel.packed_twin`), from a
+        program with that one output; the hash and join kernels have
+        such a twin, and where the call goes through a kernel cache or
+        the Pallas walk it returns the :class:`MatchResult` as ever, so
+        the caller reads what it was given."""
+        packed = packed and flat_cap > 0 and not donate_inputs
         with self._lock:
             node, edge, seeds = self.arrays()
             be = backend or "hash"
@@ -520,9 +531,12 @@ class DeviceNfa:
                     interpret=(jax.default_backend() != "tpu"),
                 )
             if be == "join":
-                from .join_match import join_match, join_match_donated
+                from .join_match import (
+                    join_match, join_match_donated, join_match_packed,
+                )
 
-                jfn = join_match_donated if donate_inputs else join_match
+                jfn = (join_match_packed if packed else
+                       join_match_donated if donate_inputs else join_match)
                 return jfn(
                     words, lens, is_sys, node, *self._jarrs,
                     active_slots=self.active_slots,
@@ -530,7 +544,8 @@ class DeviceNfa:
                     compact_output=self.compact_output,
                     flat_cap=flat_cap,
                 )
-            fn = nfa_match_donated if donate_inputs else nfa_match
+            fn = (nfa_match_packed if packed else
+                  nfa_match_donated if donate_inputs else nfa_match)
             return fn(
                 words, lens, is_sys, node, edge, seeds,
                 active_slots=self.active_slots,

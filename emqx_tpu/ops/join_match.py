@@ -78,7 +78,8 @@ from .compiler import BUCKET_SLOTS
 log = logging.getLogger(__name__)
 
 __all__ = ["OVERLAY_CAP", "OVERLAY_EMPTY", "JoinRelation", "OverlayFull",
-           "join_match", "join_match_donated", "relation_capacity",
+           "join_match", "join_match_donated", "join_match_packed",
+           "relation_capacity",
            "BackendAutotuner"]
 
 #: overlay rows available between rebuilds.  Small on purpose: the
@@ -205,10 +206,10 @@ def _join_match(
     )
 
 
-def _jit_pair():
+def _jit_twins():
     import jax
 
-    from .match_kernel import _MATCH_STATIC
+    from .match_kernel import _MATCH_STATIC, packed_twin
 
     statics = tuple(_MATCH_STATIC) + ("linear_overlay",)
     fn = jax.jit(_join_match, static_argnames=statics)
@@ -216,10 +217,12 @@ def _jit_pair():
     # (they serve every in-flight batch) — same contract as nfa_match
     fn_d = jax.jit(_join_match, static_argnames=statics,
                    donate_argnums=(0, 1, 2))
-    return fn, fn_d
+    # serial-readback twin: the flat answer as ONE array
+    fn_p = jax.jit(packed_twin(_join_match), static_argnames=statics)
+    return fn, fn_d, fn_p
 
 
-join_match, join_match_donated = _jit_pair()
+join_match, join_match_donated, join_match_packed = _jit_twins()
 
 
 # ---------------------------------------------------------------------------

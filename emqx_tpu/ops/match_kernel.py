@@ -48,7 +48,8 @@ from .compiler import BUCKET_SLOTS, NfaTable, encode_topics
 __all__ = ["MatchResult", "SERVE_FLAT_MULT", "build_matcher",
            "decode_flat", "decode_row_meta", "fetch_flat_prefix",
            "fetch_flat_ragged", "match_topics", "nfa_match",
-           "nfa_match_donated", "nfa_walk", "ragged_capacity"]
+           "nfa_match_donated", "nfa_match_packed", "nfa_walk",
+           "packed_twin", "ragged_capacity"]
 
 # serving flat-output capacity per padded batch row (ids/topic): shared
 # by every serving engine so the fan-out tuning cannot drift between
@@ -390,6 +391,30 @@ nfa_match = jax.jit(_nfa_match, static_argnames=_MATCH_STATIC)
 #: arrays are NOT donated: they serve every in-flight batch.
 nfa_match_donated = jax.jit(_nfa_match, static_argnames=_MATCH_STATIC,
                             donate_argnums=(0, 1, 2))
+
+
+def packed_twin(match):
+    """The twin of a flat-mode match function whose WHOLE answer is one
+    ``(B + flat_cap,)`` int32 array: ``row_meta`` (counts + fail-open
+    flags, :func:`decode_row_meta`) then the flat ids.  Not a sixth
+    output beside the five: on the attached chip every buffer a call
+    takes or returns costs 0.06–0.07 ms of its dispatch (the runtime
+    allocates each) and a little of its fetch, whatever its size, so
+    the serial slab readback, which needs all of the answer and nothing
+    else, asks for a program with ONE output (PERF.md §6, PR 31).  The
+    other fields are dead code to that program; XLA drops them."""
+    def packed(*operands, **static):
+        res = match(*operands, **static)
+        return jnp.concatenate([res.row_meta, res.matches])
+    # the XLA module is named after the function: a trace still finds
+    # the twin among the "nfa_match" / "join_match" programs
+    packed.__name__ = packed.__qualname__ = match.__name__ + "_packed"
+    return packed
+
+
+#: the serial serve path's entry point (flat mode only: ``flat_cap`` > 0)
+nfa_match_packed = jax.jit(packed_twin(_nfa_match),
+                           static_argnames=_MATCH_STATIC)
 
 # a donated operand whose shape no kernel output can alias degrades to
 # a plain argument; XLA warns once per compile, which is noise on the

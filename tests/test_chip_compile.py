@@ -86,6 +86,19 @@ def test_join_match_compiles_for_v5e(one_chip):
     _compile(_key(LANES[1], flat=True, backend="join"), one_chip)
 
 
+@pytest.mark.parametrize("depth", [LANES[1], LANES[0]])
+def test_packed_twin_compiles_for_v5e(one_chip, depth):
+    """What a default node's serial path dispatches since PR 31: the
+    same operands and statics, ONE (B + flat_cap,) output."""
+    from emqx_tpu.ops.match_kernel import nfa_match_packed
+
+    _fn, args, static = MatchKernelCache.lowering(
+        _key(depth, flat=True), sharding=one_chip)
+    compiled = nfa_match_packed.lower(*args, **static).compile()
+    assert compiled.memory_analysis().output_size_in_bytes == \
+        4 * (B + SERVE_FLAT_MULT * B)
+
+
 # Mosaic's verdict on the in-VMEM table gathers, taken 2026-09-26 with
 # jax 0.9.0 / libtpu 0.0.34.  strict: the day a repair lands, this says so.
 _MOSAIC = "Shape mismatch in input, indices and output"
