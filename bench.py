@@ -860,37 +860,29 @@ def _serve_flat_cap(batch):
     return _serve_flat_mult() * batch
 
 
-def _readback(r, k):
-    """Block on a flat-mode result; returns (ids-per-row, spilled rows).
-    ``k`` is the dispatching DeviceNfa's max_matches — decode offsets
-    must mirror the kernel's scatter offsets.  This is the FULL
-    consumer-side cost: transfer + decode.  The spill OR runs on host —
-    r.spilled_rows() would build NEW lazy device ops at readback time,
-    i.e. a fresh synchronous dispatch round trip per batch."""
-    from emqx_tpu.ops.match_kernel import decode_flat
+def _readback(r, n, k):
+    """Block on a served batch's packed answer; returns (ids-per-row,
+    spilled rows) of its first ``n`` rows.  ``k`` is the dispatching
+    DeviceNfa's max_matches.  This is the FULL consumer-side cost:
+    transfer + decode."""
+    from emqx_tpu.ops.match_kernel import decode_packed
 
-    m = np.asarray(r.matches)
-    n = np.asarray(r.n_matches)
-    sp = (np.asarray(r.active_overflow) > 0) | (
-        np.asarray(r.match_overflow) > 0)
-    return decode_flat(m, n, k), np.flatnonzero(sp)
+    rows, spilled = decode_packed(r, n, k)
+    return rows, np.asarray(spilled, dtype=np.int64)
 
 
 def _dispatch(dev, table, names, depth, batch):
-    """Encode + upload + enqueue one flat-mode batch; starts the async
-    device→host copies so readback overlaps later batches (d2h sits
+    """Encode + upload + enqueue one served batch; starts the async
+    device→host copy so readback overlaps later batches (d2h sits
     on the serving path)."""
     import jax.numpy as jnp
 
     w, l, s = _encode(table, names, depth, batch)
-    r = dev.match(jnp.asarray(w), jnp.asarray(l), jnp.asarray(s),
-                  flat_cap=_serve_flat_cap(batch))
-    for a in (r.matches, r.n_matches, r.active_overflow,
-              r.match_overflow):
-        try:
-            a.copy_to_host_async()
-        except Exception:  # noqa: BLE001 — platform without async d2h
-            break
+    r = dev.serve(jnp.asarray(w), jnp.asarray(l), jnp.asarray(s))
+    try:
+        r.copy_to_host_async()
+    except Exception:  # noqa: BLE001 — platform without async d2h
+        pass
     return r
 
 
@@ -898,7 +890,7 @@ def warm_serve(dev, table, topics, batch, depth):
     """Trigger the serving-mode jit compile OUTSIDE any timed section."""
     names = (topics[:batch] * (batch // max(1, len(topics[:batch])) + 1)
              )[:batch]
-    _readback(_dispatch(dev, table, names, depth, batch),
+    _readback(_dispatch(dev, table, names, depth, batch), batch,
               dev.max_matches)
 
 
@@ -931,10 +923,10 @@ def calibrate_serve(dev, table, topics, batch, depth=8,
             inflight.append(
                 _dispatch(dev, table, next_names(), depth, batch))
             if len(inflight) >= SERVE_INFLIGHT:
-                _readback(inflight.pop(0), dev.max_matches)
+                _readback(inflight.pop(0), batch, dev.max_matches)
                 done += batch
         for r in inflight:
-            _readback(r, dev.max_matches)
+            _readback(r, batch, dev.max_matches)
             done += batch
     else:
         t0 = time.perf_counter()
@@ -1125,7 +1117,7 @@ async def serve_harness(dev, table, topics, batch, target_rate,
             first, take, names, r, disp_t = item
             rb0 = time.perf_counter()
             ids, rows = await asyncio.to_thread(
-                _readback, r, dev.max_matches)
+                _readback, r, take, dev.max_matches)
             rb1 = time.perf_counter()
             est_r[0] = est_r[0] * 0.7 + (rb1 - rb0) * 0.3
             est_samples[0] += 1
@@ -1227,500 +1219,6 @@ def bench_serve_deadline_smoke(n_filters=2000, batch=256, seconds=1.5,
     rate = 0.6 * cap
     out = bench_serve_deadline(dev, table, topics, batch, rate, seconds,
                                deadline_ms, depth=depth)
-    out["table"] = kind
-    out["n_filters"] = len(filters)
-    return out
-
-
-# ---------------------------------------------------------------------------
-# overlapped serve pipeline A/B (ISSUE 11): serial encode→dispatch→
-# readback round trips vs the double-buffered chain with match-
-# proportional two-phase d2h, at EQUAL offered load
-# ---------------------------------------------------------------------------
-
-def _readback_twophase(r, n, k):
-    """Bench twin of MatchService._readback_rows_twophase: phase 1 the
-    packed (B,) row_meta, phase 2 exactly sum(counts) ids.  Returns
-    (rows, spilled, d2h bytes, raw counts total)."""
-    import jax
-
-    from emqx_tpu.ops.match_kernel import (
-        decode_row_meta, fetch_flat_prefix,
-    )
-
-    meta = jax.device_get(r.row_meta)
-    nk, sp = decode_row_meta(meta)
-    nk = np.minimum(nk, k)
-    total = int(nk[:n].sum())
-    ids = fetch_flat_prefix(r.matches, total)
-    offs = np.cumsum(nk[:n]) - nk[:n]
-    rows = [ids[o:o + c] for o, c in zip(offs, nk[:n])]
-    counts_raw = int(np.asarray(
-        jax.device_get(r.n_matches))[:n].sum())
-    return rows, np.flatnonzero(sp[:n]), 4 * (meta.size + total), \
-        counts_raw
-
-
-def _readback_ragged(r, n, k):
-    """Bench twin of the ragged single-transfer readback
-    (``match.readback.mode = ragged``): phase 1 the packed (B,)
-    row_meta, phase 2 ONE dynamic_slice padded to the pow2 capacity
-    class and trimmed on host.  Returns (rows, spilled, d2h bytes,
-    raw counts total, d2h round trips) — trips is the headline: 2
-    whenever anything matched, 1 when the meta says nothing did."""
-    import jax
-
-    from emqx_tpu.ops.match_kernel import (
-        decode_row_meta, fetch_flat_ragged, ragged_capacity,
-    )
-
-    meta = jax.device_get(r.row_meta)
-    nk, sp = decode_row_meta(meta)
-    nk = np.minimum(nk, k)
-    total = int(nk[:n].sum())
-    ids = fetch_flat_ragged(r.matches, total)
-    trips = 1 + (1 if total else 0)
-    nbytes = 4 * (meta.size
-                  + ragged_capacity(total, int(r.matches.shape[0])))
-    offs = np.cumsum(nk[:n]) - nk[:n]
-    rows = [ids[o:o + c] for o, c in zip(offs, nk[:n])]
-    counts_raw = int(np.asarray(
-        jax.device_get(r.n_matches))[:n].sum())
-    return rows, np.flatnonzero(sp[:n]), nbytes, counts_raw, trips
-
-
-def _hist_add(hist, key):
-    k = str(key)
-    hist[k] = hist.get(k, 0) + 1
-
-
-def _overlap_ms(iv, others):
-    """Wall-clock overlap of interval ``iv`` with a list of intervals —
-    the per-batch evidence that encode N+1 really ran while batch N was
-    in flight (serial mode measures ~0 by construction)."""
-    t0, t1 = iv
-    total = 0.0
-    for o0, o1 in others:
-        lo, hi = max(t0, o0), min(t1, o1)
-        if hi > lo:
-            total += hi - lo
-    return total * 1e3
-
-
-async def serve_pipeline_harness(dev, table, topics, batch, target_rate,
-                                 seconds, depth=8, window_s=0.0002,
-                                 pipelined=True, inflight=2):
-    """Open-loop serving run (same analytic arrival process as
-    serve_harness).  ``pipelined=False`` is the serial PR-10 shape: the
-    loop blocks on encode + dispatch + FULL-slab readback per batch.
-    ``pipelined=True`` is the ISSUE-11 chain: encode+dispatch (donated
-    operands) in a worker thread while up to ``inflight`` batches sit
-    past dispatch, readback two-phase and match-proportional.  The
-    result carries readback-bytes and stage-overlap histograms plus the
-    per-batch readback-bytes bound check."""
-    import jax.numpy as jnp
-
-    from emqx_tpu.observe.hist import LatencyHistogram
-
-    h_e2e = LatencyHistogram()
-    np_lats: List[np.ndarray] = []   # post-warmup parity subset
-    served = [0]
-    enc_iv: List[tuple] = []   # encode+dispatch wall intervals
-    rb_iv: List[tuple] = []    # readback wall intervals
-    rb_hist: dict = {}         # readback bytes per batch (histogram)
-    bytes_total = [0]
-    bound_ok = [True]
-    spill_reruns = [0]
-    n_topics = len(topics)
-    consumed = 0
-    k = dev.max_matches
-    slab_bytes = 4 * (_serve_flat_cap(batch) + 3 * batch)
-
-    def _dispatch_once(names, donate):
-        w, l, s = _encode(table, names, depth, batch)
-        return dev.match(jnp.asarray(w), jnp.asarray(l),
-                         jnp.asarray(s),
-                         flat_cap=_serve_flat_cap(batch),
-                         donate_inputs=donate)
-
-    # warm BOTH jit variants outside the timed window
-    _readback(_dispatch_once(topics[:batch], False), k)
-    if pipelined:
-        _readback_twophase(_dispatch_once(topics[:batch], True),
-                           batch, k)
-
-    q: asyncio.Queue = asyncio.Queue(maxsize=max(1, inflight - 1))
-    t0 = time.perf_counter()
-    stop_at = t0 + seconds
-    warm_at = t0 + seconds * 0.25   # hist/parity record post-ramp only
-
-    def next_batch(first):
-        return [topics[(first + j) % n_topics] for j in range(batch)]
-
-    async def batcher():
-        nonlocal consumed
-        while True:
-            now = time.perf_counter()
-            if now >= stop_at:
-                break
-            arrived = int((now - t0) * target_rate)
-            avail = arrived - consumed
-            oldest_age = (now - (t0 + consumed / target_rate)
-                          if avail > 0 else 0.0)
-            if avail <= 0 or (avail < batch and oldest_age < window_s):
-                await asyncio.sleep(window_s / 2)
-                continue
-            take = min(avail, batch)
-            first = consumed
-            consumed += take
-            names = next_batch(first)[:batch]
-            e0 = time.perf_counter()
-            if pipelined:
-                r = await asyncio.to_thread(_dispatch_once, names, True)
-                e1 = time.perf_counter()
-                enc_iv.append((e0, e1))
-                await q.put((first, take, names, r, e0))
-            else:
-                # serial: the flag-off product path — encode+dispatch
-                # and slab readback each ride a worker-thread hop, but
-                # the next batch waits for the WHOLE round trip (one in
-                # flight)
-                r = await asyncio.to_thread(_dispatch_once, names,
-                                            False)
-                e1 = time.perf_counter()
-                enc_iv.append((e0, e1))
-                rb0 = time.perf_counter()
-                rows, sp = await asyncio.to_thread(_readback, r, k)
-                rb1 = time.perf_counter()
-                rb_iv.append((rb0, rb1))
-                _finish(first, take, names, sp, slab_bytes, None)
-        await q.put(None)
-
-    def _finish(first, take, names, sp, nbytes, counts_raw):
-        sp = np.asarray(sp)
-        sp = sp[sp < take]
-        if len(sp):
-            spill_reruns[0] += len(sp)
-            for i in sp:
-                table.match_host(names[i])
-        bytes_total[0] += nbytes
-        _hist_add(rb_hist, nbytes)
-        if counts_raw is not None and nbytes > 4 * (batch + counts_raw):
-            bound_ok[0] = False
-        done_t = time.perf_counter()
-        arr_t = t0 + (first + np.arange(take)) / target_rate
-        lat_arr = done_t - arr_t
-        served[0] += len(lat_arr)
-        if done_t >= warm_at:
-            h_e2e.record_many_s(lat_arr)
-            np_lats.append(lat_arr)
-
-    async def collector():
-        while True:
-            item = await q.get()
-            if item is None:
-                return
-            first, take, names, r, _disp = item
-            rb0 = time.perf_counter()
-            rows, sp, nbytes, counts_raw = await asyncio.to_thread(
-                _readback_twophase, r, take, k)
-            rb1 = time.perf_counter()
-            rb_iv.append((rb0, rb1))
-            _finish(first, take, names, sp, nbytes, counts_raw)
-
-    if pipelined:
-        await asyncio.gather(batcher(), collector())
-    else:
-        await batcher()
-        q.get_nowait()   # drain the sentinel
-    if not served[0]:
-        return None
-    # stage overlap: ms of each encode interval spent while some
-    # readback was in flight — the pipelining evidence (serial ≈ 0)
-    ov_hist: dict = {}
-    for iv in enc_iv:
-        _hist_add(ov_hist, round(_overlap_ms(iv, rb_iv), 1))
-    # per-stage latency distributions from the PRODUCT's histogram
-    # buckets (post-warmup intervals) — one definition with the broker
-    h_disp = LatencyHistogram()
-    h_rb = LatencyHistogram()
-    for a, b in enc_iv:
-        if a >= warm_at:
-            h_disp.record_s(b - a)
-    for a, b in rb_iv:
-        if a >= warm_at:
-            h_rb.record_s(b - a)
-    n_batches = max(1, len(enc_iv))
-    out = {
-        "offered_rate": int(target_rate),
-        "served": served[0],
-        "served_rate": int(served[0] / max(seconds, 1e-9)),
-        "p50_ms": round(h_e2e.percentile_ms(50), 2),
-        "p99_ms": round(h_e2e.percentile_ms(99), 2),
-        "hist": h_e2e.to_dict(),
-        "stages": {
-            "match_dispatch": h_disp.to_dict(),
-            "match_readback": h_rb.to_dict(),
-        },
-        "dispatch_mean_ms": round(
-            float(np.mean([b - a for a, b in enc_iv])) * 1e3, 2),
-        "readback_mean_ms": round(
-            float(np.mean([b - a for a, b in rb_iv])) * 1e3, 2)
-            if rb_iv else 0.0,
-        "batches": len(enc_iv),
-        "spill_reruns": spill_reruns[0],
-        "readback_bytes_total": bytes_total[0],
-        "readback_bytes_per_batch": bytes_total[0] // n_batches,
-        "slab_bytes_per_batch": slab_bytes,
-        "readback_bytes_hist": rb_hist,
-        "stage_overlap_ms_hist": ov_hist,
-        "readback_bound_ok": bound_ok[0],
-    }
-    if np_lats:
-        arr = np.concatenate(np_lats)
-        p50np = float(np.percentile(arr, 50)) * 1e3
-        p99np = float(np.percentile(arr, 99)) * 1e3
-        out["p50_np_ms"] = round(p50np, 2)
-        out["p99_np_ms"] = round(p99np, 2)
-        out["gate_hist_parity"] = _hist_parity_ok(
-            out["p50_ms"], p50np) and _hist_parity_ok(
-            out["p99_ms"], p99np)
-    return out
-
-
-def bench_serve_pipeline(dev, table, topics, batch, offered_rate,
-                         seconds, depth=8, inflight=2):
-    """Serial vs pipelined at EQUAL offered load.  Gate booleans ride
-    the JSON: pipelined throughput >= serial (5% tolerance), p99 no
-    worse, and every pipelined batch's readback bytes within the
-    4·(B + sum(counts)) contract.
-
-    The p99 bound is HOST-DEPENDENT (the table-lifecycle stall_bound
-    idiom): on a multi-core host the stages genuinely overlap and the
-    bound is 1.10× serial (scheduler noise); on a 1-core host the
-    encode thread, XLA compute, and readback serialize, so depth-k
-    buffering structurally costs up to k extra pipeline cycles of
-    latency — the bound is serial p99 + depth × the measured
-    (dispatch + readback) cycle, and the applied bound rides the JSON
-    as ``p99_bound``."""
-    serial = asyncio.run(serve_pipeline_harness(
-        dev, table, topics, batch, offered_rate, seconds, depth=depth,
-        pipelined=False))
-    piped = asyncio.run(serve_pipeline_harness(
-        dev, table, topics, batch, offered_rate, seconds, depth=depth,
-        pipelined=True, inflight=inflight))
-    out = {
-        "offered_rate": int(offered_rate),
-        "batch": batch,
-        "serial": serial,
-        "pipeline": piped,
-    }
-    if serial and piped:
-        out["throughput_ratio"] = round(
-            piped["served_rate"] / max(1, serial["served_rate"]), 3)
-        out["p99_ratio"] = round(
-            serial["p99_ms"] / max(piped["p99_ms"], 1e-6), 2)
-        out["readback_bytes_ratio"] = round(
-            serial["readback_bytes_per_batch"]
-            / max(1, piped["readback_bytes_per_batch"]), 1)
-        out["gate_throughput_ge_serial"] = bool(
-            piped["served_rate"] >= 0.95 * serial["served_rate"])
-        cycle_ms = (piped["dispatch_mean_ms"]
-                    + piped["readback_mean_ms"])
-        if (os.cpu_count() or 1) > 1:
-            out["p99_bound"] = "1.1x_serial"
-            bound_ms = 1.10 * serial["p99_ms"]
-        else:
-            out["p99_bound"] = "serial_plus_depth_cycles"
-            bound_ms = 1.10 * (serial["p99_ms"]
-                               + inflight * cycle_ms)
-        out["p99_bound_ms"] = round(bound_ms, 2)
-        out["gate_p99_no_worse"] = bool(piped["p99_ms"] <= bound_ms)
-        out["gate_readback_proportional"] = bool(
-            piped["readback_bound_ok"]
-            and piped["readback_bytes_per_batch"]
-            < serial["readback_bytes_per_batch"])
-    return out
-
-
-def bench_serve_pipeline_smoke(n_filters=2000, batch=256, seconds=1.5,
-                               depth=8):
-    """CPU-jax tiny-scale serve_pipeline A/B for bench_e2e --smoke."""
-    from emqx_tpu.ops.device_table import DeviceNfa
-
-    rng = np.random.default_rng(13)
-    filters, topics = build_workload(rng, n_filters, batch * 8, depth)
-    table, kind, _ = build_table(filters, depth)
-    dev = DeviceNfa(table, active_slots=8, compact_output=False,
-                    max_matches=_serve_max_matches())
-    cap = calibrate_serve(dev, table, topics, batch, depth=depth,
-                          seconds=0.8)
-    rate = 0.6 * cap
-    out = bench_serve_pipeline(dev, table, topics, batch, rate, seconds,
-                               depth=depth)
-    out["table"] = kind
-    out["n_filters"] = len(filters)
-    return out
-
-
-def serve_roundtrip_run(dev, table, topics, batch, target_rate,
-                        seconds, depth=8, window_s=0.0002,
-                        mode="chunked"):
-    """Open-loop serial serve over the two-phase readback contract in
-    one transfer shape.  The headline is the per-batch d2h ROUND-TRIP
-    histogram: chunked pays 1 + popcount(Σcounts), ragged exactly
-    1 + (anything matched) — the quantity a real-link RTT multiplies."""
-    import jax.numpy as jnp
-
-    from emqx_tpu.observe.hist import LatencyHistogram
-
-    n_topics = len(topics)
-    k = dev.max_matches
-    h_e2e = LatencyHistogram()
-    trips_hist: dict = {}
-    bytes_total = 0
-    trips_total = 0
-    trips_max = 0
-    batches = 0
-    served = 0
-    spill_reruns = 0
-
-    def _dispatch_once(names):
-        w, l, s = _encode(table, names, depth, batch)
-        return dev.match(jnp.asarray(w), jnp.asarray(l),
-                         jnp.asarray(s),
-                         flat_cap=_serve_flat_cap(batch))
-
-    rb = _readback_ragged if mode == "ragged" else None
-    # warm outside the timed window
-    r0 = _dispatch_once(topics[:batch])
-    (rb or _readback_twophase)(r0, batch, k)
-    t0 = time.perf_counter()
-    stop_at = t0 + seconds
-    warm_at = t0 + seconds * 0.25
-    consumed = 0
-    while True:
-        now = time.perf_counter()
-        if now >= stop_at:
-            break
-        arrived = int((now - t0) * target_rate)
-        avail = arrived - consumed
-        oldest_age = (now - (t0 + consumed / target_rate)
-                      if avail > 0 else 0.0)
-        if avail <= 0 or (avail < batch and oldest_age < window_s):
-            time.sleep(window_s / 2)
-            continue
-        take = min(avail, batch)
-        first = consumed
-        consumed += take
-        names = [topics[(first + j) % n_topics] for j in range(batch)]
-        r = _dispatch_once(names)
-        if rb is not None:
-            rows, sp, nbytes, _counts, trips = rb(r, take, k)
-        else:
-            rows, sp, nbytes, _counts = _readback_twophase(r, take, k)
-            trips = 1 + bin(sum(len(x) for x in rows)).count("1")
-        sp = np.asarray(sp)
-        sp = sp[sp < take]
-        if len(sp):
-            spill_reruns += len(sp)
-            for i in sp:
-                table.match_host(names[i])
-        batches += 1
-        bytes_total += nbytes
-        trips_total += trips
-        trips_max = max(trips_max, trips)
-        _hist_add(trips_hist, trips)
-        done_t = time.perf_counter()
-        served += take
-        if done_t >= warm_at:
-            h_e2e.record_many_s(
-                done_t - (t0 + (first + np.arange(take)) / target_rate))
-    if not batches:
-        return None
-    return {
-        "mode": mode,
-        "offered_rate": int(target_rate),
-        "served": served,
-        "served_rate": int(served / max(seconds, 1e-9)),
-        "p50_ms": round(h_e2e.percentile_ms(50), 2),
-        "p99_ms": round(h_e2e.percentile_ms(99), 2),
-        "batches": batches,
-        "spill_reruns": spill_reruns,
-        "readback_bytes_per_batch": bytes_total // batches,
-        "d2h_calls_hist": trips_hist,
-        "roundtrips_per_batch": round(trips_total / batches, 2),
-        "roundtrips_max": trips_max,
-    }
-
-
-def bench_serve_roundtrip(dev, table, topics, batch, offered_rate,
-                          seconds, depth=8):
-    """Chunked vs ragged readback at EQUAL offered load (ISSUE 17).
-
-    Gate booleans ride the JSON: every ragged batch reads back in ≤ 2
-    d2h round trips (``gate_roundtrips_le_2``) and a same-dispatch
-    probe decodes bit-identical rows through both transfer shapes
-    (``gate_ragged_parity``).  On loopback the trip count is latency
-    noise — the A/B exists to carry the d2h-call histograms whose
-    RTT-multiplied cost the r06 real-hardware round prices."""
-    import jax.numpy as jnp
-
-    # same-dispatch parity probe, outside the timed windows
-    w, l, s = _encode(table, topics[:batch], depth, batch)
-    r = dev.match(jnp.asarray(w), jnp.asarray(l), jnp.asarray(s),
-                  flat_cap=_serve_flat_cap(batch))
-    k = dev.max_matches
-    rows_c, sp_c, _b, _n = _readback_twophase(r, batch, k)
-    rows_r, sp_r, _b2, _n2, probe_trips = _readback_ragged(r, batch, k)
-    parity = (len(rows_c) == len(rows_r)
-              and all(np.array_equal(a, b)
-                      for a, b in zip(rows_c, rows_r))
-              and np.array_equal(sp_c, sp_r))
-    chunked = serve_roundtrip_run(dev, table, topics, batch,
-                                  offered_rate, seconds, depth=depth,
-                                  mode="chunked")
-    ragged = serve_roundtrip_run(dev, table, topics, batch,
-                                 offered_rate, seconds, depth=depth,
-                                 mode="ragged")
-    out = {
-        "offered_rate": int(offered_rate),
-        "batch": batch,
-        "chunked": chunked,
-        "ragged": ragged,
-        "gate_ragged_parity": bool(parity and probe_trips <= 2),
-    }
-    if chunked and ragged:
-        out["roundtrip_ratio"] = round(
-            chunked["roundtrips_per_batch"]
-            / max(ragged["roundtrips_per_batch"], 1e-9), 2)
-        out["bytes_ratio"] = round(
-            ragged["readback_bytes_per_batch"]
-            / max(1, chunked["readback_bytes_per_batch"]), 2)
-        out["gate_roundtrips_le_2"] = bool(ragged["roundtrips_max"] <= 2)
-        # the padding price of the single transfer is bounded: the
-        # capacity class is < 2× the exact prefix
-        out["gate_ragged_bytes_bounded"] = bool(
-            ragged["readback_bytes_per_batch"]
-            <= 2 * chunked["readback_bytes_per_batch"])
-    return out
-
-
-def bench_serve_roundtrip_smoke(n_filters=2000, batch=256, seconds=1.2,
-                                depth=8):
-    """CPU-jax tiny-scale chunked-vs-ragged A/B for bench_e2e --smoke."""
-    from emqx_tpu.ops.device_table import DeviceNfa
-
-    rng = np.random.default_rng(17)
-    filters, topics = build_workload(rng, n_filters, batch * 8, depth)
-    table, kind, _ = build_table(filters, depth)
-    dev = DeviceNfa(table, active_slots=8, compact_output=False,
-                    max_matches=_serve_max_matches())
-    cap = calibrate_serve(dev, table, topics, batch, depth=depth,
-                          seconds=0.8)
-    rate = 0.6 * cap
-    out = bench_serve_roundtrip(dev, table, topics, batch, rate,
-                                seconds, depth=depth)
     out["table"] = kind
     out["n_filters"] = len(filters)
     return out
@@ -1841,10 +1339,10 @@ def bench_multichip_serve(n_filters=200_000, batch=2048, iters=10,
       ``measured_on`` field says which regime measured)."""
     import jax
 
-    from emqx_tpu.broker.match_service import MatchService
     from emqx_tpu.ops import encode_batch
     from emqx_tpu.ops.device_table import DeviceNfa
     from emqx_tpu.ops.incremental import IncrementalNfa
+    from emqx_tpu.ops.match_kernel import decode_packed
     from emqx_tpu.parallel.multichip_serve import (
         MultichipMatcher, ShardDead,
     )
@@ -1867,12 +1365,10 @@ def bench_multichip_serve(n_filters=200_000, batch=2048, iters=10,
     mc.apply_pending()
 
     names = (topics * (batch // max(1, len(topics)) + 1))[:batch]
-    flat_cap = _serve_flat_cap(batch)
 
     def single_rows():
         enc = encode_batch(inc, names, batch=batch, depth=depth)
-        res = dev.match(*enc, flat_cap=flat_cap)
-        return MatchService._readback_rows(res, len(names), max_matches)
+        return decode_packed(dev.serve(*enc), len(names), max_matches)
 
     def mesh_rows():
         enc = mc.encode(names, batch=batch, depth=depth)
@@ -3132,24 +2628,6 @@ def main():
                         / max(1e-9, min(args.serve_seconds, 6.0))))
         note(f"serve deadline A/B done: {serve_deadline}")
 
-    # overlapped serve pipeline A/B (ISSUE 11): serial vs double-
-    # buffered with two-phase match-proportional readback, same load
-    serve_pipeline = None
-    if serve_dev:
-        serve_pipeline = bench_serve_pipeline(
-            dev, table, topics, args.batch, serve_dev["offered_rate"],
-            min(args.serve_seconds, 6.0), depth=args.depth)
-        note(f"serve pipeline A/B done: {serve_pipeline}")
-
-    # one-round-trip serve A/B (ISSUE 17): chunked vs ragged readback
-    # transfer shape at the same load, d2h-call histograms + gates
-    serve_roundtrip = None
-    if serve_dev:
-        serve_roundtrip = bench_serve_roundtrip(
-            dev, table, topics, args.batch, serve_dev["offered_rate"],
-            min(args.serve_seconds, 6.0), depth=args.depth)
-        note(f"serve roundtrip A/B done: {serve_roundtrip}")
-
     deltas = bench_deltas(dev, table)
     note("deltas done")
 
@@ -3218,8 +2696,6 @@ def main():
         "serve_device_half_batch": serve_dev2,
         "serve_device_quarter_batch": serve_dev4,
         "serve_deadline": serve_deadline,
-        "serve_pipeline": serve_pipeline,
-        "serve_roundtrip": serve_roundtrip,
         "kernel_join": kj,
         "multichip_serve": mcs,
         "multichip_ep": mce,

@@ -155,7 +155,7 @@ async def run(args, jax) -> None:
     from emqx_tpu.client import Client
     from emqx_tpu.config import Config
     from emqx_tpu.node import BrokerNode, enable_xla_cache
-    from emqx_tpu.ops.match_kernel import nfa_match
+    from emqx_tpu.ops.match_kernel import nfa_match_packed
 
     size = TINY if args.rehearse else FULL
     enable_xla_cache()
@@ -269,9 +269,9 @@ async def run(args, jax) -> None:
               "warm-up moved no device batch")
         emit("warmup", publishes=len(warm_topics),
              seconds=round(time.perf_counter() - t0, 1),
-             compiled_match_shapes=nfa_match._cache_size(),
+             compiled_match_shapes=nfa_match_packed._cache_size(),
              batches=m.get("tpu.match.batches"))
-        check(nfa_match._cache_size() >= 2,
+        check(nfa_match_packed._cache_size() >= 2,
               "the two depth lanes did not both compile")
         snap = dict(m.all())
 

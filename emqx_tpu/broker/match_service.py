@@ -104,28 +104,23 @@ rebuilds or recompiles on the hot path:
 
 **Overlapped serve pipeline** (opt-in, ``match.pipeline.enable``): the
 dispatch tax BENCH_r05 measured (match kernel ~17 ms p99 vs 398 ms
-served at batch 8192 — the gap is host-side encode, serialized
-dispatch, and a d2h readback sized to the table) is killed by
-overlapping the three serve stages, the way the FPGA XML-filtering
-architecture streams documents through match units while I/O overlaps
-compute:
+served at batch 8192 — the gap is host-side encode and serialized
+dispatch) is attacked by overlapping the three serve stages, the way
+the FPGA XML-filtering architecture streams documents through match
+units while I/O overlaps compute (on the attached chip it tied the
+serial loop on the one cell, PERF.md §5, PR 27; the switch means
+overlap and nothing else):
 
 * **encode off the loop, overlapped**: ``encode_batch`` for batch N+1
-  runs in a worker thread while batch N computes on device; the batch
-  operand buffers are DONATED to the kernel (the ``_scatter_rows``
-  donation idiom), so the chain never holds two generations of encode
-  buffers;
+  runs in a worker thread while batch N computes on device;
 * **double-buffered dispatch**: up to ``match.pipeline.depth``
   (default 2) batches sit past dispatch awaiting readback
   (``broker.match.pipeline_inflight``); the serve loop goes back to
   batching the moment a dispatch lands, instead of parking on the
   round trip;
-* **match-proportional two-phase readback** in a supervised
-  ``match.readback`` child: phase 1 ships the tiny packed per-row
-  meta vector (counts + fail-open flags, 4·B bytes), phase 2 ships
-  exactly ``sum(counts)`` ids from the on-device-compacted flat
-  buffer — ``tpu.match.readback_bytes`` is 4·(B + Σcounts) per batch
-  instead of the 4·FLAT_MULT·B slab the serial path reads;
+* **readback in a supervised ``match.readback`` child**: the same one
+  packed array the serial path reads (``DeviceNfa.serve`` →
+  ``match_kernel.decode_packed``), fetched off the serve loop;
 * **per-slot staleness guards**: every in-flight slot carries the
   table generation + aid-reuse counters it dispatched against; a
   segment swap or aid reuse landing mid-flight discards exactly the
@@ -181,13 +176,13 @@ import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Set, Tuple
 
-import numpy as np
 from jax.profiler import TraceAnnotation as _Annot
 
 from .. import faultinject as _fi
 from .. import topic as T
 from ..observe.span import stage_span
 from ..ops.kernel_cache import CompileMiss
+from ..ops.match_kernel import decode_packed
 from .trie import FilterTrie
 
 log = logging.getLogger(__name__)
@@ -362,8 +357,6 @@ class MatchService:
         multichip_ep_shrink_threshold: float = 0.01,
         multichip_ep_max_cap_class: int = 3,
         multichip_balance_budget: int = 64,
-        readback_mode: str = "chunked",
-        readback_auto_slack: float = 1.0,
         hists: Any = None,
         flightrec: Any = None,
     ) -> None:
@@ -469,17 +462,6 @@ class MatchService:
         # DeviceNfa relation mirror on; flag off every join structure
         # stays unbuilt.
         self.backend = backend
-        # phase-2 readback shape (module docstring): "chunked" = the
-        # pow2 binary decomposition (byte-identical to PR 16), "ragged"
-        # = ONE padded-to-capacity-class transfer per batch (two d2h
-        # round trips total, meta + payload), "auto" = ragged exactly
-        # when the total is not a power of two (pow2 totals are one
-        # chunk either way, so the decomposition already costs 2).
-        self.readback_mode = readback_mode
-        # auto-mode ragged crossover (satellite, ISSUE 18): padding
-        # slack tolerated before auto falls back to chunked; 1.0 admits
-        # every pow2-capacity class (byte-identical to the PR 17 rule)
-        self.readback_auto_slack = float(readback_auto_slack)
         self.tuner = None
         self._tuning: Set[str] = set()
         self._seg_join_seed = None   # (epoch, shape_key, arrays)
@@ -1019,34 +1001,21 @@ class MatchService:
                 raise _fi.InjectedFault("match.compile")
             if act == "delay":
                 time.sleep(_fi._injector.last_delay)
-        # flat_cap is a jit STATIC arg — warming without it would
-        # compile the wrong variant and the first live batch would still
-        # stall on an XLA compile.  Pipeline mode dispatches through the
-        # DONATED jit twin, a separate executable: warm that variant too
-        # (fresh operands each pass — donation consumes them).  Under
-        # backend routing every family auto can pick must warm, or the
-        # first re-routed batch stalls exactly like an unwarmed shape.
-        # The serial path's one-output twin is a third executable: warm
-        # what the serve path will ask for (``packed``).
-        donates = (False, True) if self.pipeline else (False,)
+        # warm the program the serve path dispatches (``dev.serve``),
+        # or the first live batch would still stall on an XLA compile.
+        # Under backend routing every family auto can pick must warm, or
+        # the first re-routed batch stalls exactly like an unwarmed shape.
         backends = (("hash", "join") if self.backend == "auto"
                     else (self.backend,))
-        for donate in donates:
-            for be in backends:
-                words, lens, is_sys = encode_batch(self.inc, [], batch=64)
-                self.dev.match(words, lens, is_sys,
-                               flat_cap=self.FLAT_MULT * 64,
-                               donate_inputs=donate, backend=be,
-                               packed=self._packed_serve)
-                if self.short_depth and self.short_depth < self.depth:
-                    # pre-pay the short-depth kernel shape too, or the
-                    # first split batch stalls the loop on an XLA compile
-                    w, l, sy = encode_batch(self.inc, [], batch=64,
-                                            depth=self.short_depth)
-                    self.dev.match(w, l, sy,
-                                   flat_cap=self.FLAT_MULT * 64,
-                                   donate_inputs=donate, backend=be,
-                                   packed=self._packed_serve)
+        for be in backends:
+            self.dev.serve(*encode_batch(self.inc, [], batch=64),
+                           backend=be)
+            if self.short_depth and self.short_depth < self.depth:
+                # pre-pay the short-depth kernel shape too, or the
+                # first split batch stalls the loop on an XLA compile
+                self.dev.serve(
+                    *encode_batch(self.inc, [], batch=64,
+                                  depth=self.short_depth), backend=be)
 
     # ------------------------------------------------------------------
     # multichip serve backend (opt-in, match.multichip.enable)
@@ -1279,9 +1248,7 @@ class MatchService:
             def runner(be):
                 def go():
                     enc = encode_batch(inc, names, batch=b, depth=d)
-                    res = dev.match(
-                        *enc, flat_cap=self.FLAT_MULT * b, backend=be)
-                    jax.device_get(res.n_matches)   # block to completion
+                    jax.block_until_ready(dev.serve(*enc, backend=be))
                 return go
 
             # no join-pallas candidate: Mosaic refuses its in-VMEM
@@ -1810,124 +1777,7 @@ class MatchService:
                 rules.update(r)
         return filters, sorted(rules)
 
-    # flat-output capacity per padded batch row: every readback byte
-    # sits on the serving path, and ~6 ids/topic covers the workload's
-    # fan-out tail
-    from ..ops.match_kernel import SERVE_FLAT_MULT as FLAT_MULT
-
-    @property
-    def _packed_serve(self) -> bool:
-        """The serial slab readback is what reads this service's
-        dispatches (no pipeline, ``match.readback.mode`` chunked): it
-        needs the whole answer and nothing else, so they ask the device
-        table for the one-output program."""
-        return not self.pipeline and self.readback_mode == "chunked"
-
-    def _device_rows(self, enc, n: int):
-        B = enc[0].shape[0]
-        res = self.dev.match(*enc, flat_cap=self.FLAT_MULT * B,
-                             packed=self._packed_serve)
-        return self._readback_rows(res, n, self.dev.max_matches)
-
-    @staticmethod
-    def _readback_rows(res, n: int, k: int):
-        """The serial readback's d2h: ``(rows, spilled row indices)`` of
-        the batch's first ``n`` rows, from either answer
-        ``DeviceNfa.match`` gives: the packed array (``row_meta`` then
-        the flat ids, ``flat_cap`` = FLAT_MULT·B: ONE buffer to fetch)
-        or a ``MatchResult`` (four).  :meth:`_readback_cost` says which
-        was paid."""
-        import jax
-
-        from ..ops.match_kernel import (
-            MatchResult, decode_flat, decode_row_meta,
-        )
-
-        if not isinstance(res, MatchResult):
-            packed = jax.device_get(res)
-            B = packed.size // (1 + MatchService.FLAT_MULT)
-            # row_meta's count is min(n, K) and its flag is
-            # active_overflow | match_overflow: what the four-array
-            # decode below computes on the host
-            nk, sp = decode_row_meta(packed[:B])
-            matches = packed[B:]
-        else:
-            # fetch the kernel's own outputs and OR the spill flags on
-            # host: res.spilled_rows() would build NEW lazy device ops
-            # here, i.e. an extra dispatch round trip per batch on the
-            # readback path
-            matches, nk, aover, mover = jax.device_get(
-                (res.matches, res.n_matches, res.active_overflow,
-                 res.match_overflow)
-            )
-            sp = (aover > 0) | (mover > 0)
-        rows = [seg.tolist() for seg in decode_flat(matches, nk, k)[:n]]
-        return rows, np.flatnonzero(sp[:n]).tolist()
-
-    @staticmethod
-    def _readback_cost(res) -> Tuple[int, int]:
-        """``(d2h bytes, device buffers fetched)`` :meth:`_readback_rows`
-        pays for ``res``: the packed array, or the flat id buffer, the
-        counts and both overflow vectors."""
-        from ..ops.match_kernel import MatchResult
-
-        if not isinstance(res, MatchResult):
-            return 4 * int(res.size), 1
-        return 4 * int(res.matches.size + 3 * res.n_matches.size), 4
-
-    @staticmethod
-    def _readback_rows_twophase(res, n: int, k: int,
-                                mode: str = "chunked",
-                                auto_slack: float = 1.0):
-        """Match-proportional two-phase d2h: phase 1 ships the packed
-        (B,) ``row_meta`` vector (counts + fail-open flags), phase 2
-        exactly ``sum(counts)`` ids from the flat buffer — the first
-        Σ nk[:n] entries are the real rows by the cumsum-offset
-        construction (padding rows pack strictly after).  ``mode``
-        picks the phase-2 transfer shape: "chunked" is the pow2 binary
-        decomposition (popcount(total) transfers, zero padding bytes),
-        "ragged" ONE padded-to-capacity-class transfer (a batch then
-        costs exactly TWO d2h round trips, meta + payload), "auto"
-        ragged when the total is not a power of two AND the capacity
-        padding stays within ``auto_slack``·total extra ids (a pow2
-        total is one chunk either way — identical bytes AND trips).
-        ``auto_slack`` is the crossover knob (``match.readback
-        .auto_slack``): pow2 capacity classes pad < total for any
-        non-pow2 total, so the 1.0 default always takes the ragged
-        trip — exactly the pre-knob heuristic; a low-bandwidth link
-        dials it down to keep byte-bloated totals on the chunked
-        path.  Returns ``(rows, spilled row indices, d2h bytes
-        shipped, d2h round trips performed)``."""
-        import jax
-
-        from ..ops.match_kernel import (
-            decode_row_meta, fetch_flat_prefix, fetch_flat_ragged,
-            ragged_capacity,
-        )
-
-        meta = jax.device_get(res.row_meta)
-        nk, sp = decode_row_meta(meta)
-        nk = np.minimum(nk, k)
-        total = int(nk[:n].sum())
-        ragged = mode == "ragged" or (
-            mode == "auto" and bool(total & (total - 1))
-            and (ragged_capacity(total, int(res.matches.shape[0]))
-                 - total) <= auto_slack * total)
-        if ragged:
-            ids = fetch_flat_ragged(res.matches, total)
-            nbytes = 4 * (meta.size +
-                          ragged_capacity(total, int(res.matches.shape[0])))
-            trips = 1 + (1 if total else 0)
-        else:
-            ids = fetch_flat_prefix(res.matches, total)
-            nbytes = 4 * (meta.size + total)
-            trips = 1 + bin(total).count("1")
-        offs = np.cumsum(nk[:n]) - nk[:n]
-        rows = [ids[o:o + c].tolist() for o, c in zip(offs, nk[:n])]
-        return rows, np.flatnonzero(sp[:n]).tolist(), nbytes, trips
-
-    def _encode_dispatch(self, inc, dev, topics, groups, donate,
-                         cyc=None):
+    def _encode_dispatch(self, inc, dev, topics, groups, cyc=None):
         """WORKER-THREAD stage: encode every depth group and dispatch
         its kernel — both OFF the event loop (the encode of a 2048
         batch held the loop ~2.3 ms per dispatch; vocab dict reads are
@@ -1936,11 +1786,9 @@ class MatchService:
         proof).  Dispatch only holds the device lock; the returned
         handles are lazy device results, so group 2 executes while
         group 1's answers stream back and — in pipeline mode — batch
-        N+1 encodes while batch N computes.  ``donate`` hands the
-        operand buffers to the kernel (pipeline mode; nothing reads
-        them after dispatch).  ``cyc`` is the batch's books (``seq`` for
-        the spans; this function's first- and last-line stamps for the
-        loop's hop spans)."""
+        N+1 encodes while batch N computes.  ``cyc`` is the batch's
+        books (``seq`` for the spans; this function's first- and
+        last-line stamps for the loop's hop spans)."""
         t_in = _now_ns()
         from ..ops import encode_batch
 
@@ -1979,15 +1827,14 @@ class MatchService:
                     res = dev.dispatch(
                         enc, block_compile=(dev.kernel_cache is None))
                 else:
-                    res = dev.match(
-                        *enc, flat_cap=self.FLAT_MULT * enc[0].shape[0],
+                    res = dev.serve(
+                        *enc,
                         # serving never parks behind XLA: an uncompiled
                         # shape raises CompileMiss (CPU trie answers,
                         # shape warms in the background) instead of
                         # stalling
                         block_compile=(dev.kernel_cache is None),
-                        donate_inputs=donate, backend=be,
-                        packed=self._packed_serve)
+                        backend=be)
             t2 = _now_ns()
             if be in ("join", "join-pallas") and self.metrics is not None:
                 # this worker is the single in-flight encode stage, so
@@ -2006,29 +1853,19 @@ class MatchService:
             cyc.t_out = _now_ns()
         return handles, enc_ns, disp_ns
 
-    def _readback_groups(self, handles, dev, proportional, cyc=None):
-        """WORKER-THREAD stage: block on every group's d2h.  What each
-        mode ships, in how many device buffers (= d2h transfers; the
-        copies one ``device_get`` call names start together, so on the
-        attached chip ready buffers cost 0.46 ms for one and 0.54 ms
-        for four, not four latencies: PERF.md §6, PR 31): serial
-        (flag-off) mode the whole flat slab, as the one-output
-        program's packed array (4·(B + flat_cap) bytes, 1 buffer) or,
-        from a ``MatchResult`` (kernel cache, Pallas walk), as four of
-        its fields (4·(flat_cap + 3·B) bytes, 4 buffers), unless
-        ``match.readback.mode`` asks for the ragged contract;
-        ``proportional`` (pipeline mode) the two-phase contract in the
-        configured transfer shape (``row_meta``, then 4·Σcounts bytes
-        in popcount(Σcounts) chunks, or ≤ 1 padded chunk ragged, each
-        a dispatch and a fetch of its own); the mesh its dense compact
-        rows in one call.  Returns ``([(rows,
-        spilled)...], total d2h bytes, readback ns, d2h round
-        trips)``.  ``cyc`` as in :meth:`_encode_dispatch`."""
+    def _readback_groups(self, handles, dev, cyc=None):
+        """WORKER-THREAD stage: block on every group's d2h, in BOTH
+        serve modes.  A single-chip group's answer is the one packed
+        array ``dev.serve`` returned: 4·(B + flat_cap) bytes, ONE device
+        buffer (what a buffer costs on the attached chip, and why not
+        fewer bytes in more fetches: PERF.md §6, PR 31); the mesh ships
+        its own dense compact rows in one call.  Returns ``([(rows,
+        spilled)...], total d2h bytes, readback ns, device buffers
+        fetched)``.  ``cyc`` as in :meth:`_encode_dispatch`."""
         t0 = _now_ns()
         out = []
         nbytes = 0
         total = sum(n for _res, n in handles)
-        trips = 0
         seq = 0
         if cyc is not None:
             cyc.t_in, seq = t0, cyc.seq
@@ -2036,20 +1873,13 @@ class MatchService:
         with _Annot("emqx.match.readback", seq=seq, n=total, t_ns=t0):
             for res, n in handles:
                 if multichip:
-                    # dense compact contract off the mesh: d2h is
-                    # already matches-proportional in BOTH serve modes,
-                    # one device_get round trip
+                    # dense compact contract off the mesh, one
+                    # device_get round trip
                     rows, sp, b = dev.readback(res, n)
-                    t = 1
-                elif proportional or self.readback_mode != "chunked":
-                    rows, sp, b, t = self._readback_rows_twophase(
-                        res, n, dev.max_matches, mode=self.readback_mode,
-                        auto_slack=self.readback_auto_slack)
                 else:
-                    rows, sp = self._readback_rows(res, n, dev.max_matches)
-                    b, t = self._readback_cost(res)
+                    rows, sp = decode_packed(res, n, dev.max_matches)
+                    b = 4 * int(res.size)
                 nbytes += b
-                trips += t
                 out.append((rows, sp))
         t1 = _now_ns()
         # single writer: the flag-off serve loop's to_thread hop OR the
@@ -2058,7 +1888,7 @@ class MatchService:
             self._sp_readback.rec(t0, t1, total, self._table_gen, seq)
         if cyc is not None:
             cyc.t_out = _now_ns()
-        return out, nbytes, t1 - t0, trips
+        return out, nbytes, t1 - t0, len(handles)
 
     def _depth_groups(self, topics: List[str]) -> List[Tuple[List[int], int]]:
         """Partition batch indices into (indices, kernel_depth) groups.
@@ -2268,13 +2098,13 @@ class MatchService:
             cyc = _Cycle(0, len(topics), gen0, 0)
         t_call = _now_ns()
         handles, enc_ns, disp_ns = await asyncio.to_thread(
-            self._encode_dispatch, inc, dev, topics, groups, False, cyc
+            self._encode_dispatch, inc, dev, topics, groups, cyc
         )
         await self._readback_gate()
         t_mid = _now_ns()
         self._rec_hops(cyc, t_call, t_mid)
         results, nbytes, rb_ns, trips = await asyncio.to_thread(
-            self._readback_groups, handles, dev, False, cyc
+            self._readback_groups, handles, dev, cyc
         )
         t_ep = cyc.t_ep = _now_ns()
         self._rec_hops(cyc, t_mid, t_ep)
@@ -2655,10 +2485,10 @@ class MatchService:
                                  deadline_mode: bool,
                                  cyc: Optional[_Cycle] = None) -> None:
         """Pipeline-mode front half of a serve batch: encode + dispatch
-        in a worker thread (donated operand buffers), then hand the
-        in-flight slot to the ``match.readback`` child and return — the
-        serve loop goes straight back to batching (and encoding batch
-        N+1) while this batch computes on device.  Every slot carries
+        in a worker thread, then hand the in-flight slot to the
+        ``match.readback`` child and return — the serve loop goes
+        straight back to batching (and encoding batch N+1) while this
+        batch computes on device.  Every slot carries
         the aid-reuse/table-gen guards it dispatched against, so a swap
         or reuse landing mid-flight discards exactly the stale slot.
         ``cyc`` rides the slot for its ``seq`` alone: the overlapped
@@ -2680,7 +2510,7 @@ class MatchService:
             await self._fault_gate()
             groups = self._depth_groups(topics)
             dispatch = asyncio.to_thread(
-                self._encode_dispatch, inc, dev, topics, groups, True, cyc)
+                self._encode_dispatch, inc, dev, topics, groups, cyc)
             if deadline_mode:
                 handles, enc_ns, disp_ns = await asyncio.wait_for(
                     dispatch, self.dispatch_timeout_s)
@@ -2710,10 +2540,10 @@ class MatchService:
 
     async def _readback_loop(self) -> None:
         """Supervised ``match.readback`` child: drains the in-flight
-        slot queue, rides the two-phase match-proportional d2h, and
-        mints hints — the back half of the double-buffered chain.  A
-        kill resolves every queued slot's waiters NOW (CPU path serves)
-        and the supervised restart resumes consuming."""
+        slot queue, fetches each slot's answer and mints hints — the
+        back half of the double-buffered chain.  A kill resolves every
+        queued slot's waiters NOW (CPU path serves) and the supervised
+        restart resumes consuming."""
         try:
             while True:
                 slot = await self._inflight_q.get()
@@ -2737,7 +2567,7 @@ class MatchService:
                 await self._readback_gate()
                 results, nbytes, rb_ns, trips = await asyncio.wait_for(
                     asyncio.to_thread(
-                        self._readback_groups, handles, dev, True, cyc),
+                        self._readback_groups, handles, dev, cyc),
                     self.dispatch_timeout_s)
                 self._note_split(dispatch_ns / 1e9, rb_ns / 1e9)
                 if self.metrics is not None:
@@ -2914,7 +2744,7 @@ class MatchService:
             mc.readback(res, 1)
             return
         enc = encode_batch(self.inc, ["probe/health"], batch=64)
-        self._device_rows(enc, 1)
+        decode_packed(self.dev.serve(*enc), 1, self.dev.max_matches)
 
     def info(self) -> dict:
         return {
@@ -2945,8 +2775,6 @@ class MatchService:
             "pending": len(self._pending),
             # kernel backend routing (ISSUE 13)
             "backend": self.backend,
-            "readback_mode": self.readback_mode,
-            "readback_auto_slack": self.readback_auto_slack,
             "join_rebuilds": self.dev.join_rebuilds,
             "autotune": (self.tuner.info()
                          if self.tuner is not None else None),

@@ -444,11 +444,10 @@ SCHEMA: Dict[str, Field] = {
     # cadence of the recovery probe while the breaker is open
     "match.breaker.probe_interval": Field(1.0, duration),
     # overlapped serve pipeline (broker/match_service.py): encode batch
-    # N+1 in a worker thread while batch N computes on device (donated
-    # input buffers), readback as a supervised match.readback child with
-    # match-proportional two-phase d2h (counts vector first, then
-    # exactly sum(counts) ids).  Off = the PR-10 serve path,
-    # byte-identical.
+    # N+1 in a worker thread while batch N computes on device, readback
+    # in a supervised match.readback child.  Overlap and nothing else:
+    # both loops dispatch the same program and read the same one packed
+    # array (PERF.md §6, PRs 27, 31, 32).
     "match.pipeline.enable": Field(False, _bool),
     # max device batches past dispatch awaiting readback (2 = classic
     # double buffering: one queued while one reads back)
@@ -463,25 +462,6 @@ SCHEMA: Dict[str, Field] = {
     # VMEM-resident tables; auto measures it alongside hash/join
     "match.backend": Field(
         "hash", _enum("hash", "join", "join-pallas", "auto")),
-    # phase-2 readback transfer shape (broker/match_service.py):
-    # "chunked" = pow2 binary decomposition (1+popcount(total) d2h
-    # trips, zero padding bytes), "ragged" = ONE padded-to-capacity-
-    # class transfer (exactly TWO trips per batch: meta + payload),
-    # "auto" = ragged exactly when the total is not a power of two.
-    # Capacity classes reuse the chunked (buffer, pow2) executables,
-    # so flipping modes never grows the executable set.
-    "match.readback.mode": Field(
-        "chunked", _enum("chunked", "ragged", "auto")),
-    # auto-mode crossover (effective only with match.readback.mode =
-    # auto): ragged serves a non-pow2 total only when its padding slack
-    # (capacity - total) stays <= auto_slack * total — 1.0 admits every
-    # pow2-capacity class (the PR 17 heuristic, byte-identical); r06
-    # tunes this down from measured link numbers without a code change
-    # a slack is a padding FRACTION: values past 1.0 would admit every
-    # capacity class and negative ones none — both misbehave only at
-    # serve time, so reject them at load time instead
-    "match.readback.auto_slack": Field(
-        1.0, float, lambda v: 0.0 <= v <= 1.0),
     # autotuner (effective only with match.backend=auto): measure
     # hash-vs-join per (B, D, S, Hb) shape on recently served topics;
     # the pick table persists as checksummed JSON next to the XLA disk

@@ -141,7 +141,7 @@ AFFINITY_SEEDS: Dict[str, Tuple[str, bool]] = {
     "Shard._consume_inbox": ("shard", False),
     "_ShardProtocol.data_received": ("shard", False),
     # serve-pipeline worker stages (broker/match_service.py, PR 11):
-    # the encode/dispatch stage and the two-phase readback stage are
+    # the encode/dispatch stage and the readback stage are
     # entered via asyncio.to_thread (auto-seeded too — these facts
     # write the contract down): PURE COMPUTE against captured
     # arguments.  MatchService is MAIN_ONLY, so any state write (or a

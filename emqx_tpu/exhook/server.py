@@ -33,8 +33,6 @@ import os
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
-import numpy as np
-
 from ..broker.trie import FilterTrie
 from .rpc import (
     add_hook_provider_to_server,
@@ -303,11 +301,7 @@ class TpuMatchSidecar:
         """Warm the match jit for the smallest batch bucket (larger
         buckets compile on first use).  Uses pre-encoded inert rows so no
         live host state is read off-loop."""
-        from ..ops.match_kernel import SERVE_FLAT_MULT
-
-        words, lens, is_sys = eng.encode([], 64)  # inert padding rows
-        # flat_cap is jit-static: warm the SAME variant serving uses
-        eng.dev.match(words, lens, is_sys, flat_cap=SERVE_FLAT_MULT * 64)
+        eng.dev.serve(*eng.encode([], 64))  # inert padding rows
 
     def _save_checkpoint(self) -> None:
         try:
@@ -337,26 +331,11 @@ class TpuMatchSidecar:
 
     def _device_rows(self, eng: _IncEngine, enc, n: int):
         """WORKER THREAD: kernel dispatch + readback.  Returns (rows,
-        spilled_row_indexes).  ONE bundled device→host fetch of the
-        FLAT-compacted output (~fan-out·4 bytes/topic instead of K·4):
-        every readback byte sits on the serving path."""
-        import jax
+        spilled_row_indexes): the served batch's one packed array,
+        fetched and split by its one decode."""
+        from ..ops.match_kernel import decode_packed
 
-        from ..ops.match_kernel import SERVE_FLAT_MULT, decode_flat
-
-        B = enc[0].shape[0]
-        res = eng.dev.match(*enc, flat_cap=SERVE_FLAT_MULT * B)
-        # OR the spill flags on host — res.spilled_rows() would build new
-        # lazy device ops, adding a dispatch round trip to every readback
-        matches, counts, aover, mover = jax.device_get(
-            (res.matches, res.n_matches, res.active_overflow,
-             res.match_overflow)
-        )
-        sp = (aover > 0) | (mover > 0)
-        rows = [seg.tolist()
-                for seg in decode_flat(matches, counts,
-                                       eng.dev.max_matches)[:n]]
-        return rows, np.flatnonzero(sp[:n]).tolist()
+        return decode_packed(eng.dev.serve(*enc), n, eng.dev.max_matches)
 
     async def _match_rows(self, topics: List[str]) -> List[List[int]]:
         """Match a batch to accept-id rows: device kernel + per-row

@@ -1125,8 +1125,6 @@ class BrokerNode:
                     "match.multichip.ep.autotune.max_cap_class"),
                 multichip_balance_budget=cfg.get(
                     "match.multichip.ep.autotune.max_moved_roots"),
-                readback_mode=cfg.get("match.readback.mode"),
-                readback_auto_slack=cfg.get("match.readback.auto_slack"),
                 hists=self.hists,
                 flightrec=self.flightrec,
             )

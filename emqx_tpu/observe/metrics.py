@@ -151,11 +151,9 @@ ROBUSTNESS_METRIC_NAMES: List[str] = [
 # brownout stage (set: 0-3).  pipeline_inflight is the live count of
 # pipelined batches past dispatch awaiting readback (set, opt-in via
 # match.pipeline.enable); readback_bytes accumulates the d2h bytes the
-# match readback path actually shipped (inc): serial mode ships the
-# one-output program's packed slab, 4·(B + FLAT_MULT·B) per batch group
-# (from a MatchResult four of its fields, 4·(FLAT_MULT·B + 3·B)), the
-# two-phase proportional readback 4·(B + Σcounts), ragged mode
-# 4·(B + pow2 capacity class of Σcounts).  backend_join_dispatches
+# match readback path actually shipped (inc): the served program's one
+# packed array, 4·(B + SERVE_FLAT_MULT·B) per batch group in both serve
+# modes; the mesh its dense compact rows.  backend_join_dispatches
 # counts kernel dispatches served by the relational-join backend (inc,
 # one per depth group; opt-in via match.backend) and autotune_picks the
 # per-shape hash-vs-join measurements the autotuner recorded (inc, one
@@ -163,11 +161,9 @@ ROBUSTNESS_METRIC_NAMES: List[str] = [
 # buffers the readback path fetched (inc, by amount per batch group),
 # each a d2h transfer of its own however many one device_get call
 # names (that call starts them together: not one latency each, PERF.md
-# §6 PR 31): serial mode 1 (the packed array; 4 from a MatchResult,
-# which was counted as 1 before PR 31), the chunked two-phase contract
-# 1 + popcount(Σcounts), ragged ≤ 2, the mesh 1.  Over
-# tpu.match.batches it reads 1.0 where every serial batch was served by
-# the one-output program.
+# §6 PR 31): 1 a batch group (the packed array; the mesh 1 too).  Over
+# tpu.match.batches it reads 1.0 where no batch split into depth
+# groups.
 MATCH_SERVE_METRIC_NAMES: List[str] = [
     "broker.match.deadline_dispatch", "broker.match.cpu_fallback",
     "broker.match.deadline_miss", "broker.match.breaker_state",

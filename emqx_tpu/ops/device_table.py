@@ -11,10 +11,10 @@ reuses ONE compiled scatter per table shape (pre-warmed at upload) —
 XLA recompiles are the p99 killer (SURVEY.md §7).
 
 Threading model (for the asyncio serving path): host mutations and
-``drain()`` happen on the owner (event-loop) thread; ``apply_pending``
-and ``match`` may run on worker threads.  A lock serializes device-op
-*dispatch* (donation invalidates the old buffers, so an unserialized
-late dispatch could touch a deleted array); result readback happens
+``drain()`` happen on the owner (event-loop) thread; ``apply_pending``,
+``serve`` and ``match`` may run on worker threads.  A lock serializes
+device-op *dispatch* (donation invalidates the old buffers, so an
+unserialized late dispatch could touch a deleted array); result readback happens
 outside the lock.  ``arrays()`` returns one atomically-read tuple so a
 reader never sees a half-applied (node, edge) pair.
 """
@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import threading
 from functools import partial
-from typing import NamedTuple, Optional, Sequence, Tuple, Union
+from typing import NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +31,7 @@ import numpy as np
 
 from .incremental import IncrementalNfa, NfaDelta
 from .match_kernel import (
-    MatchResult, nfa_match, nfa_match_donated, nfa_match_packed,
+    SERVE_FLAT_MULT, MatchResult, nfa_match, nfa_match_packed,
 )
 
 __all__ = ["DeviceNfa", "PendingSync", "SCATTER_CHUNK"]
@@ -107,9 +107,10 @@ class PendingSync(NamedTuple):
 
 
 class DeviceNfa:
-    """Live device mirror: ``sync()`` after host mutations, ``match()``
-    to evaluate a batch.  Single-chip twin; the sharded path wraps the
-    same arrays via ``parallel.sharded_match``."""
+    """Live device mirror: ``sync()`` after host mutations, ``serve()``
+    to answer a batch (``match()`` is its reference).  Single-chip
+    twin; the sharded path wraps the same arrays via
+    ``parallel.sharded_match``."""
 
     def __init__(
         self,
@@ -143,12 +144,12 @@ class DeviceNfa:
         self.grow_applies = 0           # in-place grow resizes applied
         self.dirty_rows_uploaded = 0    # rows shipped by scatter/grow
         # optional shape-keyed AOT compile cache (ops/kernel_cache.py):
-        # when set, match() dispatches through pre-compiled executables
+        # when set, serve() dispatches through pre-compiled executables
         # so a table resize never stalls a serve batch on an XLA compile
         self.kernel_cache = None
         # relational-join backend (ops/join_match.py, opt-in): when
         # enabled the device ALSO mirrors the sorted edge relation so
-        # match(backend="join") can serve; maintenance rides the same
+        # serve(backend="join") can answer; maintenance rides the same
         # drain/apply cycle (tombstone/overlay scatters per delta, one
         # rebuild on rehash/compact/overlay-overflow)
         self.join_enabled = False
@@ -459,100 +460,102 @@ class DeviceNfa:
 
     # -- serving -----------------------------------------------------------
 
-    def match(self, words, lens, is_sys, *,
-              flat_cap: int = 0, block_compile: bool = True,
-              donate_inputs: bool = False,
-              backend: Optional[str] = None,
-              packed: bool = False) -> Union[MatchResult, jax.Array]:
-        """Run the kernel on already-encoded operands.  Dispatch happens
-        under the device lock; the returned arrays are futures — callers
-        block (np.asarray) outside any lock.  ``flat_cap`` > 0 selects
-        the flat compacted output (minimal-readback serving mode; see
-        match_kernel.decode_flat).  With a kernel cache attached and
-        ``block_compile=False``, an uncompiled shape raises
-        :class:`~emqx_tpu.ops.kernel_cache.CompileMiss` instead of
-        stalling the caller behind XLA (serving fail-open contract).
-        ``donate_inputs`` hands the batch operand buffers to the kernel
-        (the pipelined serve chain's idiom — the caller must not touch
-        words/lens/is_sys afterwards; same donation contract as
-        ``_scatter_rows``).  ``backend`` selects the edge-structure
-        kernel ("hash" default; "join" rides the sorted-relation mirror
-        and silently falls back to hash while the relation is not yet
-        mirrored — both kernels answer identically; "join-pallas" walks
-        the same relation with the fused Pallas kernel and falls back
-        to "join" when the shape doesn't fit its tiling contract —
-        flat output only, batch a multiple of its tile).  ``packed``
-        (flat mode, operands not donated) asks for the whole answer as
-        ONE ``(B + flat_cap,)`` array, ``row_meta`` then the flat ids
-        (:func:`~emqx_tpu.ops.match_kernel.packed_twin`), from a
-        program with that one output; the hash and join kernels have
-        such a twin, and where the call goes through a kernel cache or
-        the Pallas walk it returns the :class:`MatchResult` as ever, so
-        the caller reads what it was given."""
-        packed = packed and flat_cap > 0 and not donate_inputs
+    def _backend(self, backend: Optional[str], words, flat: bool) -> str:
+        """The kernel that will answer: "hash" unless the relation is
+        mirrored; "join-pallas" only for a flat batch that fits its
+        tile, else "join" (every kernel answers identically)."""
+        be = backend or "hash"
+        if be in ("join", "join-pallas") and self._jarrs is None:
+            return "hash"
+        if be == "join-pallas":
+            from .pallas_match import TILE_B
+
+            b = int(words.shape[0])
+            if not flat or b % min(TILE_B, b):
+                return "join"
+        return be
+
+    def _static(self, flat_cap: int) -> dict:
+        """The kernels' static arguments for this table's knobs."""
+        return dict(active_slots=self.active_slots,
+                    max_matches=self.max_matches,
+                    compact_output=self.compact_output,
+                    flat_cap=flat_cap)
+
+    def _pallas_flat(self, words, lens, is_sys, node, flat_cap: int,
+                     packed: bool = False):
+        from . import pallas_match
+
+        fn = pallas_match.pallas_join_match_packed if packed \
+            else pallas_match.pallas_join_match_flat
+        return fn(
+            words, lens, is_sys, node, *self._jarrs,
+            depth=int(words.shape[1]),
+            active_slots=self.active_slots,
+            max_matches=self.max_matches,
+            flat_cap=flat_cap,
+            interpret=(jax.default_backend() != "tpu"),
+        )
+
+    def match(self, words, lens, is_sys, *, flat_cap: int = 0,
+              backend: Optional[str] = None) -> MatchResult:
+        """The REFERENCE walk on already-encoded operands: every field
+        of the kernel's answer, as lazy device arrays (``flat_cap`` > 0
+        selects the flat compacted output, match_kernel.decode_flat).
+        What parity tests and measurements compare :meth:`serve`
+        against; nothing on a serve path calls it.  ``backend`` as in
+        :meth:`serve`."""
         with self._lock:
             node, edge, seeds = self.arrays()
-            be = backend or "hash"
-            if be in ("join", "join-pallas") and self._jarrs is None:
-                be = "hash"
+            be = self._backend(backend, words, flat_cap > 0)
             if be == "join-pallas":
-                from .pallas_match import TILE_B
+                return self._pallas_flat(words, lens, is_sys, node,
+                                         flat_cap)
+            if be == "join":
+                from .join_match import join_match
 
-                b = int(words.shape[0])
-                if flat_cap <= 0 or b % min(TILE_B, b):
-                    be = "join"
+                return join_match(words, lens, is_sys, node,
+                                  *self._jarrs, **self._static(flat_cap))
+            return nfa_match(words, lens, is_sys, node, edge, seeds,
+                             **self._static(flat_cap))
+
+    def serve(self, words, lens, is_sys, *, block_compile: bool = True,
+              backend: Optional[str] = None) -> jax.Array:
+        """Dispatch one SERVED batch: the answer is ONE lazy
+        ``(B + flat_cap,)`` int32 array, ``flat_cap`` =
+        ``SERVE_FLAT_MULT``·B, from a program with that one output;
+        :func:`~emqx_tpu.ops.match_kernel.decode_packed` fetches and
+        splits it, outside any lock (dispatch alone holds the device
+        lock).  With a kernel cache attached and ``block_compile=False``,
+        an uncompiled shape raises
+        :class:`~emqx_tpu.ops.kernel_cache.CompileMiss` instead of
+        stalling the caller behind XLA (serving fail-open contract).
+        ``backend`` selects the edge-structure kernel ("hash" default;
+        "join" rides the sorted-relation mirror and silently falls back
+        to hash while the relation is not yet mirrored; "join-pallas"
+        walks the same relation with the fused Pallas kernel and falls
+        back to "join" when the batch is not a multiple of its tile)."""
+        flat_cap = SERVE_FLAT_MULT * int(words.shape[0])
+        with self._lock:
+            node, edge, seeds = self.arrays()
+            be = self._backend(backend, words, True)
+            tabs = (node, edge, seeds) if be == "hash" \
+                else (node,) + tuple(self._jarrs)
+            static = self._static(flat_cap)
             kc = self.kernel_cache
             if kc is not None and self.device is None:
                 fn = kc.executable(
                     tuple(words.shape), int(node.shape[0]),
-                    int(edge.shape[0]),
-                    active_slots=self.active_slots,
-                    max_matches=self.max_matches,
-                    compact_output=self.compact_output,
-                    flat_cap=flat_cap,
-                    donate=donate_inputs,
-                    backend=be,
-                    block=block_compile,
-                )
-                if be in ("join", "join-pallas"):
-                    return fn(words, lens, is_sys, node, *self._jarrs)
-                return fn(words, lens, is_sys, node, edge, seeds)
+                    int(edge.shape[0]), backend=be,
+                    block=block_compile, **static)
+                return fn(words, lens, is_sys, *tabs)
             if be == "join-pallas":
-                import jax
+                return self._pallas_flat(words, lens, is_sys, node,
+                                         flat_cap, packed=True)
+            from .join_match import join_match_packed
 
-                from .pallas_match import pallas_join_match_flat
-
-                return pallas_join_match_flat(
-                    words, lens, is_sys, node, *self._jarrs,
-                    depth=int(words.shape[1]),
-                    active_slots=self.active_slots,
-                    max_matches=self.max_matches,
-                    flat_cap=flat_cap,
-                    interpret=(jax.default_backend() != "tpu"),
-                )
-            if be == "join":
-                from .join_match import (
-                    join_match, join_match_donated, join_match_packed,
-                )
-
-                jfn = (join_match_packed if packed else
-                       join_match_donated if donate_inputs else join_match)
-                return jfn(
-                    words, lens, is_sys, node, *self._jarrs,
-                    active_slots=self.active_slots,
-                    max_matches=self.max_matches,
-                    compact_output=self.compact_output,
-                    flat_cap=flat_cap,
-                )
-            fn = (nfa_match_packed if packed else
-                  nfa_match_donated if donate_inputs else nfa_match)
-            return fn(
-                words, lens, is_sys, node, edge, seeds,
-                active_slots=self.active_slots,
-                max_matches=self.max_matches,
-                compact_output=self.compact_output,
-                flat_cap=flat_cap,
-            )
+            fn = nfa_match_packed if be == "hash" else join_match_packed
+            return fn(words, lens, is_sys, *tabs, **static)
 
     def match_names(self, names: Sequence[str], batch: Optional[int] = None):
         """Encode + match a batch of topic names (encode must run on the

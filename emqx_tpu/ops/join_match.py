@@ -78,8 +78,7 @@ from .compiler import BUCKET_SLOTS
 log = logging.getLogger(__name__)
 
 __all__ = ["OVERLAY_CAP", "OVERLAY_EMPTY", "JoinRelation", "OverlayFull",
-           "join_match", "join_match_donated", "join_match_packed",
-           "relation_capacity",
+           "join_match", "join_match_packed", "relation_capacity",
            "BackendAutotuner"]
 
 #: overlay rows available between rebuilds.  Small on purpose: the
@@ -213,16 +212,12 @@ def _jit_twins():
 
     statics = tuple(_MATCH_STATIC) + ("linear_overlay",)
     fn = jax.jit(_join_match, static_argnames=statics)
-    # pipelined twin: batch operands donated, table/relation arrays NOT
-    # (they serve every in-flight batch) — same contract as nfa_match
-    fn_d = jax.jit(_join_match, static_argnames=statics,
-                   donate_argnums=(0, 1, 2))
-    # serial-readback twin: the flat answer as ONE array
+    # the served twin: the flat answer as ONE array
     fn_p = jax.jit(packed_twin(_join_match), static_argnames=statics)
-    return fn, fn_d, fn_p
+    return fn, fn_p
 
 
-join_match, join_match_donated, join_match_packed = _jit_twins()
+join_match, join_match_packed = _jit_twins()
 
 
 # ---------------------------------------------------------------------------

@@ -10,8 +10,10 @@ the serve plane browns out to the host path for the whole window.
 
 This cache closes that window two ways:
 
-* **AOT executables** — keys compile via ``jit(nfa_match).lower(...).
-  compile()`` against :class:`jax.ShapeDtypeStruct` operands (no dummy
+* **AOT executables** — keys compile via ``jit(...).lower(...).
+  compile()`` (the served one-output program for a flat key, the
+  ``MatchResult`` one for a compact key) against
+  :class:`jax.ShapeDtypeStruct` operands (no dummy
   arrays materialized, no device upload paid just to warm a shape) and
   the resulting ``Compiled`` is what serving dispatches through, so the
   compile-or-hit decision is explicit and countable (the compile-counter
@@ -37,9 +39,12 @@ log = logging.getLogger(__name__)
 
 __all__ = ["MatchKernelCache", "CompileMiss"]
 
-#: (B, D, S, Hb, active_slots, max_matches, compact, flat_cap, donate,
-#: backend, mesh).  ``backend`` selects the kernel family: "hash" is
-#: the cuckoo-probe nfa_match, "join" the sorted-relation kernel
+#: (B, D, S, Hb, active_slots, max_matches, compact, flat_cap, backend,
+#: mesh).  ``flat_cap`` > 0 names a SERVED shape: its executable is the
+#: one-output program whose answer ``match_kernel.decode_packed`` reads
+#: (the Pallas walk's is still a ``MatchResult``, which
+#: ``DeviceNfa.serve`` packs).  ``backend`` selects the kernel family:
+#: "hash" is the cuckoo-probe nfa_match, "join" the sorted-relation kernel
 #: (ops/join_match.py) whose edge-structure shapes DERIVE from the same
 #: (S, Hb) pair (relation capacity = Hb * BUCKET_SLOTS), so one shape
 #: key covers both families; "join-pallas" is the same join relation
@@ -53,7 +58,7 @@ __all__ = ["MatchKernelCache", "CompileMiss"]
 #: hits without ever parking behind XLA — and installs a
 #: ``mesh_lower`` hook the cache delegates those keys to; the same
 #: prewarm/CompileMiss contract then covers the mesh step.
-Key = Tuple[int, int, int, int, int, int, bool, int, bool, str,
+Key = Tuple[int, int, int, int, int, int, bool, int, str,
             Optional[Tuple[int, ...]]]
 
 
@@ -71,11 +76,10 @@ class MatchKernelCache:
         self._inflight: Set[Key] = set()
         self._lock = threading.Lock()
         self._done = threading.Condition(self._lock)
-        # every (B, D, A, K, compact, flat_cap, donate, backend, mesh)
-        # combo ever requested: what prewarm_shape replays against the
-        # NEXT table shape
-        self._combos: Set[Tuple[int, int, int, int, bool, int,
-                                bool, str,
+        # every (B, D, A, K, compact, flat_cap, backend, mesh) combo
+        # ever requested: what prewarm_shape replays against the NEXT
+        # table shape
+        self._combos: Set[Tuple[int, int, int, int, bool, int, str,
                                 Optional[Tuple[int, ...]]]] = set()
         # mesh-key lowering hook, installed by the multichip matcher
         # that owns the mesh (the cache itself stays mesh-agnostic)
@@ -97,17 +101,16 @@ class MatchKernelCache:
     def key(batch_shape: Tuple[int, int], s: int, hb: int, *,
             active_slots: int, max_matches: int,
             compact_output: bool, flat_cap: int,
-            donate: bool = False, backend: str = "hash",
+            backend: str = "hash",
             mesh: Optional[Tuple[int, ...]] = None) -> Key:
         b, d = batch_shape
         return (b, d, s, hb, active_slots, max_matches,
-                bool(compact_output), flat_cap, bool(donate), backend,
-                mesh)
+                bool(compact_output), flat_cap, backend, mesh)
 
     def executable(self, batch_shape: Tuple[int, int], s: int, hb: int, *,
                    active_slots: int, max_matches: int,
                    compact_output: bool, flat_cap: int,
-                   donate: bool = False, backend: str = "hash",
+                   backend: str = "hash",
                    mesh: Optional[Tuple[int, ...]] = None,
                    block: bool = True):
         """The compiled executable for these operand shapes — cached, or
@@ -119,10 +122,9 @@ class MatchKernelCache:
         k = self.key(batch_shape, s, hb, active_slots=active_slots,
                      max_matches=max_matches,
                      compact_output=compact_output, flat_cap=flat_cap,
-                     donate=donate, backend=backend, mesh=mesh)
+                     backend=backend, mesh=mesh)
         with self._lock:
-            self._combos.add((k[0], k[1], k[4], k[5], k[6], k[7], k[8],
-                              k[9], k[10]))
+            self._combos.add((k[0], k[1]) + k[4:])
             fn = self._compiled.get(k)
             if fn is not None:
                 self.hits += 1
@@ -159,12 +161,12 @@ class MatchKernelCache:
     def warmed(self, batch_shape: Tuple[int, int], s: int, hb: int, *,
                active_slots: int, max_matches: int,
                compact_output: bool, flat_cap: int,
-               donate: bool = False, backend: str = "hash",
+               backend: str = "hash",
                mesh: Optional[Tuple[int, ...]] = None) -> bool:
         k = self.key(batch_shape, s, hb, active_slots=active_slots,
                      max_matches=max_matches,
                      compact_output=compact_output, flat_cap=flat_cap,
-                     donate=donate, backend=backend, mesh=mesh)
+                     backend=backend, mesh=mesh)
         with self._lock:
             return k in self._compiled
 
@@ -180,10 +182,10 @@ class MatchKernelCache:
         out = []
         seen = set()
         for combo in combos:
-            backends = (combo[7],) if combo[8] is not None \
-                else (combo[7],) + extra
+            backends = (combo[6],) if combo[7] is not None \
+                else (combo[6],) + extra
             for be in backends:
-                c = combo[:7] + (be,) + combo[8:]
+                c = combo[:6] + (be,) + combo[7:]
                 if c not in seen:
                     seen.add(c)
                     out.append(c)
@@ -195,8 +197,8 @@ class MatchKernelCache:
         combos = self._expanded_combos()
         with self._lock:
             return bool(combos) and all(
-                (b, d, s, hb, a, m, c, f, dn, be, mesh) in self._compiled
-                for (b, d, a, m, c, f, dn, be, mesh) in combos
+                (b, d, s, hb, a, m, c, f, be, mesh) in self._compiled
+                for (b, d, a, m, c, f, be, mesh) in combos
             )
 
     def prewarm_shape(self, s: int, hb: int) -> int:
@@ -205,8 +207,8 @@ class MatchKernelCache:
         resize free — for every backend ``auto`` may route to.
         Returns the number of fresh compiles."""
         n = 0
-        for (b, d, a, m, c, f, dn, be, mesh) in self._expanded_combos():
-            k = (b, d, s, hb, a, m, c, f, dn, be, mesh)
+        for (b, d, a, m, c, f, be, mesh) in self._expanded_combos():
+            k = (b, d, s, hb, a, m, c, f, be, mesh)
             with self._lock:
                 if k in self._compiled:
                     continue
@@ -236,7 +238,7 @@ class MatchKernelCache:
                 self._done.notify_all()
 
     def _lower(self, k: Key):
-        if k[10] is not None:
+        if k[9] is not None:
             if self.mesh_lower is None:
                 raise RuntimeError(
                     "mesh-keyed compile requested but no mesh_lower "
@@ -249,16 +251,18 @@ class MatchKernelCache:
     def lowering(k: Key, sharding: Any = None):
         """``(jitted fn, operand ShapeDtypeStructs, static kwargs)`` of
         the single-device executable ``k`` names — what :meth:`_lower`
-        compiles.  ``sharding`` places every operand (the chip compile
-        rehearsal in tests/test_chip_compile.py passes a described
-        device, so it compiles exactly the served program)."""
+        compiles: for a served shape (``flat_cap`` > 0) the program
+        whose one output is the packed array.  ``sharding`` places every
+        operand (the chip compile rehearsal in tests/test_chip_compile.py
+        passes a described device, so it compiles exactly the served
+        program)."""
         import jax
         import jax.numpy as jnp
 
         from .compiler import BUCKET_SLOTS
-        from .match_kernel import nfa_match, nfa_match_donated
+        from .match_kernel import nfa_match, nfa_match_packed
 
-        b, d, s, hb, a, m, compact, flat_cap, donate, backend, _mesh = k
+        b, d, s, hb, a, m, compact, flat_cap, backend, _mesh = k
         i32 = jnp.int32
 
         def sd(shape, dtype=i32):
@@ -283,25 +287,21 @@ class MatchKernelCache:
                 sd((OVERLAY_CAP, 3)),             # overlay
             )
             if backend == "join":
-                from .join_match import join_match, join_match_donated
+                from .join_match import join_match, join_match_packed
 
-                fn = join_match_donated if donate else join_match
+                fn = join_match_packed if flat_cap > 0 else join_match
                 return fn, batch + relation, static
-            from .pallas_match import (
-                pallas_join_match_flat, pallas_join_match_flat_donated,
-            )
+            from .pallas_match import pallas_join_match_packed
 
             if flat_cap <= 0:
                 raise ValueError(
                     "join-pallas backend is flat-output only "
                     "(flat_cap > 0 required)")
-            fn = (pallas_join_match_flat_donated if donate
-                  else pallas_join_match_flat)
             del static["compact_output"]
             static.update(depth=d,
                           interpret=(jax.default_backend() != "tpu"))
-            return fn, batch + relation, static
-        fn = nfa_match_donated if donate else nfa_match
+            return pallas_join_match_packed, batch + relation, static
+        fn = nfa_match_packed if flat_cap > 0 else nfa_match
         return fn, batch + (
             sd((hb, BUCKET_SLOTS * 4)),           # edge_tab
             sd((2,)),                             # seeds

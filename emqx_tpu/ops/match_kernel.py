@@ -46,10 +46,9 @@ import numpy as np
 from .compiler import BUCKET_SLOTS, NfaTable, encode_topics
 
 __all__ = ["MatchResult", "SERVE_FLAT_MULT", "build_matcher",
-           "decode_flat", "decode_row_meta", "fetch_flat_prefix",
-           "fetch_flat_ragged", "match_topics", "nfa_match",
-           "nfa_match_donated", "nfa_match_packed", "nfa_walk",
-           "packed_twin", "ragged_capacity"]
+           "decode_flat", "decode_packed", "decode_row_meta",
+           "match_topics", "nfa_match", "nfa_match_packed", "nfa_walk",
+           "packed_twin"]
 
 # serving flat-output capacity per padded batch row (ids/topic): shared
 # by every serving engine so the fan-out tuning cannot drift between
@@ -63,84 +62,35 @@ SERVE_FLAT_MULT = 8
 
 #: ``row_meta`` packing: low 16 bits = per-row flat-buffer entry count
 #: (min(n, K)); bit 16 = the row's fail-open flag (active-set OR match
-#: overflow).  One (B,) vector carries everything a two-phase readback
-#: needs, so phase 1 of a match-proportional d2h costs 4·B bytes, not
-#: the 12·B of fetching counts + both overflow vectors separately.
+#: overflow).  One (B,) vector carries everything the host needs to
+#: split the flat id buffer into rows.
 ROW_META_COUNT_MASK = 0xFFFF
 ROW_META_SPILL_SHIFT = 16
 
 
 def decode_row_meta(meta: np.ndarray):
     """(B,) packed row_meta → (per-row flat entry counts, spilled rows
-    bool) — the host half of the two-phase readback contract."""
+    bool)."""
     return (meta & ROW_META_COUNT_MASK), (meta >> ROW_META_SPILL_SHIFT) > 0
 
 
-def fetch_flat_prefix(matches, total: int) -> np.ndarray:
-    """Phase 2 of the two-phase readback: ship EXACTLY the first
-    ``total`` ids of the flat buffer with a BOUNDED executable set.
+def decode_packed(packed, n: int, k: int):
+    """The ONE host decode of a served batch's answer: ``(rows, spilled
+    row indices)`` of its first ``n`` rows.
 
-    A naive ``matches[:total]`` compiles one XLA slice per distinct
-    total — unbounded compile churn on the serve path (measured: the
-    pipelined p99 collapsed under it).  Instead the prefix is fetched
-    by binary decomposition into pow2-sized ``dynamic_slice`` chunks:
-    the slice SIZE is static (one executable per (buffer, pow2) pair,
-    ≤ log2(flat_cap) of them ever) and the offset rides as a traced
-    scalar, so arbitrary totals reuse the same executables.  Bytes
-    shipped = 4·total exactly; chunk count ≤ log2(total)+1 (the right
-    trade where the d2h path is bandwidth-bound)."""
-    import jax
-
-    if total <= 0:
-        return np.empty(0, np.int32)
-    parts = []
-    off = 0
-    bit = 1 << (int(total).bit_length() - 1)
-    rem = int(total)
-    while rem:
-        if rem >= bit:
-            chunk = jax.lax.dynamic_slice(
-                matches, (jnp.int32(off),), (bit,))
-            parts.append(np.asarray(jax.device_get(chunk)))
-            off += bit
-            rem -= bit
-        bit >>= 1
-    return np.concatenate(parts) if len(parts) > 1 else parts[0]
-
-
-def ragged_capacity(total: int, flat_cap: int) -> int:
-    """Capacity class for a ragged single-transfer readback: the
-    smallest pow2 ≥ ``total``, clipped to the flat buffer size.  The
-    class set is what bounds the executable count (≤ log2(flat_cap)
-    distinct slice sizes per buffer shape — the same discipline as the
-    binary decomposition, reused by :func:`fetch_flat_ragged`)."""
-    if total <= 0:
-        return 0
-    return min(1 << max(0, int(total) - 1).bit_length(), int(flat_cap))
-
-
-def fetch_flat_ragged(matches, total: int) -> np.ndarray:
-    """Single-transfer twin of :func:`fetch_flat_prefix`: ship the
-    first ``total`` ids of the flat buffer in ONE d2h.
-
-    The chunked decomposition keeps bytes exact (4·total) but pays one
-    d2h round trip per set bit of ``total`` — on a high-latency link
-    p99 tracks RTT·popcount instead of kernel time.  Here the prefix
-    is fetched as ONE ``dynamic_slice`` padded up to its pow2
-    **capacity class** (:func:`ragged_capacity`) and trimmed on host:
-    the slice SIZE stays static (the executables are the SAME
-    (buffer, pow2) pairs the chunked path compiles, so mode flips
-    never grow the executable set) and the transfer count is exactly
-    one.  Bytes shipped = 4·capacity ≤ 8·total — the padding is the
-    price of the round trip, which is the right trade whenever RTT
-    beats bandwidth."""
-    import jax
-
-    if total <= 0:
-        return np.empty(0, np.int32)
-    cap = ragged_capacity(total, int(matches.shape[0]))
-    chunk = jax.lax.dynamic_slice(matches, (jnp.int32(0),), (cap,))
-    return np.asarray(jax.device_get(chunk))[:int(total)]
+    The served format, owned by this module: one ``(B + flat_cap,)``
+    int32 array, ``flat_cap`` = ``SERVE_FLAT_MULT``·B — the (B,)
+    ``row_meta`` vector, then the flat ids, each row's ``min(n, K)``
+    back to back in row order (-1 behind the last).  :func:`packed_twin`
+    builds it on the device; ``DeviceNfa.serve`` is the one way to ask
+    for it; this function fetches it (ONE device buffer) and splits it.
+    Spilled rows carry truncated segments: callers re-run those on the
+    host trie (fail-open)."""
+    packed = jax.device_get(packed)
+    B = packed.size // (1 + SERVE_FLAT_MULT)
+    nk, sp = decode_row_meta(packed[:B])
+    rows = [seg.tolist() for seg in decode_flat(packed[B:], nk, k)[:n]]
+    return rows, np.flatnonzero(sp[:n]).tolist()
 
 
 class MatchResult(NamedTuple):
@@ -150,8 +100,8 @@ class MatchResult(NamedTuple):
     active_overflow: jax.Array  # (B,) int32 — per-row active-set spills
     match_overflow: jax.Array   # (B,) int32 — 1 where count > K (flat
                            # mode: also rows truncated by the global cap)
-    # flat mode only: packed per-row metadata for match-proportional
-    # two-phase readback (see decode_row_meta); None otherwise
+    # flat mode only: packed per-row metadata (see decode_row_meta);
+    # None otherwise
     row_meta: Optional[jax.Array] = None
 
     def spilled_rows(self):
@@ -219,12 +169,11 @@ def flat_epilogue(flat, n, aover, max_matches: int, flat_cap: int):
     """The fused on-device compaction epilogue for flat serving mode:
     per-row top-K compaction, a GLOBAL cumsum-offset scatter into one
     ``(flat_cap,)`` buffer, and the packed ``row_meta`` vector — the
-    dense (row, accept-id) list is produced entirely on device, so a
-    two-phase readback ships 4·B meta bytes + 4·Σcounts id bytes
-    instead of the 4·flat_cap slab.  Shared by :func:`nfa_match` and
-    the pallas walk (:func:`~emqx_tpu.ops.pallas_match
-    .pallas_small_match_flat`) so both backends honor one readback
-    contract.  Returns ``(matches, mover, row_meta)``."""
+    dense (row, accept-id) list is produced entirely on device.  Shared
+    by :func:`nfa_match` and the pallas walk
+    (:func:`~emqx_tpu.ops.pallas_match.pallas_small_match_flat`) so
+    both backends honor one readback contract.  Returns ``(matches,
+    mover, row_meta)``."""
     K = max_matches
     per_row = _compact(flat, K)                        # (B, K)
     nk = jnp.minimum(n, K)
@@ -330,10 +279,8 @@ def nfa_walk(
         )
         row_meta = None
         if flat_cap:
-            # flat mode: the fused compaction epilogue — readback shrinks
-            # from B·K·4 bytes to ~avg_fanout·4 bytes per topic: d2h is
-            # the slower direction of the host link, and every byte of it
-            # sits on the serving path.
+            # flat mode: the fused compaction epilogue, so that the
+            # served answer is one small array (decode_packed)
             matches, mover, row_meta = flat_epilogue(
                 flat, n, aover, K, flat_cap)
         elif compact_output:
@@ -381,27 +328,18 @@ def _nfa_match(
 _MATCH_STATIC = ("active_slots", "max_matches", "compact_output",
                  "flat_cap")
 
-#: the shipping entry point — one compilation per shape bucket
+#: the reference entry point (``MatchResult``) — one compilation per
+#: shape bucket
 nfa_match = jax.jit(_nfa_match, static_argnames=_MATCH_STATIC)
-
-#: pipelined-serving twin: the batch operands (words, lens, is_sys) are
-#: DONATED to the kernel (the ``_scatter_rows`` idiom — the dispatch
-#: consumes the uploaded buffers, so a double-buffered serve chain
-#: never holds two generations of encode buffers on device).  Table
-#: arrays are NOT donated: they serve every in-flight batch.
-nfa_match_donated = jax.jit(_nfa_match, static_argnames=_MATCH_STATIC,
-                            donate_argnums=(0, 1, 2))
 
 
 def packed_twin(match):
-    """The twin of a flat-mode match function whose WHOLE answer is one
-    ``(B + flat_cap,)`` int32 array: ``row_meta`` (counts + fail-open
-    flags, :func:`decode_row_meta`) then the flat ids.  Not a sixth
-    output beside the five: on the attached chip every buffer a call
-    takes or returns costs 0.06–0.07 ms of its dispatch (the runtime
-    allocates each) and a little of its fetch, whatever its size, so
-    the serial slab readback, which needs all of the answer and nothing
-    else, asks for a program with ONE output (PERF.md §6, PR 31).  The
+    """The SERVED twin of a flat-mode match function: its WHOLE answer
+    is one ``(B + flat_cap,)`` int32 array, ``row_meta`` then the flat
+    ids (the format :func:`decode_packed` reads).  One output, not five:
+    on the attached chip every buffer a call takes or returns costs
+    0.06–0.09 ms of its dispatch (the runtime allocates each) and a
+    little of its fetch, whatever its size (PERF.md §6, PR 31).  The
     other fields are dead code to that program; XLA drops them."""
     def packed(*operands, **static):
         res = match(*operands, **static)
@@ -412,18 +350,9 @@ def packed_twin(match):
     return packed
 
 
-#: the serial serve path's entry point (flat mode only: ``flat_cap`` > 0)
+#: the served hash program (flat mode only: ``flat_cap`` > 0)
 nfa_match_packed = jax.jit(packed_twin(_nfa_match),
                            static_argnames=_MATCH_STATIC)
-
-# a donated operand whose shape no kernel output can alias degrades to
-# a plain argument; XLA warns once per compile, which is noise on the
-# serve path (the donation is best-effort by design)
-import warnings as _warnings  # noqa: E402 — scoped to the filter below
-
-_warnings.filterwarnings(
-    "ignore", message="Some donated buffers were not usable",
-    category=UserWarning)
 
 
 def build_matcher(active_slots: int = 16, max_matches: int = 32):

@@ -42,8 +42,9 @@ from .compiler import BUCKET_SLOTS
 
 __all__ = ["pallas_small_match", "pallas_small_match_flat",
            "pallas_join_match", "pallas_join_match_flat",
-           "pallas_join_match_flat_donated", "supports_table",
-           "supports_join_table", "bench_pallas_small"]
+           "pallas_join_match_packed",
+           "supports_table", "supports_join_table",
+           "bench_pallas_small"]
 
 VMEM_BUDGET_BYTES = 8 << 20   # tables beyond this stay on nfa_match
 TILE_B = 256                  # batch rows per grid step
@@ -264,9 +265,9 @@ def pallas_small_match_flat(words, lens, is_sys, node_tab, edge_tab,
     """Pallas walk + the SHARED flat compaction epilogue
     (:func:`~emqx_tpu.ops.match_kernel.flat_epilogue`): the dense
     (row, accept-id) list and the packed ``row_meta`` vector are
-    produced on device, so the match-proportional two-phase readback
-    contract holds identically for both kernel backends — the VMEM
-    walk fuses straight into the cumsum-offset scatter under one jit.
+    produced on device, so one readback contract holds for both
+    kernel backends — the VMEM walk fuses straight into the
+    cumsum-offset scatter under one jit.
     Returns the same :class:`~emqx_tpu.ops.match_kernel.MatchResult`
     layout as ``nfa_match(flat_cap=...)``."""
     from .match_kernel import MatchResult, flat_epilogue
@@ -358,15 +359,20 @@ def _pallas_join_match_flat(words, lens, is_sys, node_tab, state_start,
 
 #: Pallas join walk + the SHARED flat compaction epilogue — the same
 #: readback contract as ``nfa_match(flat_cap=...)`` / ``join_match``,
-#: so the two-phase (and ragged) d2h decode is backend-agnostic.
+#: so the host decode is backend-agnostic.
 pallas_join_match_flat = jax.jit(
     _pallas_join_match_flat, static_argnames=_JOIN_FLAT_STATIC)
 
-#: pipelined twin: batch operands donated, table/relation arrays NOT
-#: (they serve every in-flight batch) — the nfa_match_donated contract
-pallas_join_match_flat_donated = jax.jit(
-    _pallas_join_match_flat, static_argnames=_JOIN_FLAT_STATIC,
-    donate_argnums=(0, 1, 2))
+
+def _pallas_join_match_packed(*operands, **static):
+    r = _pallas_join_match_flat(*operands, **static)
+    return jnp.concatenate([r.row_meta, r.matches])
+
+
+#: The same walk with the SERVED answer as its one output: ``row_meta``
+#: then the flat ids (``match_kernel.decode_packed``).
+pallas_join_match_packed = jax.jit(
+    _pallas_join_match_packed, static_argnames=_JOIN_FLAT_STATIC)
 
 
 def bench_pallas_small(n_filters: int = 50_000, batch: int = 8192,

@@ -1320,7 +1320,7 @@ class MultichipMatcher:
         from ..ops.compiler import BUCKET_SLOTS
 
         b, d, s, hb = key[0], key[1], key[2], key[3]
-        mk = key[10]
+        mk = key[9]
         _dp, _tp, acap, kind, cap, sm, hbm, am, wcap, km = mk[:10]
         owner = int(mk[10]) if len(mk) > 10 else 0
         step = build_multichip_step(

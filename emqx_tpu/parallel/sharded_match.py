@@ -156,9 +156,7 @@ def build_sharded_matcher_compact(
     shard — the cross-chip output is per-topic dense global subscriber
     ids + counts (4·(tp·cap_row + tp) bytes/topic, matches-proportional
     with cap_row sized to the fan-out tail) instead of the full (B, W)
-    bitmap tile (W words/topic ≈ 1.2 MB/topic at 10M filters).  The
-    readback-side contract mirrors the serve plane's two-phase d2h:
-    counts first, then the dense segments."""
+    bitmap tile (W words/topic ≈ 1.2 MB/topic at 10M filters)."""
     repl = P()
 
     @partial(

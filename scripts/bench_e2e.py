@@ -814,7 +814,6 @@ def main(argv=None) -> dict:
         _table_lifecycle_size, bench_adversarial, bench_config1,
         bench_config1_sweep, bench_fanout_e2e, bench_kernel_join_smoke,
         bench_qos1_e2e, bench_qos2_e2e, bench_serve_deadline_smoke,
-        bench_serve_pipeline_smoke, bench_serve_roundtrip_smoke,
         bench_table_lifecycle,
     )
 
@@ -841,18 +840,6 @@ def main(argv=None) -> dict:
     # structure + delivery per PR; the real ratio comes from bench.py
     out["serve_deadline"] = bench_serve_deadline_smoke(
         seconds=(1.2 if args.smoke else 4.0))
-    # overlapped serve pipeline A/B (ISSUE 11): serial round trips vs
-    # the double-buffered chain with match-proportional two-phase
-    # readback, same offered load; gates ride the JSON with the
-    # host-dependent p99 bound (1-core hosts can't overlap stages)
-    out["serve_pipeline"] = bench_serve_pipeline_smoke(
-        seconds=(1.2 if args.smoke else 4.0))
-    # one-round-trip serve A/B (ISSUE 17): chunked vs ragged readback
-    # transfer shape at equal load — the ≤2-round-trip and bit-parity
-    # gates are CI-asserted; the latency ratio is a tracking number
-    # (loopback d2h has no RTT for the single transfer to win back)
-    out["serve_roundtrip"] = bench_serve_roundtrip_smoke(
-        seconds=(1.0 if args.smoke else 3.0))
     # streaming table lifecycle A/B (ISSUE 9): segment cold start vs
     # full rebuild + churn soak across live compaction swaps
     out["table_lifecycle"] = bench_table_lifecycle(
@@ -902,12 +889,6 @@ def main(argv=None) -> dict:
         if sec and "gate_hist_parity" in sec:
             assert sec["gate_hist_parity"], (
                 "serve_deadline histogram/np.percentile parity broke",
-                side, sec)
-    for side in ("serial", "pipeline"):
-        sec = out["serve_pipeline"].get(side)
-        if sec and "gate_hist_parity" in sec:
-            assert sec["gate_hist_parity"], (
-                "serve_pipeline histogram/np.percentile parity broke",
                 side, sec)
     # staticcheck gate row (ISSUE 19): the cold full-tree scan must
     # stay clean (exit 0, zero live waivers) and under the bench-box

@@ -39,13 +39,6 @@ def pytest_configure(config):
         "slow: long-running (bench smoke, multihost) — excluded from "
         "tier-1 via -m 'not slow'",
     )
-    # Donated-operand kernels (the serve pipeline's nfa_match_donated)
-    # warn once per compile when a donated buffer can't be aliased —
-    # best-effort donation by design (match_kernel.py filters this in
-    # production; pytest's per-test filter reset needs the ini form).
-    config.addinivalue_line(
-        "filterwarnings",
-        "ignore:Some donated buffers were not usable")
 
 # The unit suite runs on the CPU whatever the machine holds: pin the
 # config too (it wins over anything that set jax_platforms earlier)

@@ -61,42 +61,31 @@ def _compile(key, sharding, **steer):
     return fn.lower(*args, **static).compile()
 
 
-def _key(depth, *, flat, donate=False, backend="hash", s=S, hb=HB):
+def _key(depth, *, flat, backend="hash", s=S, hb=HB):
     return MatchKernelCache.key(
         (B, depth), s, hb, active_slots=A, max_matches=K,
         compact_output=True, flat_cap=SERVE_FLAT_MULT * B if flat else 0,
-        donate=donate, backend=backend)
+        backend=backend)
 
 
-@pytest.mark.parametrize("depth,flat,donate", [
-    (LANES[1], True, False),    # the serve dispatch, long lane
-    (LANES[0], True, False),    # ... short lane
-    (LANES[1], False, False),   # compact (B, K) output
-    (LANES[1], True, True),     # pipeline mode's donated twin
-    (LANES[0], False, True),
-])
-def test_nfa_match_compiles_for_v5e(one_chip, depth, flat, donate):
-    compiled = _compile(_key(depth, flat=flat, donate=donate), one_chip)
+@pytest.mark.parametrize("depth", [LANES[1], LANES[0]])
+def test_nfa_match_compiles_for_v5e(one_chip, depth):
+    """The reference program, compact (B, K) output, both lanes."""
+    compiled = _compile(_key(depth, flat=False), one_chip)
     mem = compiled.memory_analysis()
     # the two table operands alone are 2^21 * (16 + 32) bytes
     assert mem.argument_size_in_bytes >= S * 16 + HB * 32
 
 
-def test_join_match_compiles_for_v5e(one_chip):
-    _compile(_key(LANES[1], flat=True, backend="join"), one_chip)
-
-
 @pytest.mark.parametrize("depth", [LANES[1], LANES[0]])
-def test_packed_twin_compiles_for_v5e(one_chip, depth):
-    """What a default node's serial path dispatches since PR 31: the
-    same operands and statics, ONE (B + flat_cap,) output."""
-    from emqx_tpu.ops.match_kernel import nfa_match_packed
-
-    _fn, args, static = MatchKernelCache.lowering(
-        _key(depth, flat=True), sharding=one_chip)
-    compiled = nfa_match_packed.lower(*args, **static).compile()
-    assert compiled.memory_analysis().output_size_in_bytes == \
-        4 * (B + SERVE_FLAT_MULT * B)
+@pytest.mark.parametrize("backend", ["hash", "join"])
+def test_served_program_compiles_for_v5e(one_chip, backend, depth):
+    """What every single-chip serve path dispatches (``DeviceNfa.serve``,
+    with or without a kernel cache): ONE (B + flat_cap,) output."""
+    compiled = _compile(_key(depth, flat=True, backend=backend), one_chip)
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == 4 * (B + SERVE_FLAT_MULT * B)
+    assert mem.argument_size_in_bytes >= S * 16
 
 
 # Mosaic's verdict on the in-VMEM table gathers, taken 2026-09-26 with

@@ -98,9 +98,8 @@ def test_duration_and_size_coercion_via_env():
 
 def test_schema_clamps_multichip_autotune_keys():
     """ISSUE 20 registry hygiene: the autotune keys validate their
-    documented ranges, and ``match.readback.auto_slack`` is a
-    FRACTION — values outside [0, 1] are config errors, not silent
-    extrapolation."""
+    documented ranges; the readback-mode keys are gone (ISSUE 32) and
+    rejected as every unknown key is."""
     cfg = Config(env={})
     assert cfg.get("match.multichip.ep.autotune.enable") is False
     cfg.put("match.multichip.ep.autotune.enable", True)
@@ -120,9 +119,5 @@ def test_schema_clamps_multichip_autotune_keys():
         cfg.put("match.multichip.ep.autotune.max_cap_class", -1)
     with pytest.raises(ValueError):
         cfg.put("match.multichip.ep.autotune.max_moved_roots", 5000)
-    cfg.put("match.readback.auto_slack", 0.0)
-    cfg.put("match.readback.auto_slack", 1.0)
-    with pytest.raises(ValueError):
-        cfg.put("match.readback.auto_slack", 1.5)
-    with pytest.raises(ValueError):
-        cfg.put("match.readback.auto_slack", -0.1)
+    with pytest.raises(ValueError, match="unknown config key"):
+        cfg.put("match.readback.mode", "ragged")

@@ -29,6 +29,7 @@ from emqx_tpu.ops.join_match import (
     relation_capacity,
 )
 from emqx_tpu.ops.kernel_cache import CompileMiss, MatchKernelCache
+from emqx_tpu.ops.match_kernel import decode_packed
 
 
 def run(coro):
@@ -292,17 +293,16 @@ def test_compile_miss_raised_for_uncompiled_join_shape():
     dev.kernel_cache = kc
     enc = encode_batch(inc, ["a/k"], batch=64)
     with pytest.raises(CompileMiss):
-        dev.match(*enc, flat_cap=8 * 64, block_compile=False,
-                  backend="join")
+        dev.serve(*enc, block_compile=False, backend="join")
     import time
 
     for _ in range(400):
         if kc.info()["entries"]:
             break
         time.sleep(0.02)
-    res = dev.match(*enc, flat_cap=8 * 64, block_compile=False,
-                    backend="join")
-    np.asarray(res.matches)
+    rows, _sp = decode_packed(
+        dev.serve(*enc, block_compile=False, backend="join"), 1, 16)
+    assert rows == [[inc.aid_of("a/+")]]
     assert kc.hits >= 1
 
 
@@ -324,8 +324,7 @@ def test_prewarm_covers_both_backends_under_auto_zero_compile():
     dev.sync()
     enc = encode_batch(inc, ["a/3/k"], batch=64)
     # observe the combo via the HASH backend only (the auto cold path)
-    np.asarray(dev.match(*enc, flat_cap=8 * 64,
-                         backend="hash").matches)
+    np.asarray(dev.serve(*enc, backend="hash"))
     s, hb, _d = inc.shape_key()
     kc.prewarm_shape(2 * s, hb)
     assert kc.shape_covered(2 * s, hb)
@@ -336,9 +335,9 @@ def test_prewarm_covers_both_backends_under_auto_zero_compile():
     assert inc.shape_key() == (2 * s, hb, 8)
     enc = encode_batch(inc, ["b/5/x"], batch=64)
     # the first JOIN dispatch on the fresh shape: zero compiles
-    res = dev.match(*enc, flat_cap=8 * 64, block_compile=False,
-                    backend="join")
-    np.asarray(res.matches)
+    rows, _sp = decode_packed(
+        dev.serve(*enc, block_compile=False, backend="join"), 1, 16)
+    assert rows == [[inc.aid_of("b/5/x")]]
     assert kc.compiles == compiles0, \
         "auto-routed join dispatch on a prewarmed shape paid a compile"
 
@@ -351,10 +350,10 @@ def test_prewarm_single_backend_unchanged_without_auto():
     kc = MatchKernelCache()
     dev.kernel_cache = kc
     enc = encode_batch(inc, ["a/k"], batch=64)
-    np.asarray(dev.match(*enc, flat_cap=8 * 64).matches)
+    np.asarray(dev.serve(*enc))
     n = kc.prewarm_shape(128, inc.Hb)
     assert n == 1       # one combo, one backend, one fresh shape
-    assert all(k[9] == "hash" for k in kc._compiled)
+    assert all(k[8] == "hash" for k in kc._compiled)
 
 
 # ---------------------------------------------------------------------------

@@ -20,6 +20,7 @@ from emqx_tpu.observe.metrics import Metrics
 from emqx_tpu.ops.device_table import DeviceNfa
 from emqx_tpu.ops.incremental import IncrementalNfa
 from emqx_tpu.ops.kernel_cache import CompileMiss, MatchKernelCache
+from emqx_tpu.ops.match_kernel import decode_packed
 from emqx_tpu.storage.segments import (
     SegmentError, load_segment, restore_incremental, save_segment,
 )
@@ -235,7 +236,7 @@ def test_prewarmed_resize_serves_with_zero_compiles():
         inc.add(f"a/{i}/+")
     dev.sync()
     enc = encode_batch(inc, ["a/3/k"], batch=64)
-    np.asarray(dev.match(*enc, flat_cap=8 * 64).matches)   # observe combo
+    np.asarray(dev.serve(*enc))          # observe combo
     s, hb, _d = inc.shape_key()
     kc.prewarm_shape(2 * s, hb)         # the next pow2 state shape
     compiles0 = kc.compiles
@@ -245,8 +246,8 @@ def test_prewarmed_resize_serves_with_zero_compiles():
     dev.sync()
     assert inc.shape_key() == (2 * s, hb, 8)
     enc = encode_batch(inc, ["b/5/x"], batch=64)
-    res = dev.match(*enc, flat_cap=8 * 64, block_compile=False)
-    np.asarray(res.matches)
+    rows, _sp = decode_packed(dev.serve(*enc, block_compile=False), 1, 16)
+    assert rows == [[inc.aid_of("b/5/x")]]
     assert kc.compiles == compiles0, "resize serve paid a compile"
     assert kc.hits > hits0
 
@@ -261,7 +262,7 @@ def test_compile_miss_raises_instead_of_stalling():
     dev.kernel_cache = kc
     enc = encode_batch(inc, ["a/k"], batch=64)
     with pytest.raises(CompileMiss):
-        dev.match(*enc, flat_cap=8 * 64, block_compile=False)
+        dev.serve(*enc, block_compile=False)
     # the miss kicked a background compile: the same key eventually hits
     import time
 
@@ -269,8 +270,8 @@ def test_compile_miss_raises_instead_of_stalling():
         if kc.info()["entries"]:
             break
         time.sleep(0.02)
-    np.asarray(dev.match(*enc, flat_cap=8 * 64,
-                         block_compile=False).matches)
+    rows, _sp = decode_packed(dev.serve(*enc, block_compile=False), 1, 16)
+    assert rows == [[inc.aid_of("a/+")]]
     assert kc.hits >= 1
 
 

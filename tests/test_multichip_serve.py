@@ -114,9 +114,9 @@ def test_compact_rows_bit_for_bit_vs_host_and_single_chip():
     """The dense compact contract off the mesh must reproduce the
     single-chip serve path's rows bit-for-bit (same service accept
     ids) and agree with the host walk on every topic."""
-    from emqx_tpu.broker.match_service import MatchService
     from emqx_tpu.ops import encode_batch
     from emqx_tpu.ops.device_table import DeviceNfa
+    from emqx_tpu.ops.match_kernel import decode_packed
 
     inc, mc, _pairs = build_pair()
     dev = DeviceNfa(inc, active_slots=8, max_matches=16)
@@ -124,8 +124,7 @@ def test_compact_rows_bit_for_bit_vs_host_and_single_chip():
     rows8, sp8, nbytes = mesh_rows(mc, topics)
     assert nbytes > 0
     enc = encode_batch(inc, topics, batch=64)
-    res = dev.match(*enc, flat_cap=8 * 64)
-    rows1, sp1 = MatchService._readback_rows(res, len(topics), 16)
+    rows1, sp1 = decode_packed(dev.serve(*enc), len(topics), 16)
     assert not sp8 and not sp1
     for t, r8, r1 in zip(topics, rows8, rows1):
         assert sorted(r8) == sorted(r1) == sorted(inc.match_host(t)), t

@@ -206,8 +206,8 @@ def test_flat_output_parity_and_truncation():
 
 def test_row_meta_packs_counts_and_spill_flags():
     """Flat mode's packed (B,) row_meta vector (ISSUE 11): low 16 bits
-    = min(n, K), bit 16 = the fail-open flag — ONE tiny d2h carries
-    everything a two-phase readback needs; non-flat modes carry None."""
+    = min(n, K), bit 16 = the fail-open flag — everything the host
+    needs to split the flat ids; non-flat modes carry None."""
     from emqx_tpu.ops.match_kernel import decode_row_meta
 
     t = compile_filters(FILTERS, depth=16, state_bucket=8)
@@ -228,51 +228,3 @@ def test_row_meta_packs_counts_and_spill_flags():
     # non-flat modes: no meta output
     assert nfa_match(*args, active_slots=16, max_matches=K
                      ).row_meta is None
-
-
-def test_fetch_flat_prefix_exact_and_bounded_executables():
-    """Phase 2 of the two-phase readback ships EXACTLY total ids via
-    pow2 binary decomposition — parity with a host slice for arbitrary
-    totals, including 0 and the full buffer."""
-    from emqx_tpu.ops.match_kernel import fetch_flat_prefix
-
-    buf = jnp.asarray(np.arange(937, dtype=np.int32))
-    for total in (0, 1, 2, 3, 7, 64, 100, 511, 937):
-        got = fetch_flat_prefix(buf, total)
-        np.testing.assert_array_equal(
-            got, np.arange(total, dtype=np.int32))
-
-
-def test_donated_kernel_variant_matches_and_consumes_inputs():
-    """nfa_match_donated: identical results, operand buffers donated
-    (the pipelined serve chain's contract — nothing may reuse them)."""
-    import jax
-
-    from emqx_tpu.ops.match_kernel import nfa_match_donated
-
-    t = compile_filters(FILTERS, depth=16, state_bucket=8)
-    words, lens, is_sys = encode_topics(t, TOPICS)
-    tabs = [jnp.asarray(a) for a in t.device_arrays()]
-    K = 8
-    ref = nfa_match(jnp.asarray(words), jnp.asarray(lens),
-                    jnp.asarray(is_sys), *tabs,
-                    active_slots=16, max_matches=K, flat_cap=128)
-    jw, jl, js = (jnp.asarray(words), jnp.asarray(lens),
-                  jnp.asarray(is_sys))
-    got = nfa_match_donated(jw, jl, js, *tabs,
-                            active_slots=16, max_matches=K,
-                            flat_cap=128)
-    np.testing.assert_array_equal(np.asarray(ref.matches),
-                                  np.asarray(got.matches))
-    np.testing.assert_array_equal(np.asarray(ref.row_meta),
-                                  np.asarray(got.row_meta))
-    # at least one operand buffer was really donated (deleted)
-    def deleted(a):
-        try:
-            jax.device_get(a)
-            return False
-        except RuntimeError:
-            return True
-    assert any(deleted(a) for a in (jw, jl, js))
-    # table arrays are NOT donated: they serve every in-flight batch
-    assert not any(deleted(a) for a in tabs)

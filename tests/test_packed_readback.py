@@ -1,9 +1,9 @@
-"""One result, one trip (ISSUE 31): the serial serve path asks for a
-program whose WHOLE answer is one packed array (``row_meta`` then the
-flat ids), the serial readback fetches that array alone, and the
-counters say how many device buffers a batch really fetched.  The
-pipelined two-phase, ragged and mesh readbacks are untouched
-(tests/test_match_pipeline.py)."""
+"""One answer from the device (ISSUES 31, 32): every single-chip serve
+path asks for a program whose WHOLE answer is one packed array
+(``row_meta`` then the flat ids), ``match_kernel.decode_packed`` is the
+one host decode of it, and the counters say how many device buffers a
+batch really fetched.  The five-output ``nfa_match`` / ``join_match``
+stay as the reference these tests compare it against."""
 
 import asyncio
 from functools import lru_cache
@@ -21,7 +21,7 @@ from emqx_tpu.ops import (
     compile_filters, encode_batch, encode_topics, nfa_match,
 )
 from emqx_tpu.ops.match_kernel import (
-    SERVE_FLAT_MULT, MatchResult, nfa_match_packed,
+    SERVE_FLAT_MULT, decode_flat, decode_packed, nfa_match_packed,
 )
 
 K = 12          # max_matches: above SERVE_FLAT_MULT, so a batch of
@@ -48,15 +48,35 @@ FILTERS = (
     + ["s/a/b/z", "s/a/+/z", "s/+/b/z", "s/+/+/z", "+/a/b/z", "+/a/+/z"]
 )
 FULL, OVER, SPILL = f"k/{TAIL}", f"m/{TAIL}", "s/a/b/z"
+ONE = "x/a/c/z"                         # matches +/a/+/z alone
 
-# case → the topic of every fourth row (None: no such row) and of the
-# rows between; "past_flat_cap" fills every row with K ids, 12·n > 8·B
+
+def _every4(every4, rest):
+    """``every4`` on every fourth row (None: no such row), ``rest`` on
+    the rows between."""
+    return lambda i, bucket: (every4 if every4 and i % 4 == 0
+                              else rest.format(i=i))
+
+
+def _sum_counts(off):
+    """One id a row on the first ``bucket // 2 + off`` rows, none after:
+    the batch's Σcounts sits on a power of two or one beside it."""
+    return lambda i, bucket: ONE if i < bucket // 2 + off else f"zero/{i}"
+
+
+# case → row i's topic in a batch padded to ``bucket``.  "past_flat_cap"
+# fills every row with K ids, 12·n > 8·B; "all_spill" flags every row
+# (n > K on every fourth, the active set on the rest) under the cap
 CASES = {
-    "zero_matches": (None, "zero/{i}"),
-    "n_eq_k": (FULL, "zero/{i}"),
-    "n_gt_k": (OVER, "zero/{i}"),
-    "active_spill": (SPILL, "zero/{i}"),
-    "past_flat_cap": (FULL, FULL),
+    "zero_matches": _every4(None, "zero/{i}"),
+    "n_eq_k": _every4(FULL, "zero/{i}"),
+    "n_gt_k": _every4(OVER, "zero/{i}"),
+    "active_spill": _every4(SPILL, "zero/{i}"),
+    "past_flat_cap": _every4(FULL, FULL),
+    "all_spill": _every4(OVER, SPILL),
+    "sum_pow2_less_1": _sum_counts(-1),
+    "sum_pow2": _sum_counts(0),
+    "sum_pow2_plus_1": _sum_counts(1),
 }
 BUCKETS = (64, 128, 256, 512, 1024, 2048)
 
@@ -73,6 +93,17 @@ def _match(fn, names, bucket):
     return fn(jnp.asarray(words), jnp.asarray(lens), jnp.asarray(is_sys),
               *tabs, active_slots=A, max_matches=K,
               flat_cap=SERVE_FLAT_MULT * bucket)
+
+
+def _four_array_decode(res, n, k):
+    """The reference decode: the five-output program's flat ids, counts
+    and both overflow vectors, fetched and ORed on the host."""
+    matches, nk, aover, mover = jax.device_get(
+        (res.matches, res.n_matches, res.active_overflow,
+         res.match_overflow))
+    sp = (aover > 0) | (mover > 0)
+    rows = [seg.tolist() for seg in decode_flat(matches, nk, k)[:n]]
+    return rows, np.flatnonzero(sp[:n]).tolist()
 
 
 def _count_arrays_fetched(monkeypatch):
@@ -92,10 +123,8 @@ def _count_arrays_fetched(monkeypatch):
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_packed_readback_parity_with_four_array_decode(
         case, bucket, monkeypatch):
-    every4, rest = CASES[case]
     n = bucket - 3                      # the last rows are padding
-    names = [every4 if every4 and i % 4 == 0 else rest.format(i=i)
-             for i in range(n)]
+    names = [CASES[case](i, bucket) for i in range(n)]
     res = _match(nfa_match, names, bucket)
     packed = _match(nfa_match_packed, names, bucket)
     assert packed.shape == (bucket + SERVE_FLAT_MULT * bucket,)
@@ -106,12 +135,9 @@ def test_packed_readback_parity_with_four_array_decode(
                         np.asarray(res.matches)]))
 
     seen = _count_arrays_fetched(monkeypatch)
-    rows, spilled = MatchService._readback_rows(packed, n, K)
+    rows, spilled = decode_packed(packed, n, K)
     assert seen["n"] == 1
-    assert MatchService._readback_cost(packed) == (4 * packed.size, 1)
-    rows4, spilled4 = MatchService._readback_rows(res, n, K)
-    assert seen["n"] == 1 + 4
-    assert (rows, spilled) == (rows4, spilled4)
+    assert (rows, spilled) == _four_array_decode(res, n, K)
 
     # the batch is the case it says it is, and unspilled rows are exact
     t, _tabs = _table()
@@ -126,6 +152,14 @@ def test_packed_readback_parity_with_four_array_decode(
         assert len(rows[0]) == K and spilled == list(range(0, n, 4))
     elif case == "active_spill":
         assert spilled == list(range(0, n, 4))
+    elif case == "all_spill":
+        assert spilled == list(range(n))
+        assert all(len(r) == K for r in rows[::4])
+    elif case.startswith("sum_pow2"):
+        off = {"sum_pow2_less_1": -1, "sum_pow2": 0,
+               "sum_pow2_plus_1": 1}[case]
+        assert sum(map(len, rows)) == bucket // 2 + off
+        assert spilled == []
     else:
         whole = SERVE_FLAT_MULT * bucket // K   # rows wholly under the cap
         assert spilled == list(range(whole, n))
@@ -206,10 +240,13 @@ class _Service:
 
 
 @pytest.mark.parametrize("backend", ["hash", "join"])
-def test_serial_batch_counts_one_trip_and_the_packed_bytes(backend):
+@pytest.mark.parametrize("mode", ["serial", "pipeline"])
+def test_serial_batch_counts_one_trip_and_the_packed_bytes(mode, backend):
+    """Both serve loops read the same one answer: 1 buffer and
+    4·(B + flat_cap) bytes a group."""
     async def main():
-        async with _Service(backend=backend) as s:
-            assert s.ms._packed_serve
+        async with _Service(backend=backend,
+                            pipeline=(mode == "pipeline")) as s:
             assert backend == "hash" or await _settle(
                 lambda: s.ms.dev._jarrs is not None)
             hints, added = await s.serve(TOPICS)
@@ -246,75 +283,28 @@ def test_serial_batch_serves_inside_a_profiler_session(tmp_path):
     asyncio.run(main())
 
 
-def test_match_result_takes_four_trips(monkeypatch):
-    """A backend or mode without the one-output program hands the serial
-    readback a ``MatchResult``: the four-array fetch stays, and the
-    counters say 4 where they used to say 1."""
-    async def main():
-        async with _Service() as s:
-            ms = s.ms
-            handles, _e, _d = ms._encode_dispatch(
-                ms.inc, ms.dev, TOPICS,
-                [(list(range(len(TOPICS))), ms.depth)], False)
-            (packed, n), = handles
-            assert not isinstance(packed, MatchResult)
-            enc = encode_batch(ms.inc, TOPICS, batch=64, depth=ms.depth)
-            res = ms.dev.match(*enc, flat_cap=SERVE_FLAT_MULT * 64)
-            assert isinstance(res, MatchResult)
-            one, b1, _ns, t1 = ms._readback_groups(handles, ms.dev, False)
-            seen = _count_arrays_fetched(monkeypatch)
-            four, b4, _ns, t4 = ms._readback_groups(
-                [(res, n)], ms.dev, False)
-            assert seen["n"] == t4 == 4 and t1 == 1
-            assert b1 == 4 * (64 + SERVE_FLAT_MULT * 64)
-            assert b4 == 4 * (SERVE_FLAT_MULT * 64 + 3 * 64)
-            assert four == one
-
-    asyncio.run(main())
-
-
-@pytest.mark.parametrize("kw", [
-    {"pipeline": True}, {"readback_mode": "ragged"},
-], ids=["pipeline", "ragged"])
-def test_other_readbacks_are_handed_the_match_result(kw):
-    """Only the serial slab readback asks for the one-output program:
-    the two-phase contracts slice ``row_meta`` and ``matches`` on the
-    device and get the ``MatchResult`` they always got."""
-    async def main():
-        async with _Service(**kw) as s:
-            ms = s.ms
-            assert not ms._packed_serve
-            handles, _e, _d = ms._encode_dispatch(
-                ms.inc, ms.dev, TOPICS,
-                [(list(range(len(TOPICS))), ms.depth)], False)
-            (res, n), = handles
-            assert isinstance(res, MatchResult)
-            rows2, sp2, _b, _t = ms._readback_rows_twophase(
-                res, n, ms.dev.max_matches)
-            assert (rows2, sp2) == ms._device_rows(
-                encode_batch(ms.inc, TOPICS, batch=64, depth=ms.depth), n)
-
-    asyncio.run(main())
-
-
-def test_kernel_cache_serves_the_match_result(tmp_path):
+def test_kernel_cache_serves_the_packed_array_one_buffer(tmp_path):
     """Through a kernel cache (``match.segments.enable``) the AOT
-    executables are the five-output program: the serial readback reads
-    four of them and the counters say so."""
+    executable of a served shape is the one-output program: the batch
+    fetches one buffer and mints the host trie's hints."""
     async def main():
         async with _Service(segments=True,
                             segments_dir=str(tmp_path)) as s:
-            assert s.ms._packed_serve and s.ms.dev.kernel_cache is not None
+            kc = s.ms.dev.kernel_cache
+            assert kc is not None
             enc = encode_batch(s.ms.inc, TOPICS, batch=64, depth=s.ms.depth)
-            assert isinstance(
-                s.ms.dev.match(*enc, flat_cap=SERVE_FLAT_MULT * 64,
-                               packed=True), MatchResult)
+            hits = kc.hits
+            packed = s.ms.dev.serve(*enc, block_compile=False)
+            assert kc.hits == hits + 1      # warmed: no CompileMiss
+            assert packed.shape == (64 + SERVE_FLAT_MULT * 64,)
             plain_hints = {t: [f for f in SUBS if T.match(t, f)]
                            for t in TOPICS}
             hints, added = await s.serve(TOPICS)
             assert {t: h[0] for t, h in hints.items()} == plain_hints
             assert added["tpu.match.readback_roundtrips"] == \
-                4 * added["tpu.match.batches"] > 0
+                added["tpu.match.batches"] > 0
+            assert added["tpu.match.readback_bytes"] == \
+                4 * (64 + SERVE_FLAT_MULT * 64) * added["tpu.match.batches"]
             assert added["broker.match.cpu_fallback"] == 0
 
     asyncio.run(main())

@@ -45,7 +45,7 @@ def test_pallas_flat_epilogue_parity_interpret():
     """The SHARED flat compaction epilogue rides the pallas walk too
     (ISSUE 11): pallas_small_match_flat produces the same dense flat
     buffer + packed row_meta as nfa_match(flat_cap=...), so both
-    backends honor one two-phase readback contract."""
+    backends honor one readback contract."""
     from emqx_tpu.ops.match_kernel import decode_flat, decode_row_meta
     from emqx_tpu.ops.pallas_match import pallas_small_match_flat
 
@@ -223,8 +223,8 @@ def test_pallas_join_fallback_paths(monkeypatch):
 
 def test_pallas_join_kernel_cache_backend():
     """The join-pallas backend is a first-class kernel-cache citizen:
-    a cached dispatch compiles once, hits after, and returns the lax
-    join's exact bits; lowering it without a flat cap is a contract
+    a cached served dispatch compiles once, hits after, and returns the
+    lax join's exact bits as the one packed array; lowering it without a flat cap is a contract
     error (flat-output only)."""
     import pytest as _pytest
 
@@ -235,19 +235,19 @@ def test_pallas_join_kernel_cache_backend():
     kc = MatchKernelCache()
     dev.kernel_cache = kc
     enc = encode_batch(inc, JOIN_TOPICS, batch=16)
-    cap = 8 * 16
-    want = dev.match(*enc, backend="join", flat_cap=cap)
-    rp = dev.match(*enc, backend="join-pallas", flat_cap=cap)
-    _assert_flat_parity(want, rp, "cache first")
+    want = dev.match(*enc, backend="join", flat_cap=8 * 16)
+    want = np.concatenate([np.asarray(want.row_meta),
+                           np.asarray(want.matches)])
+    rp = dev.serve(*enc, backend="join-pallas")
+    np.testing.assert_array_equal(want, np.asarray(rp), "cache first")
     compiles = kc.compiles
-    rp2 = dev.match(*enc, backend="join-pallas", flat_cap=cap)
-    _assert_flat_parity(want, rp2, "cache hit")
+    rp2 = dev.serve(*enc, backend="join-pallas")
+    np.testing.assert_array_equal(want, np.asarray(rp2), "cache hit")
     assert kc.compiles == compiles    # pure hit, no recompile
     assert kc.hits >= 1
     s, hb, _d = inc.shape_key()
     with _pytest.raises(ValueError):
-        kc._lower((16, 8, s, hb, 8, 16, True, 0, False,
-                   "join-pallas", None))
+        kc._lower((16, 8, s, hb, 8, 16, True, 0, "join-pallas", None))
 
 
 def test_pallas_join_excluded_from_auto_prewarm_cross():
