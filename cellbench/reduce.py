@@ -4,13 +4,21 @@ The generator returns per-publish ``due``/``sent``/``acked`` and
 per-delivery ``(subscriber, seq, received)``; the node's histograms are
 read as bucket counts before and after the window.  Everything that
 turns those into a metric is here, so that no change to the program can
-change how a number is computed."""
+change how a number is computed; the one thing taken from the program is
+the histograms' bucket layout, the format of its counts."""
 
 from __future__ import annotations
 
 import math
 
 import numpy as np
+
+# The bucket layout has one owner, the module that writes the counts (no
+# JAX behind this import).  tests/test_stage_spans.py holds the export to
+# 16 sub-buckets an octave and 688 buckets, and reads it as ``_SUB_BITS``
+# and ``bucket_bounds`` from here.
+from emqx_tpu.observe.hist import SUB_BITS as _SUB_BITS     # noqa: F401
+from emqx_tpu.observe.hist import bucket_bounds
 
 
 def percentile(arrived_sorted, n_total: int, q: float, missing: float):
@@ -77,21 +85,8 @@ def series_stat(values_ns, n_total, stat: str, missing_ns):
 
 
 # -- the node's fixed-bucket histograms (observe/hist.py layout) ---------
-# 16 linear sub-buckets per octave of nanoseconds; a copy of
-# ``_bucket_bounds`` so that the percentile of a DELTA of two snapshots
-# is computed here (the program's own reader has no delta).
-
-_SUB_BITS = 4
-_SUB = 1 << _SUB_BITS
-
-
-def bucket_bounds(idx: int):
-    if idx < _SUB:
-        return idx, 1
-    k = (idx >> _SUB_BITS) + _SUB_BITS - 1
-    shift = k - _SUB_BITS
-    sub = idx - ((k - _SUB_BITS) << _SUB_BITS)
-    return sub << shift, 1 << shift
+# The percentile of a DELTA of two snapshots is computed here: the
+# program's own reader has no delta.
 
 
 def hist_delta_stat(before, after, stat: str):

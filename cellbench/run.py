@@ -444,6 +444,7 @@ async def measure(dep, mix, rate, seconds, seed, trace, control, compiles,
         workers.tell(f"go {t0}")
         out["warmup_s"] = (t0 - w0) / 1e9
         await at(t0)
+        note(f"window open for {seconds:g} s")
         out["window_start_after_process_s"] = time.monotonic() - T_PROCESS
         c0, h0 = dep.metrics.all(), dep.hist_counts()
 
@@ -461,12 +462,13 @@ async def measure(dep, mix, rate, seconds, seed, trace, control, compiles,
             await loop.run_in_executor(
                 None, lambda: jax.profiler.start_trace(
                     tdir, profiler_options=opts))
-            ta = now_ns()
+            # the slice also on the clock of the stage events' ``t_ns``
+            ta, pa = now_ns(), time.perf_counter_ns()
             await at(t1)
-            tb = now_ns()
+            tb, pb = now_ns(), time.perf_counter_ns()
             c1, h1 = dep.metrics.all(), dep.hist_counts()
             await loop.run_in_executor(None, jax.profiler.stop_trace)
-            tr = (tdir, ta, tb)
+            tr = (tdir, ta, tb, pa, pb)
         else:
             await at(t1)
             c1, h1 = dep.metrics.all(), dep.hist_counts()
@@ -495,13 +497,14 @@ async def measure(dep, mix, rate, seconds, seed, trace, control, compiles,
                 int(x) for x in counts[:, :5].sum(axis=0))
         out["drain_close"] = int(counts[:, 5].max())
         if tr is not None:
-            tdir, ta, tb = tr
+            tdir, ta, tb, pa, pb = tr
             paths = [os.path.join(dp, f) for dp, _d, fs in os.walk(tdir)
                      for f in fs if f.endswith(".xplane.pb")]
             if not paths:
                 raise BenchError("the profiler wrote no .xplane.pb")
-            out["trace"] = RT.reduce(RT.load(paths[0]), (tb - ta) / 1e9)
+            out["trace_rows"] = RT.load(paths[0])
             out["trace_slice"] = (ta, tb)
+            out["trace_slice_t_ns"] = (pa, pb)
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
     return out
@@ -541,17 +544,32 @@ def device_answers(dep, win_topics, seed: int, n: int, control):
     return len(picks), answered, wrong, first
 
 
-def read_layers(want_layers, value_of, must) -> dict:
-    """The per-layer metrics this cell lists.  A reader that finds nothing
-    to read (a histogram or counter that is gone or took no sample, no
-    program of the kernel's name in the trace) returns None: the metric is
-    left out, and where ``must(name)`` says it has to be there the run
-    fails, so that a renamed span, counter or kernel cannot fall silent
-    behind a well-formed line."""
-    layers, silent = {}, []
+# a reader's answer where the running tree has no such histogram or counter
+# (None: it has one, and it is silent)
+ABSENT = object()
+
+
+def read_layers(want_layers, value_of, must):
+    """The per-layer metrics this cell lists, and the names left out
+    because the running tree cannot have them.
+
+    A reader that finds nothing to read returns None (a histogram or
+    counter that took no sample, no program of the kernel's name in the
+    trace): the metric is left out, and where ``must(name)`` says it has
+    to be there the run fails, so that a span, counter or kernel that
+    exists cannot fall silent behind a well-formed line.  A reader returns
+    ``ABSENT`` where the node registers no histogram, or none of the
+    counters, of that name (a tree from before the span was added, which
+    is what the parent of the PR that adds it is): left out, by name, in
+    the second list, and never fatal."""
+    layers, silent, left_out = {}, [], []
     for name, unit in want_layers:
         v = value_of(name)
-        if v is not None:
+        if v is ABSENT:
+            left_out.append(name)
+            note(f"per-layer metric {name}: the node registers nothing of "
+                 "that name, left out")
+        elif v is not None:
             layers[name] = {"value": v, "unit": unit}
         elif must(name):
             silent.append(name)
@@ -560,7 +578,33 @@ def read_layers(want_layers, value_of, must) -> dict:
     if silent:
         raise BenchError("listed for this cell and nothing to read: "
                          + ", ".join(silent))
-    return layers
+    return layers, left_out
+
+
+def hist_value(spec, before: dict, after: dict):
+    """A percentile of what one histogram recorded between two snapshots
+    of the node's histograms: ``ABSENT`` where the node has none of that
+    name, None where it has and nothing was recorded."""
+    name = spec["hist"]
+    if name not in after:
+        return ABSENT
+    return R.hist_delta_stat(before[name], after[name], spec["stat"])
+
+
+def counter_value(spec, delta: dict, publishes: int):
+    """A ratio of counter deltas (``delta`` has every counter the node's
+    registry knows): ``ABSENT`` where it knows none of the metric's
+    counters, None where the denominator did not move."""
+    per_publish = spec["den"] == "window_publishes"
+    names = spec["num"] + ([] if per_publish else spec["den"])
+    if not any(k in delta for k in names):
+        return ABSENT
+    num = sum(delta.get(k, 0) for k in spec["num"])
+    den = publishes if per_publish else sum(
+        delta.get(k, 0) for k in spec["den"])
+    if den <= 0:
+        return None
+    return float(spec.get("scale", 1.0)) * num / den
 
 
 def result_of(dep, mix, m, layer_specs, want_layers, peaks, devs, rehearse):
@@ -598,7 +642,17 @@ def result_of(dep, mix, m, layer_specs, want_layers, peaks, devs, rehearse):
         "acked_minus_due": (acked[acked > 0] - due_abs[acked > 0], n),
         "e2e": (lat, n_expected),
     }
-    tr = m.get("trace")
+    tr = None
+    if "trace_rows" in m:
+        ta, tb = m["trace_slice"]
+        # a served program is known by the word its roofline metric reads
+        tr = RT.reduce(
+            m["trace_rows"], (tb - ta) / 1e9, slice_ns=m["trace_slice_t_ns"],
+            served=[spec["module"] for spec in layer_specs.values()
+                    if spec["kind"] == "trace_roofline"] or None)
+        if tr is not None and tr["idle_note"]:
+            note("idle time not split by stage, the longest gaps are named "
+                 f"as before: {tr['idle_note']}")
     values = {"compiles_in_window": float(m["compiles_in_window"]),
               "full_gc_pause_ms": m.get("full_gc_pause_ms"),
               **{k: v for k, v in m["host"].items()
@@ -610,18 +664,9 @@ def result_of(dep, mix, m, layer_specs, want_layers, peaks, devs, rehearse):
             vals, total = series[spec["series"]]
             return R.series_stat(vals, total, spec["stat"], missing_ns)
         if kind == "hist_delta":
-            name = spec["hist"]
-            if name not in m["h1"]:
-                return None
-            return R.hist_delta_stat(m["h0"][name], m["h1"][name],
-                                     spec["stat"])
+            return hist_value(spec, m["h0"], m["h1"])
         if kind == "counter_ratio":
-            num = sum(d.get(k, 0) for k in spec["num"])
-            den = n if spec["den"] == "window_publishes" else sum(
-                d.get(k, 0) for k in spec["den"])
-            if den <= 0:
-                return None
-            return float(spec.get("scale", 1.0)) * num / den
+            return counter_value(spec, d, n)
         if kind == "harness":
             return values.get(spec["value"])
         if kind == "trace":
@@ -640,7 +685,7 @@ def result_of(dep, mix, m, layer_specs, want_layers, peaks, devs, rehearse):
             return ROOF.roofline_pct(need, secs, peaks)
         raise BenchError(f"unknown per-layer source kind {kind!r}")
 
-    layers = read_layers(
+    layers, left_out = read_layers(
         want_layers, lambda name: layer_value(layer_specs[name]),
         # the line of a traced run is its per-layer metrics: there a
         # silent one is a fault.  Only a run with no device plane (a
@@ -689,6 +734,9 @@ def result_of(dep, mix, m, layer_specs, want_layers, peaks, devs, rehearse):
         "warm_acked": m["warm_acked"], "dup_flagged": m["dup_flagged"],
         "halves": halves, "thirds": thirds, "e2e_p99_ms": e2e["e2e_p99_ms"],
         "layers": {k: v["value"] for k, v in layers.items()},
+        "layers_left_out": left_out,
+        "trace": tr and {k: tr[k] for k in (
+            "device_clock_shift_ms", "modules_in_place_pct", "idle_note")},
         "host": m["host"], "full_gc_pause_ms": m.get("full_gc_pause_ms"),
         "phases_s": dep.phases,
         "device_answers": {"sampled": sampled, "answered": answered,
