@@ -180,6 +180,7 @@ from jax.profiler import TraceAnnotation as _Annot
 
 from .. import faultinject as _fi
 from .. import topic as T
+from ..observe import heap
 from ..observe.span import stage_span
 from ..ops.kernel_cache import CompileMiss
 from ..ops.match_kernel import decode_packed
@@ -956,7 +957,8 @@ class MatchService:
                     # (ADVICE.md round-2 high item 2)
                     self.ready = False
                 await asyncio.to_thread(self.dev.apply_pending, pending)
-                if first or pending.full is not None:
+                whole = first or pending.full is not None
+                if whole:
                     await asyncio.to_thread(self._warm)
                 if self.mc is not None and self.mc.dirty:
                     # shard partition applies in lockstep with the
@@ -964,6 +966,10 @@ class MatchService:
                     await asyncio.to_thread(self._mc_apply)
                 if self.mc is not None:
                     self._mesh_watch()
+                if whole:
+                    # router, sessions' maps, inc's tables and the
+                    # mirror's books are long-lived from here on
+                    heap.settled("full upload")
                 self.ready = True
                 self._synced_epoch = router_epoch
                 self._synced_rule_gen = rule_gen

@@ -27,6 +27,7 @@ from collections import deque
 from typing import Deque, Dict, Hashable, Iterable, List, NamedTuple, Optional, Set, Tuple
 
 from .. import topic as T
+from ..observe import heap
 from .trie import FilterTrie
 
 __all__ = ["Route", "RouteDelta", "Router"]
@@ -57,6 +58,8 @@ class Router:
         # mutation listeners (device-mirror wake-ups); called synchronously
         # after every epoch bump with the new epoch
         self.listeners: List = []
+        # routes held at the last growth freeze (observe/heap.py)
+        self._heap_mark: int = 0
 
     # ------------------------------------------------------------------
     # mutation (emqx_router:do_add_route / do_delete_route)
@@ -71,6 +74,8 @@ class Router:
             dests = table[flt] = set()
             if table is self._wild:
                 self._trie.insert(flt)
+            self._heap_mark = heap.grown(
+                len(self._exact) + len(self._wild), self._heap_mark)
         if dest in dests:
             return False
         dests.add(dest)

@@ -23,6 +23,7 @@ from .broker.cm import ConnectionManager
 from .broker.flapping import Flapping
 from .broker.limiter import LimiterGroup
 from .config import Config
+from .observe import heap
 from .observe.wiring import observe
 from .rule_engine import RuleEngine
 from .services.auto_subscribe import AutoSubscribe
@@ -1343,6 +1344,7 @@ class BrokerNode:
         while self._running:
             await asyncio.sleep(interval)
             try:
+                heap.report(self.observed.metrics)
                 if self.timer_wheel is not None:
                     # aggregate wheel-resident timer gauge: main-loop
                     # wheel + every shard wheel (racy cross-thread int
@@ -1447,6 +1449,7 @@ class BrokerNode:
             "flightrec": self.flightrec.info(),
             "admission": (self.admission.info()
                           if self.admission is not None else None),
+            "gc": heap.report(self.observed.metrics),
             **self.broker.stats(),
         }
 

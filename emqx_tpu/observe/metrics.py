@@ -22,6 +22,7 @@ __all__ = [
     "MATCH_SERVE_METRIC_NAMES", "MULTICHIP_METRIC_NAMES",
     "MESH_METRIC_NAMES", "TABLE_METRIC_NAMES",
     "OBS_METRIC_NAMES", "ADMISSION_METRIC_NAMES",
+    "RUNTIME_METRIC_NAMES",
 ]
 
 # -- the reference's fixed counter names, grouped as in emqx_metrics.erl [U]
@@ -274,6 +275,21 @@ ADMISSION_METRIC_NAMES: List[str] = [
     "messages.dropped.admission_shed",
 ]
 
+# -- host runtime (observe/heap.py): the cyclic collector's permanent
+# generation.  freezes.growth counts freezes made while a route table
+# grew (one each time it stood heap.GROWTH_STEP routes above where it
+# last froze; the mark is the router's and only rises), freezes.settled
+# those made once _sync_loop had landed a whole table on the device
+# (first sync, growth re-upload; NOT a compaction swap or a shard
+# rebuild, which recur); frozen_objects is gc.get_freeze_count() as the
+# last settle read it (the call walks what it counts): what no full
+# pass walks any more.  All three are the PROCESS's (the collector is),
+# sampled into the table by the node's housekeeping (set).
+RUNTIME_METRIC_NAMES: List[str] = [
+    "runtime.gc.freezes.growth", "runtime.gc.freezes.settled",
+    "runtime.gc.frozen_objects",
+]
+
 
 class Metrics:
     """A counter table with the reference's fixed name set.
@@ -296,6 +312,7 @@ class Metrics:
         self._c.update({n: 0 for n in TABLE_METRIC_NAMES})
         self._c.update({n: 0 for n in OBS_METRIC_NAMES})
         self._c.update({n: 0 for n in ADMISSION_METRIC_NAMES})
+        self._c.update({n: 0 for n in RUNTIME_METRIC_NAMES})
         if extra:
             self._c.update({n: 0 for n in extra})
 

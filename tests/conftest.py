@@ -9,9 +9,11 @@ Must run before any test module imports jax, hence env mutation at
 conftest import time.
 """
 
+import gc
 import os
-
 import re
+
+import pytest
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = re.sub(
@@ -48,3 +50,17 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 assert jax.devices()[0].platform == "cpu", jax.devices()
 assert len(jax.devices()) == 8, jax.devices()
+
+
+@pytest.fixture(autouse=True)
+def _thaw_heap():
+    """A node that uploads a table, or grows one by ``heap.GROWTH_STEP``
+    routes, freezes the heap and scales the collector's third threshold
+    (``observe/heap.py``).  Both belong to the process: put them back
+    after each test, so that the suite's memory and every other test's
+    collections are as they were.  (Unfreezing nothing is a list splice
+    of nothing.)"""
+    thresholds = gc.get_threshold()
+    yield
+    gc.unfreeze()
+    gc.set_threshold(*thresholds)
