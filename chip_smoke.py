@@ -119,6 +119,10 @@ async def publish_all(pubs, topics, tag: bytes) -> None:
 def mesh_facts(ms, jax, table_bytes: int) -> dict:
     """Four-chip extras: the mesh is up and every device holds a shard."""
     mc = ms.mc
+    check(not (ms.ready or ms.info()["ready"])
+          or (mc is not None and mc.ready),
+          "the service says ready and the mesh it was configured with "
+          "is not up: ready has to mean the configured plane")
     check(mc is not None and mc.ready,
           "multichip matcher not ready: the one-chip path would serve")
     node_stk, edge_stk = mc._arrs[0], mc._arrs[1]

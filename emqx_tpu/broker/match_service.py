@@ -150,8 +150,17 @@ every MULTICHIP_r05 configuration:
 * maintenance rides the SAME drain/apply cycle: ``_table_add``/
   ``_table_del`` note mutations into per-shard host subtables, the
   sync loop applies deltas off the event loop, a compaction swap
-  repartitions from the fresh aid space (single-chip path serves
-  while the partition rebuilds);
+  repartitions from the fresh aid space (the service is NOT ready
+  and the host trie serves while the partition rebuilds);
+* readiness means the configured plane: with the flag on, ``ready``
+  (and ``info()["ready"]``) is true only once the mesh has been
+  applied and its step shapes are warm.  A constructor or an apply
+  that fails is counted (``tpu.mesh.apply_failed``), logged once per
+  distinct error and retried by the sync loop (1, 2 … 32 s apart);
+  until it lands the host trie serves, as before the first sync.  The
+  one-chip mirror (still built, on the first device) never stands in
+  for a mesh that was asked for, so no line of a four-chip run can
+  come from it;
 * per-shard segments persist next to the main segment with an
   epoch-guarded, checksummed manifest — a cold start only seeds from
   them when the service epoch still matches, else it repartitions;
@@ -483,33 +492,6 @@ class MatchService:
             # auto-routed join dispatch on a fresh shape eats a
             # CompileMiss → CPU hop (ISSUE 13 bugfix)
             self.kcache.auto_backends = ("hash", "join")
-        # multichip serve backend (module docstring; opt-in, flag off
-        # leaves self.mc None and every seam below one None-test so the
-        # single-chip path is byte-identical — spy-asserted)
-        self.mc = None
-        if multichip:
-            try:
-                from ..parallel.multichip_serve import MultichipMatcher
-
-                self.mc = MultichipMatcher(
-                    depth=depth, tp=multichip_tp,
-                    active_slots=active_slots, max_matches=max_matches,
-                    metrics=metrics, kernel_cache=self.kcache,
-                    native=multichip_native, ep=multichip_ep,
-                    ep_slack=multichip_ep_slack,
-                    ep_micro_matches=multichip_ep_micro,
-                    ep_compact=multichip_ep_compact,
-                    degraded=multichip_degraded,
-                    degraded_fail_threshold=multichip_degraded_threshold,
-                    ep_overflow_warn=multichip_ep_overflow_warn,
-                    ep_autotune=multichip_ep_autotune,
-                    ep_grow_threshold=multichip_ep_grow_threshold,
-                    ep_shrink_threshold=multichip_ep_shrink_threshold,
-                    ep_max_cap_class=multichip_ep_max_cap_class,
-                    balance_budget=multichip_balance_budget)
-            except Exception:
-                log.exception("multichip serve backend unavailable; "
-                              "single-chip path serves")
         # degraded-mesh service state (inert unless the mc degraded
         # flag is on): the mesh_degraded alarm latch and the supervised
         # mesh.rebuild child's running flag
@@ -530,7 +512,7 @@ class MatchService:
         self._rule_gen = 0
         self._rule_log: Deque[Tuple[int, Tuple[str, ...]]] = deque(maxlen=512)
 
-        self.ready = False
+        self._mirror_ready = False    # behind the ``ready`` property
         self._seen_epoch = 0          # router delta-log position (drained)
         self._synced_epoch = 0        # router epoch the DEVICE table reflects
         self._synced_rule_gen = 0     # rule gen the device table reflects
@@ -603,8 +585,56 @@ class MatchService:
         self._sp_hop_back = stage_span("match_hop_back", hists, ring_loop)
         self._sp_epilogue = stage_span("match_epilogue", hists, ring_loop)
         self._seq = 0       # batch sequence number: one per popped batch
+        # multichip serve backend (module docstring; opt-in, flag off
+        # leaves self.mc None and every seam below one None-test so the
+        # single-chip path is byte-identical — spy-asserted).  Flag on,
+        # the mesh is the plane that was CONFIGURED: ``ready`` means it
+        # is up, and a constructor that fails is retried by the sync
+        # loop (``_mc_apply``) with the host trie serving meanwhile.
+        self.mc = None
+        self._mc_wanted = bool(multichip)
+        self._mc_args = dict(
+            depth=depth, tp=multichip_tp,
+            active_slots=active_slots, max_matches=max_matches,
+            metrics=metrics, kernel_cache=self.kcache,
+            native=multichip_native, ep=multichip_ep,
+            ep_slack=multichip_ep_slack,
+            ep_micro_matches=multichip_ep_micro,
+            ep_compact=multichip_ep_compact,
+            degraded=multichip_degraded,
+            degraded_fail_threshold=multichip_degraded_threshold,
+            ep_overflow_warn=multichip_ep_overflow_warn,
+            ep_autotune=multichip_ep_autotune,
+            ep_grow_threshold=multichip_ep_grow_threshold,
+            ep_shrink_threshold=multichip_ep_shrink_threshold,
+            ep_max_cap_class=multichip_ep_max_cap_class,
+            balance_budget=multichip_balance_budget,
+            # the serve shapes a whole repartition compiles BEFORE the
+            # matcher says ready (the _warm twin; the short lane too)
+            warm_depths=((self.short_depth, depth)
+                         if self.short_depth and self.short_depth < depth
+                         else (depth,)),
+            # what the mesh adds inside match_readback, written by the
+            # same (single in-flight) readback worker
+            spans=(stage_span("mesh_fetch", hists, ring_rb),
+                   stage_span("mesh_decode", hists, ring_rb)),
+        ) if multichip else None
+        if multichip:
+            self._mc_build()
 
         self.router.listeners.append(self._on_router_mutation)
+
+    @property
+    def ready(self) -> bool:
+        """The CONFIGURED device plane may serve: the one-chip mirror is
+        synced and, with ``match.multichip.enable``, the mesh is applied
+        and warm (``mc.ready``).  A mesh that was asked for and is
+        absent (its constructor failed) or not applied (an apply failed,
+        a compaction swap queued a repartition) leaves this false and
+        the host trie serving, as before the first sync: the one-chip
+        mirror never stands in for it silently."""
+        return self._mirror_ready and (
+            not self._mc_wanted or self._mc_active() is not None)
 
     # ------------------------------------------------------------------
     # lifecycle
@@ -614,15 +644,7 @@ class MatchService:
         self._running = True
         self._bootstrap()
         if self.mc is not None:
-            # seed the shard partition: per-shard segments when the
-            # main table cold-started from ITS segment and the epochs
-            # still agree, else a full repartition from the live aid
-            # space (note_add events during bootstrap are superseded —
-            # rebuild clears the pending log)
-            if not (self.segments and self._segment_loaded
-                    and self.mc.load_segments(self.segments_dir,
-                                              self.inc.epoch)):
-                self.mc.rebuild(self._mc_pairs())
+            self._mc_seed()
         serve_loop = self._deadline_loop if self.deadline \
             else self._batch_loop
         if self.pipeline:
@@ -939,12 +961,13 @@ class MatchService:
         self._seen_epoch = self.router.epoch
 
     async def _sync_loop(self) -> None:
+        mesh_retry_s = 1.0      # doubles while a configured mesh is down
         while True:
             await self._dirty.wait()
             await asyncio.sleep(self.debounce_s)
             self._dirty.clear()
             try:
-                first = not self.ready
+                first = not self._mirror_ready
                 self._drain_router()
                 # epochs the device table will reflect once this sync lands
                 router_epoch = self._seen_epoch
@@ -955,22 +978,34 @@ class MatchService:
                     # jit recompiles; drop readiness so publishes take the
                     # host path instead of stalling on the compile
                     # (ADVICE.md round-2 high item 2)
-                    self.ready = False
+                    self._mirror_ready = False
                 await asyncio.to_thread(self.dev.apply_pending, pending)
                 whole = first or pending.full is not None
                 if whole:
                     await asyncio.to_thread(self._warm)
-                if self.mc is not None and self.mc.dirty:
+                if self._mc_wanted and self.mc is None:
+                    # the constructor failed: try it again, for as
+                    # long as the mesh is absent
+                    self._mc_build()
+                    if self.mc is not None:
+                        self._mc_seed()
+                if self.mc is not None and self.mc.dirty \
+                        and not await asyncio.to_thread(self._mc_apply):
                     # shard partition applies in lockstep with the
-                    # device twin so both reflect _synced_epoch below
-                    await asyncio.to_thread(self._mc_apply)
+                    # device twin so both reflect _synced_epoch below.
+                    # What a failed apply had drained is lost: queue a
+                    # whole repartition from the live aid space
+                    # (mc.ready drops until it lands).  The pairs are
+                    # read HERE, as at every other rebuild: the loop
+                    # owns the books register_rule and the swap write
+                    self.mc.rebuild(self._mc_pairs())
                 if self.mc is not None:
                     self._mesh_watch()
                 if whole:
                     # router, sessions' maps, inc's tables and the
                     # mirror's books are long-lived from here on
                     heap.settled("full upload")
-                self.ready = True
+                self._mirror_ready = True
                 self._synced_epoch = router_epoch
                 self._synced_rule_gen = rule_gen
                 if self.metrics is not None:
@@ -988,6 +1023,16 @@ class MatchService:
                                 "tpu.table.compile_cache_hits",
                                 self.kcache.hits)
                     self._maybe_prewarm()
+                if self._mc_wanted and not self.ready:
+                    # the mesh did not come up (counted and logged in
+                    # _mesh_failed): try again, twice as far off each
+                    # time (a retried apply repartitions the whole
+                    # table); the host trie serves
+                    await asyncio.sleep(mesh_retry_s)
+                    mesh_retry_s = min(2.0 * mesh_retry_s, 32.0)
+                    self._dirty.set()
+                else:
+                    mesh_retry_s = 1.0
             except Exception:
                 log.exception("match-service sync failed; host path serves")
                 await asyncio.sleep(1.0)
@@ -1041,34 +1086,67 @@ class MatchService:
                 out.append((flt, aid))
         return out
 
-    def _mc_apply(self) -> None:
+    def _mc_build(self) -> None:
+        """Construct the mesh matcher: in ``__init__``, and again by
+        the sync loop after a constructor that failed."""
+        try:
+            from ..parallel.multichip_serve import MultichipMatcher
+
+            self.mc = MultichipMatcher(**self._mc_args)
+        except Exception as e:
+            self._mesh_failed("multichip serve backend constructor", e)
+
+    def _mc_seed(self) -> None:
+        """Seed the shard partition: per-shard segments when the main
+        table cold-started from ITS segment and the epochs still
+        agree, else a full repartition from the live aid space
+        (note_add events before it are superseded — rebuild clears the
+        pending log)."""
+        if not (self.segments and self._segment_loaded
+                and self.mc.load_segments(self.segments_dir,
+                                          self.inc.epoch)):
+            self.mc.rebuild(self._mc_pairs())
+
+    def _mesh_failed(self, what: str, e: BaseException) -> None:
+        """The configured mesh did not come up: counted, logged once
+        per distinct error, and ``ready`` stays false (the host trie
+        serves and the sync loop tries again)."""
+        if self.metrics is not None:
+            self.metrics.inc("tpu.mesh.apply_failed")
+        self._warn_device_failure(what, e)
+
+    def _mc_apply(self) -> bool:
         """WORKER-THREAD step: fold the noted mutations (or a queued
         repartition) into the shard subtables + stacked device arrays.
-        Any failure leaves the single-chip path serving — the partition
-        re-applies on the next sync pass."""
+        False where it failed (``_mesh_failed``): the service is then
+        NOT ready, and the sync loop queues the partition again."""
         mc = self.mc
         try:
-            first = not mc.ready
-            if mc.apply_pending() and first:
-                # pre-pay the mesh step compiles for the serve shapes
-                # (the _warm twin); covers the short lane when split
-                depths = ((self.short_depth, self.depth)
-                          if self.short_depth
-                          and self.short_depth < self.depth
-                          else (self.depth,))
-                mc.warm(batches=(64,), depths=depths)
+            mc.apply_pending()
             if self.segments and mc._persist_due:
                 mc.save_segments(self.segments_dir, self.inc.epoch)
-        except Exception:
-            log.exception("multichip apply failed; single-chip path "
-                          "serves")
+        except Exception as e:
+            self._mesh_failed("multichip apply", e)
+            return False
+        return True
 
     def _mc_active(self):
         """The multichip matcher when it may serve the next dispatch,
-        else None (single-chip device path).  One attribute test on the
-        flag-off path."""
+        else None.  One attribute test on the flag-off path."""
         mc = self.mc
         return mc if mc is not None and mc.ready else None
+
+    def _serving_dev(self):
+        """The plane the next dispatch goes to: the mesh where one is
+        configured, else the one-chip mirror.  ``_usable`` has the
+        same rule; this is for a mesh that dropped out since (a
+        compaction swap landed between the two)."""
+        if not self._mc_wanted:
+            return self.dev
+        mc = self._mc_active()
+        if mc is None:
+            raise _StaleRace("configured mesh not ready")
+        return mc
 
     # ------------------------------------------------------------------
     # degraded mesh: health ladder + online shard rebuild
@@ -1393,7 +1471,7 @@ class MatchService:
         self._synced_rule_gen = self._rule_gen
         self._mut_count = len(self._compact_dirty)
         self._compact_dirty = set()
-        self.ready = True
+        self._mirror_ready = True
         if self.mc is not None:
             # the fresh table reassigned EVERY aid: repartition the
             # shard subtables from the new space; mc.ready drops and
@@ -1880,8 +1958,9 @@ class MatchService:
             for res, n in handles:
                 if multichip:
                     # dense compact contract off the mesh, one
-                    # device_get round trip
-                    rows, sp, b = dev.readback(res, n)
+                    # device_get round trip (mesh_fetch, mesh_decode)
+                    rows, sp, b = dev.readback(res, n, seq,
+                                               self._table_gen)
                 else:
                     rows, sp = decode_packed(res, n, dev.max_matches)
                     b = 4 * int(res.size)
@@ -2096,7 +2175,7 @@ class MatchService:
         # The table-gen guard is the segment-swap twin: a compacted
         # table swapped in mid-flight reassigned EVERY aid.
         inc = self.inc
-        dev = self._mc_active() or self.dev
+        dev = self._serving_dev()
         reuses0 = inc.aid_reuses
         gen0 = self._table_gen
         groups = self._depth_groups(topics)
@@ -2506,7 +2585,6 @@ class MatchService:
         epoch = self._synced_epoch
         rule_gen = self._synced_rule_gen
         inc = self.inc
-        dev = self._mc_active() or self.dev
         reuses0 = inc.aid_reuses
         gen0 = self._table_gen
         t0 = time.monotonic()
@@ -2514,6 +2592,7 @@ class MatchService:
             if not self._usable():
                 raise RuntimeError("mirror stale")
             await self._fault_gate()
+            dev = self._serving_dev()
             groups = self._depth_groups(topics)
             dispatch = asyncio.to_thread(
                 self._encode_dispatch, inc, dev, topics, groups, cyc)

@@ -73,6 +73,13 @@ one popped batch share its ``seq``):
                               thread)
 ``obs.stage.match_readback``  d2h readback per batch (worker thread /
                               readback child)
+``obs.stage.mesh_fetch``      inside match_readback, mesh plane only
+                              (``match.multichip.enable``): the
+                              ``device_get`` of the mesh's answer, per
+                              depth group
+``obs.stage.mesh_decode``     after it: compact rows → service accept-id
+                              rows → the spill set; the two tile
+                              ``MultichipMatcher.readback``
 ``obs.stage.match_epilogue``  rows stitched, hints minted, cache evicted
                               (loop)
 ============================  ==============================================
@@ -125,6 +132,8 @@ HIST_NAMES: List[str] = [
     "obs.stage.ingest_queue",
     "obs.stage.intercept",
     "obs.stage.handle_publish",
+    "obs.stage.mesh_fetch",
+    "obs.stage.mesh_decode",
 ]
 
 # -- bucket geometry --------------------------------------------------------

@@ -230,11 +230,14 @@ MULTICHIP_METRIC_NAMES: List[str] = [
 # failover (inc, by amount); rebuild_s is the last online shard
 # rebuild's wall seconds (set); readmit_canary_fails counts re-admit
 # attempts refused because the bit-parity canary batch disagreed with
-# the CPU trie (inc) — the shard stays out.
+# the CPU trie (inc) — the shard stays out.  apply_failed counts the
+# times a CONFIGURED mesh did not come up (match.multichip.enable: the
+# matcher's constructor or a partition apply raised; inc) — the service
+# is not ready until a later sync pass succeeds, the host trie serves.
 MESH_METRIC_NAMES: List[str] = [
     "tpu.mesh.state", "tpu.mesh.degraded_batches",
     "tpu.mesh.cpu_filled_rows", "tpu.mesh.rebuild_s",
-    "tpu.mesh.readmit_canary_fails",
+    "tpu.mesh.readmit_canary_fails", "tpu.mesh.apply_failed",
 ]
 
 # -- streaming table lifecycle (broker/match_service.py, opt-in via

@@ -110,6 +110,7 @@ import logging
 import math
 import os
 import threading
+import time
 import zlib
 from functools import partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -125,6 +126,7 @@ from .. import topic as T
 from .sharded_match import CompactFanoutResult, decode_compact_rows
 
 log = logging.getLogger(__name__)
+_now_ns = time.perf_counter_ns      # the clock of every stage span
 
 __all__ = ["MultichipMatcher", "ShardDead", "build_multichip_step",
            "serve_mesh_shape", "shard_of_filter", "is_micro_filter"]
@@ -240,80 +242,11 @@ def build_multichip_step(mesh, active_slots: int = 16,
         out = out.at[jnp.arange(R)[:, None], pos].set(mg, mode="drop")
         return out, cnt_own + mcnt
 
-    @partial(
-        shard_map,
-        mesh=mesh,
-        in_specs=(
-            P("dp", None),        # words
-            P("dp"),              # lens
-            P("dp"),              # is_sys
-            P("tp", None, None),  # node_stk
-            P("tp", None, None),  # edge_stk
-            P("tp", None),        # seeds_stk
-            P("tp", None),        # aid_stk
-            P(None, None),        # micro_node (replicated)
-            P(None, None),        # micro_edge
-            P(None),              # micro_seeds
-            P(None),              # micro_amap
-            P(None),              # word_owner
-        ),
-        out_specs=CompactFanoutResult(
-            ids=seg_spec,
-            counts=seg_spec,
-            overflow=seg_spec,
-            n_matches=P("dp"),
-            active_overflow=P("dp"),
-            match_overflow=P("dp"),
-        ),
-        check_vma=False,
-    )
-    def step(words, lens, is_sys, node_stk, edge_stk, seeds_stk, aid_stk,
-             micro_node, micro_edge, micro_seeds, micro_amap, word_owner):
-        node, edge, seeds, amap = (
-            node_stk[0], edge_stk[0], seeds_stk[0], aid_stk[0])
-
-        def match_both(w, l, s):
-            res = nfa_match(
-                w, l, s, node, edge, seeds,
-                active_slots=active_slots, max_matches=K,
-            )
-            gids = jnp.where(
-                res.matches >= 0, amap[jnp.maximum(res.matches, 0)], -1)
-            mres = nfa_match(
-                w, l, s, micro_node, micro_edge, micro_seeds,
-                active_slots=active_slots, max_matches=Km,
-            )
-            mg = jnp.where(
-                mres.matches >= 0,
-                micro_amap[jnp.maximum(mres.matches, 0)], -1)
-            return res, gids, mres, mg
-
-        if not routed:
-            res, gids, mres, mg = match_both(words, lens, is_sys)
-            # segments must stay DISJOINT per row: exactly one shard
-            # (the micro owner — shard 0 unless the degraded mesh
-            # migrated the merge point) merges the replicated micro
-            # answers
-            is0 = jax.lax.axis_index("tp") == micro_owner
-            mcnt = jnp.where(is0, jnp.minimum(mres.n_matches, Km), 0)
-            ids, cnt = merge_micro(
-                gids, jnp.minimum(res.n_matches, K), mg, mcnt)
-            seg_ov = (res.match_overflow
-                      + jnp.where(is0, mres.match_overflow, 0))
-            return CompactFanoutResult(
-                ids=ids,
-                counts=cnt[:, None],
-                overflow=seg_ov[:, None],
-                n_matches=jax.lax.psum(
-                    res.n_matches + jnp.where(is0, mres.n_matches, 0),
-                    "tp"),
-                active_overflow=jax.lax.psum(
-                    res.active_overflow
-                    + jnp.where(is0, mres.active_overflow, 0), "tp"),
-                match_overflow=jax.lax.psum(seg_ov, "tp"),
-            )
-
-        # -- EP-routed front end ----------------------------------------
+    def route(words, lens, is_sys, word_owner):
+        """EP front end of one ``tp`` instance: bucket MY source slice
+        of the dp-local batch by root-token owner into a (tp, C) grid
+        and ``all_to_all`` it, so that every row lands on the one shard
+        that owns its root."""
         Bl, D = words.shape
         i = jax.lax.axis_index("tp")
         Bs = Bl // tp
@@ -352,62 +285,151 @@ def build_multichip_step(mesh, active_slots: int = 16,
         l2 = jax.lax.all_to_all(grid_l, "tp", 0, 0, tiled=False)
         s2 = jax.lax.all_to_all(grid_s, "tp", 0, 0, tiled=False)
         src2 = jax.lax.all_to_all(grid_src, "tp", 0, 0, tiled=False)
+        return w2, l2, s2, src2, bucket_ov, start, Bs
 
+    @partial(
+        shard_map,
+        mesh=mesh,
+        in_specs=(
+            P("dp", None),        # words
+            P("dp"),              # lens
+            P("dp"),              # is_sys
+            P("tp", None, None),  # node_stk
+            P("tp", None, None),  # edge_stk
+            P("tp", None),        # seeds_stk
+            P("tp", None),        # aid_stk
+            P(None, None),        # micro_node (replicated)
+            P(None, None),        # micro_edge
+            P(None),              # micro_seeds
+            P(None),              # micro_amap
+            P(None),              # word_owner
+        ),
+        out_specs=CompactFanoutResult(
+            ids=seg_spec,
+            counts=seg_spec,
+            overflow=seg_spec,
+            n_matches=P("dp"),
+            active_overflow=P("dp"),
+            match_overflow=P("dp"),
+        ),
+        check_vma=False,
+    )
+    def mesh_match(words, lens, is_sys, node_stk, edge_stk, seeds_stk,
+                   aid_stk, micro_node, micro_edge, micro_seeds, micro_amap,
+                   word_owner):
+        # the function's name is the XLA module's (``jit_mesh_match``),
+        # and the ``mesh.*`` scopes name its phases in a device trace
+        # beside the ``nfa.*`` scopes of the level walk (PERF.md §3)
+        node, edge, seeds, amap = (
+            node_stk[0], edge_stk[0], seeds_stk[0], aid_stk[0])
+
+        def match_both(w, l, s):
+            with jax.named_scope("mesh.walk"):
+                res = nfa_match(
+                    w, l, s, node, edge, seeds,
+                    active_slots=active_slots, max_matches=K,
+                )
+                gids = jnp.where(
+                    res.matches >= 0, amap[jnp.maximum(res.matches, 0)],
+                    -1)
+            with jax.named_scope("mesh.micro"):
+                mres = nfa_match(
+                    w, l, s, micro_node, micro_edge, micro_seeds,
+                    active_slots=active_slots, max_matches=Km,
+                )
+                mg = jnp.where(
+                    mres.matches >= 0,
+                    micro_amap[jnp.maximum(mres.matches, 0)], -1)
+            return res, gids, mres, mg
+
+        if not routed:
+            res, gids, mres, mg = match_both(words, lens, is_sys)
+            with jax.named_scope("mesh.compact"):
+                # segments must stay DISJOINT per row: exactly one
+                # shard (the micro owner — shard 0 unless the degraded
+                # mesh migrated the merge point) merges the replicated
+                # micro answers
+                is0 = jax.lax.axis_index("tp") == micro_owner
+                mcnt = jnp.where(is0, jnp.minimum(mres.n_matches, Km), 0)
+                ids, cnt = merge_micro(
+                    gids, jnp.minimum(res.n_matches, K), mg, mcnt)
+                seg_ov = (res.match_overflow
+                          + jnp.where(is0, mres.match_overflow, 0))
+                return CompactFanoutResult(
+                    ids=ids,
+                    counts=cnt[:, None],
+                    overflow=seg_ov[:, None],
+                    n_matches=jax.lax.psum(
+                        res.n_matches + jnp.where(is0, mres.n_matches, 0),
+                        "tp"),
+                    active_overflow=jax.lax.psum(
+                        res.active_overflow
+                        + jnp.where(is0, mres.active_overflow, 0), "tp"),
+                    match_overflow=jax.lax.psum(seg_ov, "tp"),
+                )
+
+        # -- EP-routed front end ----------------------------------------
+        with jax.named_scope("mesh.route"):
+            w2, l2, s2, src2, bucket_ov, start, Bs = route(
+                words, lens, is_sys, word_owner)
+        Bl, D = words.shape
         R = tp * C
         res, gids, mres, mg = match_both(
             w2.reshape(R, D), l2.reshape(R), s2.reshape(R))
         # the owner is the ONLY shard seeing this row: merge micro here
-        merged, merged_cnt = merge_micro(
-            gids, jnp.minimum(res.n_matches, K),
-            mg, jnp.minimum(mres.n_matches, Km))
+        with jax.named_scope("mesh.micro"):
+            merged, merged_cnt = merge_micro(
+                gids, jnp.minimum(res.n_matches, K),
+                mg, jnp.minimum(mres.n_matches, Km))
 
-        # scatter into MY output segment at the row's dp-local position
-        # (source j's slice starts at j*Bs); no return all_to_all —
-        # other shards' segments stay count-0 for rows they don't own
-        flat_src = src2.reshape(R)
-        pos = (jnp.arange(tp, dtype=jnp.int32)[:, None] * Bs
-               + src2).reshape(R)
-        safe = jnp.where(flat_src >= 0, pos, Bl)
-        ids_out = jnp.full((Bl, W), -1, jnp.int32).at[safe].set(
-            merged, mode="drop")
-        cnt_out = jnp.zeros((Bl,), jnp.int32).at[safe].set(
-            merged_cnt, mode="drop")
-        seg_ov = jnp.zeros((Bl,), jnp.int32).at[safe].set(
-            res.match_overflow + mres.match_overflow, mode="drop")
-        nm = jnp.zeros((Bl,), jnp.int32).at[safe].set(
-            res.n_matches + mres.n_matches, mode="drop")
-        ao = jnp.zeros((Bl,), jnp.int32).at[safe].set(
-            res.active_overflow + mres.active_overflow, mode="drop")
-        # source-side bucket overflow flags MY slice's rows: psum folds
-        # them into the fail-open set alongside owner-side truncation
-        src_ov = jax.lax.dynamic_update_slice(
-            jnp.zeros((Bl,), jnp.int32), bucket_ov, (start,))
-        if compact:
-            # exactly ONE owner wrote each row (the partition makes
-            # segments disjoint; non-owners left -1/0), so a psum of
-            # the +1-biased ids collapses tp segments into one (B, W)
-            # plane — the contiguous-from-0 owner segment survives
-            # verbatim and routed d2h bytes drop ~tp×
-            ids_c = jax.lax.psum(
-                jnp.where(ids_out >= 0, ids_out + 1, 0), "tp") - 1
+        with jax.named_scope("mesh.compact"):
+            # scatter into MY output segment at the row's dp-local position
+            # (source j's slice starts at j*Bs); no return all_to_all —
+            # other shards' segments stay count-0 for rows they don't own
+            flat_src = src2.reshape(R)
+            pos = (jnp.arange(tp, dtype=jnp.int32)[:, None] * Bs
+                   + src2).reshape(R)
+            safe = jnp.where(flat_src >= 0, pos, Bl)
+            ids_out = jnp.full((Bl, W), -1, jnp.int32).at[safe].set(
+                merged, mode="drop")
+            cnt_out = jnp.zeros((Bl,), jnp.int32).at[safe].set(
+                merged_cnt, mode="drop")
+            seg_ov = jnp.zeros((Bl,), jnp.int32).at[safe].set(
+                res.match_overflow + mres.match_overflow, mode="drop")
+            nm = jnp.zeros((Bl,), jnp.int32).at[safe].set(
+                res.n_matches + mres.n_matches, mode="drop")
+            ao = jnp.zeros((Bl,), jnp.int32).at[safe].set(
+                res.active_overflow + mres.active_overflow, mode="drop")
+            # source-side bucket overflow flags MY slice's rows: psum folds
+            # them into the fail-open set alongside owner-side truncation
+            src_ov = jax.lax.dynamic_update_slice(
+                jnp.zeros((Bl,), jnp.int32), bucket_ov, (start,))
+            if compact:
+                # exactly ONE owner wrote each row (the partition makes
+                # segments disjoint; non-owners left -1/0), so a psum of
+                # the +1-biased ids collapses tp segments into one (B, W)
+                # plane — the contiguous-from-0 owner segment survives
+                # verbatim and routed d2h bytes drop ~tp×
+                ids_c = jax.lax.psum(
+                    jnp.where(ids_out >= 0, ids_out + 1, 0), "tp") - 1
+                return CompactFanoutResult(
+                    ids=ids_c,
+                    counts=jax.lax.psum(cnt_out, "tp")[:, None],
+                    overflow=jax.lax.psum(seg_ov, "tp")[:, None],
+                    n_matches=jax.lax.psum(nm, "tp"),
+                    active_overflow=jax.lax.psum(ao, "tp"),
+                    match_overflow=jax.lax.psum(seg_ov + src_ov, "tp"),
+                )
             return CompactFanoutResult(
-                ids=ids_c,
-                counts=jax.lax.psum(cnt_out, "tp")[:, None],
-                overflow=jax.lax.psum(seg_ov, "tp")[:, None],
+                ids=ids_out,
+                counts=cnt_out[:, None],
+                overflow=seg_ov[:, None],
                 n_matches=jax.lax.psum(nm, "tp"),
                 active_overflow=jax.lax.psum(ao, "tp"),
                 match_overflow=jax.lax.psum(seg_ov + src_ov, "tp"),
             )
-        return CompactFanoutResult(
-            ids=ids_out,
-            counts=cnt_out[:, None],
-            overflow=seg_ov[:, None],
-            n_matches=jax.lax.psum(nm, "tp"),
-            active_overflow=jax.lax.psum(ao, "tp"),
-            match_overflow=jax.lax.psum(seg_ov + src_ov, "tp"),
-        )
 
-    return jax.jit(step)
+    return jax.jit(mesh_match)
 
 
 class MultichipMatcher:
@@ -462,8 +484,19 @@ class MultichipMatcher:
         ep_shrink_threshold: float = 0.01,
         ep_max_cap_class: int = 3,
         balance_budget: int = 64,
+        warm_depths: Tuple[int, ...] = (),
+        spans: Tuple[Any, Any] = (None, None),
     ) -> None:
         from .mesh import make_mesh
+
+        # topic depths whose batch-64 step a whole repartition compiles
+        # before it is published (:meth:`_restack`): ``ready`` then
+        # never turns true over a cold serve shape
+        self.warm_depths = tuple(warm_depths)
+        # stage spans of a served readback (observe/span.py handles,
+        # None where histograms and the flight recorder are off):
+        # ``mesh_fetch`` then ``mesh_decode`` tile :meth:`readback`
+        self._sp_fetch, self._sp_decode = spans
 
         devs = list(devices if devices is not None else jax.devices())
         shape = serve_mesh_shape(len(devs), tp)
@@ -633,8 +666,9 @@ class MultichipMatcher:
     def rebuild(self, pairs: List[Tuple[str, int]]) -> None:
         """Full repartition (cold start, compaction swap — the service
         aid space was reassigned wholesale).  Cheap on the loop: the
-        build itself happens at the next ``apply_pending``; until then
-        ``ready`` is False and the single-chip path serves."""
+        build itself happens at the next ``apply_pending``; until that
+        has landed and warmed its serve shapes ``ready`` is False (the
+        service is then not ready either: the host trie serves)."""
         with self._lock:
             self._rebuild_pairs = list(pairs)
             self._pending = []
@@ -947,9 +981,17 @@ class MultichipMatcher:
                 self._put_replicated(ms),
                 self._put_replicated(self._padded_micro_amap(am)),
                 self._put_replicated(self._word_owner))
+        self._stacked_shape = shape     # the step's cache key reads it
+        if self._arrs is None:
+            # a whole repartition (or the first upload): pay the serve
+            # shapes' compiles on the staged arrays, and publish last
+            for d in self.warm_depths:
+                enc = self.encode([], batch=64, depth=d)
+                step = self._step_for((64, d), self._routed_for(64))
+                jax.block_until_ready(
+                    step(*(jnp.asarray(a) for a in enc), *arrs))
         with self._lock:
             self._arrs = arrs
-            self._stacked_shape = shape
         self.gen += 1
         self.applies += 1
         self.restacks += 1
@@ -1193,7 +1235,8 @@ class MultichipMatcher:
                     * (d + 3) * 4)
         return res
 
-    def readback(self, res, n: int):
+    def readback(self, res, n: int, seq: Optional[int] = None,
+                 gen: int = 0):
         """Block on the dense compact readback and decode to per-topic
         SERVICE accept-id rows: per-shard segments concatenate (the
         partition makes them disjoint — no dedup), rows flagged by the
@@ -1201,13 +1244,17 @@ class MultichipMatcher:
         serving masks the dead shards' replicated answer segments and
         appends the dead-owned routed rows to the spill set (the
         scoped CPU-fill contract).  Returns ``(rows, spilled row
-        indices, d2h bytes)``."""
+        indices, d2h bytes)``.  A served batch hands in its ``seq``
+        (and table ``gen``) and gets one ``mesh_fetch`` and one
+        ``mesh_decode`` sample; probes and canaries do not."""
+        t0 = _now_ns()
         routed = id(res) in self._routed_live
         self._routed_live.discard(id(res))
         meta = self._degraded_meta.pop(id(res), None)
         ids, counts, nm, ao, mo = jax.device_get(
             (res.ids, res.counts, res.n_matches,
              res.active_overflow, res.match_overflow))
+        t1 = _now_ns()
         if meta is not None and not routed \
                 and counts.shape[1] == self.tp:
             # replicated scoped failover: zero the dead shards'
@@ -1256,6 +1303,10 @@ class MultichipMatcher:
                 spilled = sorted(set(spilled).union(extra))
         nbytes = 4 * int(ids.size + counts.size + nm.size
                          + ao.size + mo.size)
+        if seq is not None and self._sp_fetch is not None:
+            # both handles or neither (one stage_span rule for the two)
+            self._sp_fetch.rec(t0, t1, n, gen, seq)
+            self._sp_decode.rec(t1, _now_ns(), n, gen, seq)
         return out, spilled, nbytes
 
     def _dead_row_indices(self, words, lens, depth: int,
@@ -1341,17 +1392,6 @@ class MultichipMatcher:
             sd((am,), i32),
             sd((wcap,), i32),
         ).compile()
-
-    def warm(self, batches=(64,), depths=None) -> None:
-        """Pre-pay the mesh step compiles for the serve shapes (the
-        service ``_warm`` twin); no-op until the first apply."""
-        if self._arrs is None:
-            return
-        for b in batches:
-            for d in (depths or (self.depth,)):
-                enc = self.encode([], batch=b, depth=d)
-                res = self.dispatch(enc)
-                self.readback(res, 0)
 
     # ------------------------------------------------------------------
     # load-adaptive plane: capacity auto-resize + popularity placement

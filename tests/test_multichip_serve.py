@@ -401,9 +401,11 @@ def test_compaction_swap_repartitions_and_serves():
             assert await settle(lambda: ms.ready and ms.mc.ready)
             gen0 = ms.mc.gen
             assert await settle(lambda: ms._table_gen >= 1, timeout=30)
-            # the repartition lands on the next sync pass
+            # the repartition lands on the next sync pass; the service
+            # is ready again once its step shapes are warm (until then
+            # the host trie serves, not the one-chip mirror)
             assert await settle(
-                lambda: ms.mc.ready and ms.mc.gen > gen0, timeout=30)
+                lambda: ms.ready and ms.mc.gen > gen0, timeout=30)
             await ms.prefetch("swap/3/x")
             routes = ms.hint_routes("swap/3/x")
             assert routes is not None
