@@ -130,6 +130,13 @@ def mesh_facts(ms, jax, table_bytes: int) -> dict:
     check(len(holders) == 4 and
           len(edge_stk.sharding.device_set) == 4,
           f"stacked shard arrays live on devices {holders}, not on four")
+    # the batch's operand too: put once into the step's own input
+    # sharding, never on one device for the call to reshard
+    operand = mc._put_operands(mc.encode(["smoke/operand"], batch=64))
+    op_holders = sorted(d.id for d in operand.sharding.device_set)
+    check(operand.committed and op_holders == holders,
+          f"a dispatch's operand lives on devices {op_holders}, the "
+          f"stacked shards on {holders}: the step would reshard it")
     shard_bytes = int(node_stk.nbytes + edge_stk.nbytes) // 4
     in_use = [(d.memory_stats() or {}).get("bytes_in_use")
               for d in jax.devices()]
@@ -146,6 +153,7 @@ def mesh_facts(ms, jax, table_bytes: int) -> dict:
               f"mirror: {shares}), a shard is {shard_bytes}: not all "
               "four chips hold a comparable share of the table")
     return {"mesh": ms.mesh_info(), "shard_devices": holders,
+            "operand_devices": op_holders,
             "shard_bytes": shard_bytes, "device_bytes_in_use": in_use,
             "one_chip_mirror_on": mirror_dev.id,
             "bytes_less_mirror": shares}

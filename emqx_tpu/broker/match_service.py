@@ -614,9 +614,12 @@ class MatchService:
             warm_depths=((self.short_depth, depth)
                          if self.short_depth and self.short_depth < depth
                          else (depth,)),
-            # what the mesh adds inside match_readback, written by the
-            # same (single in-flight) readback worker
-            spans=(stage_span("mesh_fetch", hists, ring_rb),
+            # what the mesh adds inside match_dispatch and inside
+            # match_readback, each pair written by that stage's own
+            # (single in-flight) worker
+            spans=(stage_span("mesh_put", hists, ring_disp),
+                   stage_span("mesh_launch", hists, ring_disp),
+                   stage_span("mesh_fetch", hists, ring_rb),
                    stage_span("mesh_decode", hists, ring_rb)),
         ) if multichip else None
         if multichip:
@@ -1908,8 +1911,11 @@ class MatchService:
             t1 = _now_ns()
             with _Annot("emqx.match.dispatch", seq=seq, n=n, t_ns=t1):
                 if multichip:
+                    # one packed operand put into the step's own
+                    # sharding, then the launch (mesh_put, mesh_launch)
                     res = dev.dispatch(
-                        enc, block_compile=(dev.kernel_cache is None))
+                        enc, block_compile=(dev.kernel_cache is None),
+                        n=n, seq=seq, gen=gen)
                 else:
                     res = dev.serve(
                         *enc,

@@ -66,7 +66,7 @@ STAGES = (
     "match_cycle", "match_window", "match_hop_out", "match_hop_back",
     "match_epilogue", "match_resume",
     "ingest_queue", "intercept", "handle_publish",
-    "mesh_fetch", "mesh_decode",
+    "mesh_fetch", "mesh_decode", "mesh_put", "mesh_launch",
 )
 
 

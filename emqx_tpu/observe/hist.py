@@ -71,6 +71,14 @@ one popped batch share its ``seq``):
                               thread)
 ``obs.stage.match_dispatch``  kernel dispatch per depth group (worker
                               thread)
+``obs.stage.mesh_put``        inside match_dispatch, mesh plane only
+                              (``match.multichip.enable``): the batch's one
+                              packed operand built and ``device_put`` into
+                              the step's own input sharding, per depth
+                              group
+``obs.stage.mesh_launch``     after it: the compiled mesh step called,
+                              until it returns the lazy handle; the two
+                              tile ``MultichipMatcher.dispatch``
 ``obs.stage.match_readback``  d2h readback per batch (worker thread /
                               readback child)
 ``obs.stage.mesh_fetch``      inside match_readback, mesh plane only
@@ -134,6 +142,8 @@ HIST_NAMES: List[str] = [
     "obs.stage.handle_publish",
     "obs.stage.mesh_fetch",
     "obs.stage.mesh_decode",
+    "obs.stage.mesh_put",
+    "obs.stage.mesh_launch",
 ]
 
 # -- bucket geometry --------------------------------------------------------

@@ -234,10 +234,15 @@ MULTICHIP_METRIC_NAMES: List[str] = [
 # times a CONFIGURED mesh did not come up (match.multichip.enable: the
 # matcher's constructor or a partition apply raised; inc) — the service
 # is not ready until a later sync pass succeeds, the host trie serves.
+# operand_puts counts the host arrays a step's batch operands were
+# placed from (inc; one a dispatch since the packed operand, so
+# operand_puts / tpu.match.shard_dispatches reads 1.0 over a served
+# window: warm calls and canaries place one each and dispatch nothing).
 MESH_METRIC_NAMES: List[str] = [
     "tpu.mesh.state", "tpu.mesh.degraded_batches",
     "tpu.mesh.cpu_filled_rows", "tpu.mesh.rebuild_s",
     "tpu.mesh.readmit_canary_fails", "tpu.mesh.apply_failed",
+    "tpu.mesh.operand_puts",
 ]
 
 # -- streaming table lifecycle (broker/match_service.py, opt-in via

@@ -129,7 +129,8 @@ log = logging.getLogger(__name__)
 _now_ns = time.perf_counter_ns      # the clock of every stage span
 
 __all__ = ["MultichipMatcher", "ShardDead", "build_multichip_step",
-           "serve_mesh_shape", "shard_of_filter", "is_micro_filter"]
+           "serve_mesh_shape", "shard_of_filter", "is_micro_filter",
+           "pack_operands", "unpack_operands"]
 
 
 class ShardDead(RuntimeError):
@@ -171,6 +172,31 @@ def is_micro_filter(flt: str) -> bool:
     return flt.split("/", 1)[0] in ("+", "#")
 
 
+def pack_operands(words, lens, is_sys) -> np.ndarray:
+    """The mesh step's ONE batch operand, on the host: ``(B, D + 2)``
+    int32, the ``D`` word ids of a row, then its ``lens``, then
+    ``is_sys`` as 0 / 1.  This function and :func:`unpack_operands` own
+    the column order; nothing else knows it.  One array because every
+    host array a dispatch places is a transfer to every chip (one
+    array 0.70 ms of host time on the four attached chips, three
+    1.85: PERF.md §6, PR 37)."""
+    words = np.asarray(words)
+    d = words.shape[1]
+    out = np.empty((words.shape[0], d + 2), np.int32)
+    out[:, :d] = words
+    out[:, d] = lens
+    out[:, d + 1] = is_sys
+    return out
+
+
+def unpack_operands(packed):
+    """``(words, lens, is_sys)`` of a :func:`pack_operands` array, by
+    static slices: the step's first lines (a traced array) and the
+    tests (a numpy one) both read the format here."""
+    d = packed.shape[1] - 2
+    return packed[:, :d], packed[:, d], packed[:, d + 1] != 0
+
+
 @partial(jax.jit, donate_argnums=(0,))
 def _scatter_stacked(tab, tvec, idx, rows):
     """stacked[t, idx] = rows, in place (donated) — the per-shard
@@ -184,12 +210,13 @@ def build_multichip_step(mesh, active_slots: int = 16,
                          max_matches: int = 32, micro_matches: int = 8,
                          routed: bool = False, capacity: int = 0,
                          compact: bool = False, micro_owner: int = 0):
-    """Return a jitted ``step(words, lens, is_sys, node_stk, edge_stk,
-    seeds_stk, aid_stk, micro_node, micro_edge, micro_seeds,
-    micro_amap, word_owner) -> CompactFanoutResult``.
+    """Return a jitted ``step(packed, node_stk, edge_stk, seeds_stk,
+    aid_stk, micro_node, micro_edge, micro_seeds, micro_amap,
+    word_owner) -> CompactFanoutResult``.
 
-    Input layouts: batch arrays sharded over ``dp`` (replicated —
-    *fanned* — over ``tp``); the stacked per-shard tables
+    Input layouts: the batch's one operand ``packed (B, D + 2)``
+    (:func:`pack_operands`: words, lens, is_sys) sharded over ``dp``
+    (replicated — *fanned* — over ``tp``); the stacked per-shard tables
     ``node_stk (tp, S, 4)``, ``edge_stk (tp, Hb, slots·4)``,
     ``seeds_stk (tp, 2)`` and the local→service accept-id map
     ``aid_stk (tp, A)`` sharded over ``tp``; the wildcard-root
@@ -291,9 +318,7 @@ def build_multichip_step(mesh, active_slots: int = 16,
         shard_map,
         mesh=mesh,
         in_specs=(
-            P("dp", None),        # words
-            P("dp"),              # lens
-            P("dp"),              # is_sys
+            P("dp", None),        # packed: words | lens | is_sys
             P("tp", None, None),  # node_stk
             P("tp", None, None),  # edge_stk
             P("tp", None),        # seeds_stk
@@ -314,12 +339,13 @@ def build_multichip_step(mesh, active_slots: int = 16,
         ),
         check_vma=False,
     )
-    def mesh_match(words, lens, is_sys, node_stk, edge_stk, seeds_stk,
-                   aid_stk, micro_node, micro_edge, micro_seeds, micro_amap,
+    def mesh_match(packed, node_stk, edge_stk, seeds_stk, aid_stk,
+                   micro_node, micro_edge, micro_seeds, micro_amap,
                    word_owner):
         # the function's name is the XLA module's (``jit_mesh_match``),
         # and the ``mesh.*`` scopes name its phases in a device trace
         # beside the ``nfa.*`` scopes of the level walk (PERF.md §3)
+        words, lens, is_sys = unpack_operands(packed)
         node, edge, seeds, amap = (
             node_stk[0], edge_stk[0], seeds_stk[0], aid_stk[0])
 
@@ -485,7 +511,7 @@ class MultichipMatcher:
         ep_max_cap_class: int = 3,
         balance_budget: int = 64,
         warm_depths: Tuple[int, ...] = (),
-        spans: Tuple[Any, Any] = (None, None),
+        spans: Tuple[Any, Any, Any, Any] = (None, None, None, None),
     ) -> None:
         from .mesh import make_mesh
 
@@ -493,14 +519,20 @@ class MultichipMatcher:
         # before it is published (:meth:`_restack`): ``ready`` then
         # never turns true over a cold serve shape
         self.warm_depths = tuple(warm_depths)
-        # stage spans of a served readback (observe/span.py handles,
-        # None where histograms and the flight recorder are off):
-        # ``mesh_fetch`` then ``mesh_decode`` tile :meth:`readback`
-        self._sp_fetch, self._sp_decode = spans
+        # stage spans of a served batch (observe/span.py handles, None
+        # where histograms and the flight recorder are off):
+        # ``mesh_put`` then ``mesh_launch`` tile :meth:`dispatch` (the
+        # dispatch worker's ring), ``mesh_fetch`` then ``mesh_decode``
+        # tile :meth:`readback` (the readback worker's)
+        (self._sp_put, self._sp_launch,
+         self._sp_fetch, self._sp_decode) = spans
 
         devs = list(devices if devices is not None else jax.devices())
         shape = serve_mesh_shape(len(devs), tp)
         self.mesh = make_mesh(shape, devs)
+        # where the step reads its batch operand (``in_specs[0]``):
+        # built once per mesh, :meth:`_put_operands` places into it
+        self._operand_sharding = NamedSharding(self.mesh, P("dp", None))
         self.dp = shape["dp"]
         self.tp = shape["tp"]
         self.n_devices = self.dp * self.tp
@@ -900,6 +932,22 @@ class MultichipMatcher:
         ``word_owner``): one copy per device, placed once."""
         return jax.device_put(arr, NamedSharding(self.mesh, P()))
 
+    def _put_operands(self, enc):
+        """The one way a batch reaches a step (serve, warm, canary):
+        :func:`pack_operands` of the encoded ``(words, lens, is_sys)``,
+        put ONCE from the host straight into the step's own input
+        sharding.  Every chip gets its copy in one batched call and the
+        committed array already matches what the step reads, so the
+        call reshards nothing (three uncommitted arrays on the default
+        device cost a dispatch 1.7 ms more on four chips: PERF.md §6,
+        PR 37).  ``tpu.mesh.operand_puts`` counts the host arrays
+        placed: one a dispatch."""
+        packed = jax.device_put(pack_operands(*enc),
+                                self._operand_sharding)
+        if self.metrics is not None:
+            self.metrics.inc("tpu.mesh.operand_puts")
+        return packed
+
     @staticmethod
     def _table_shape(sub) -> Tuple[int, int]:
         """(S, Hb) for either table implementation."""
@@ -986,10 +1034,10 @@ class MultichipMatcher:
             # a whole repartition (or the first upload): pay the serve
             # shapes' compiles on the staged arrays, and publish last
             for d in self.warm_depths:
-                enc = self.encode([], batch=64, depth=d)
                 step = self._step_for((64, d), self._routed_for(64))
-                jax.block_until_ready(
-                    step(*(jnp.asarray(a) for a in enc), *arrs))
+                jax.block_until_ready(step(
+                    self._put_operands(self.encode([], batch=64, depth=d)),
+                    *arrs))
         with self._lock:
             self._arrs = arrs
         self.gen += 1
@@ -1167,13 +1215,17 @@ class MultichipMatcher:
                 and batch % (self.dp * self.tp) == 0
                 and (batch // self.dp) >= self.tp)
 
-    def dispatch(self, enc, *, block_compile: bool = True):
+    def dispatch(self, enc, *, block_compile: bool = True, n: int = 0,
+                 seq: Optional[int] = None, gen: int = 0):
         """One mesh dispatch of an already-encoded batch; returns the
         lazy :class:`CompactFanoutResult` handle (readback blocks
         later, outside any lock).  Raises :class:`ShardDead` /
         :class:`~emqx_tpu.faultinject.InjectedFault` at the
         ``match.shard`` / ``ep.route`` seams, :class:`CompileMiss` on
-        a cold mesh shape when a kernel cache is attached."""
+        a cold mesh shape when a kernel cache is attached.  A served
+        batch hands in its ``seq`` (with its ``n`` topics and table
+        ``gen``) and gets one ``mesh_put`` and one ``mesh_launch``
+        sample; probes, warm calls and canaries do not."""
         self._gate()
         words, lens, is_sys = enc
         b, d = int(words.shape[0]), int(words.shape[1])
@@ -1197,11 +1249,17 @@ class MultichipMatcher:
                 owner = min(x for x in range(self.tp) if x not in dead)
         step = self._step_for((b, d), routed=routed, micro_owner=owner,
                               block_compile=block_compile)
+        t0 = _now_ns()
+        packed = self._put_operands(enc)
+        t1 = _now_ns()
         with self._lock:
             if self._arrs is None:
                 raise RuntimeError("multichip mirror not synced yet")
-            res = step(jnp.asarray(words), jnp.asarray(lens),
-                       jnp.asarray(is_sys), *self._arrs)
+            res = step(packed, *self._arrs)
+        if seq is not None and self._sp_put is not None:
+            # both handles or neither (one stage_span rule for the two)
+            self._sp_put.rec(t0, t1, n, gen, seq)
+            self._sp_launch.rec(t1, _now_ns(), n, gen, seq)
         if dead is not None:
             self._degraded_meta[id(res)] = (dead, dead_rows)
             self.degraded_batches += 1
@@ -1381,7 +1439,7 @@ class MultichipMatcher:
         sd = jax.ShapeDtypeStruct
         i32 = jnp.int32
         return step.lower(
-            sd((b, d), i32), sd((b,), i32), sd((b,), jnp.bool_),
+            sd((b, d + 2), i32),            # pack_operands' one array
             sd((self.tp, s, 4), i32),
             sd((self.tp, hb, BUCKET_SLOTS * 4), i32),
             sd((self.tp, 2), i32),
@@ -1528,9 +1586,8 @@ class MultichipMatcher:
             arrs = self._arrs
         if arrs is not None:
             try:
-                enc = self.encode([], batch=b, depth=d)
-                res = fn(jnp.asarray(enc[0]), jnp.asarray(enc[1]),
-                         jnp.asarray(enc[2]), *arrs)
+                res = fn(self._put_operands(
+                    self.encode([], batch=b, depth=d)), *arrs)
                 jax.block_until_ready(res.counts)
             except Exception:
                 # a concurrent apply donated the snapshot away: the
@@ -1645,11 +1702,11 @@ class MultichipMatcher:
                 owner = min(x for x in range(self.tp) if x not in dead)
         step = self._step_for((b, d), routed=routed, micro_owner=owner,
                               block_compile=True)
+        packed = self._put_operands(enc)
         with self._lock:
             if self._arrs is None:
                 raise RuntimeError("multichip mirror not synced yet")
-            res = step(jnp.asarray(words), jnp.asarray(lens),
-                       jnp.asarray(is_sys), *self._arrs)
+            res = step(packed, *self._arrs)
         ids, counts, ao, mo = jax.device_get(
             (res.ids, res.counts, res.active_overflow,
              res.match_overflow))

@@ -1416,3 +1416,158 @@ def test_ep_rebalance_fault_injection_noop():
         assert mc._placement_next
     finally:
         faultinject.uninstall()
+
+
+# ---------------------------------------------------------------------------
+# the step's one packed operand, placed where the step reads it (ISSUE 37)
+# ---------------------------------------------------------------------------
+
+MESHES = {"dp1xtp4": 4, "dp2xtp2": 2}        # tp, over four devices
+MODES = {"replicated": {}, "routed": {"ep": True},
+         "compact": {"ep": True, "ep_compact": True}}
+# sha1 of json.dumps([sorted rows, spilled]) of ``seeded_case`` as the
+# tree BEFORE the packed operand answered it (commit 27c9d81: three
+# ``jnp.asarray`` operands, a twelve-argument step), per mode and mesh;
+# where tp is 2 no bucket overflows and the three modes agree
+_ALL = "a7623736c8b0a5d4eaf713fc4f3bea833ce94159"
+_SPILL8 = "ec1985d2df9881d30b19feccbd7786b12101e83b"
+PARENT_DIGESTS = {
+    ("replicated", "dp1xtp4"): _ALL, ("replicated", "dp2xtp2"): _ALL,
+    ("routed", "dp1xtp4"): _SPILL8, ("routed", "dp2xtp2"): _ALL,
+    ("compact", "dp1xtp4"): _SPILL8, ("compact", "dp2xtp2"): _ALL,
+}
+
+
+def seeded_case(seed=3700000042, n_filters=400, n_topics=56):
+    rng = np.random.default_rng(seed)
+    roots = [f"r{i}" for i in range(12)]
+    words = [f"w{i}" for i in range(6)]
+
+    def path(lo, hi):
+        return [str(rng.choice(roots))] + [
+            str(rng.choice(words)) for _ in range(rng.integers(lo, hi))]
+
+    filters = set()
+    while len(filters) < n_filters:
+        ws = path(1, 5)
+        kind = rng.random()
+        if kind < 0.45:
+            ws[int(rng.integers(0, len(ws)))] = "+"
+        elif kind < 0.75:
+            ws = ws[:int(rng.integers(1, len(ws)))] + ["#"]
+        filters.add("/".join(ws))
+    topics = ["/".join(path(1, 5)) for _ in range(n_topics - 24)]
+    # a volley on one root: over a routed bucket's capacity at tp 4, so
+    # the spill set is not empty there
+    topics += ["/".join(["r3"] + path(1, 4)[1:]) for _ in range(24)]
+    return sorted(filters), topics
+
+
+def four_device_matcher(tp, filters=FILTERS, **mc_kw):
+    import jax
+
+    return build_pair(filters=filters, tp=tp, devices=jax.devices()[:4],
+                      **mc_kw)
+
+
+def decoded(mc, res, n):
+    rows, spilled, _ = mc.readback(res, n)
+    return [sorted(r) for r in rows], list(spilled)
+
+
+@pytest.mark.parametrize("mesh", MESHES)
+@pytest.mark.parametrize("routed", [True, False],
+                         ids=["routed", "replicated"])
+def test_the_operand_is_placed_in_the_steps_own_input_sharding(mesh, routed):
+    """What ``_put_operands`` returns is committed, held by every device
+    of the mesh, and equivalent to what the compiled step says it reads
+    for that argument: the call has nothing to reshard.  Both forms of
+    the step: the kernel cache's AOT executable and the jitted one."""
+    import jax
+
+    from emqx_tpu.ops.kernel_cache import MatchKernelCache
+
+    for kc in (MatchKernelCache(), None):
+        _inc, mc, _pairs = four_device_matcher(
+            MESHES[mesh], ep=routed, kernel_cache=kc)
+        assert (mc.dp, mc.tp) == (4 // MESHES[mesh], MESHES[mesh])
+        enc = mc.encode(topics_for(40), batch=64)
+        assert mc._routed_for(64) is routed
+        step = mc._step_for((64, 8), routed=routed)
+        packed = mc._put_operands(enc)
+        assert packed.committed
+        assert packed.shape == (64, 8 + 2) and packed.dtype == np.int32
+        assert packed.sharding.device_set == set(jax.devices()[:4])
+        compiled = step if kc is not None else \
+            step.lower(packed, *mc._arrs).compile()
+        reads = compiled.input_shardings[0]
+        assert len(reads) == 1 + len(mc._arrs)
+        assert packed.sharding.is_equivalent_to(reads[0], packed.ndim)
+        for arr, sh in zip(mc._arrs, reads[1:]):
+            # (None: an argument this step does not read, as the
+            # replicated one does not read ``word_owner``)
+            assert sh is None or arr.sharding.is_equivalent_to(
+                sh, arr.ndim)
+        # and a row block lives where ``dp`` puts it: whole on every
+        # device at dp 1, halves at dp 2
+        assert {s.data.shape for s in packed.addressable_shards} == {
+            (64 // mc.dp, 10)}
+
+
+@pytest.mark.parametrize("depth", [8, 16])
+def test_pack_and_unpack_round_trip_the_encoded_batch(depth):
+    _inc, mc, _pairs = build_pair(depth=depth)
+    topics = topics_for(37) + ["$SYS/brokers/x", "a/" * (depth + 3) + "z"]
+    words, lens, is_sys = mc.encode(topics, batch=64, depth=depth)
+    words, lens, is_sys = map(np.asarray, (words, lens, is_sys))
+    # the batch has what the format must carry: pad rows under the
+    # D + 2 sentinel, an over-deep topic's D + 1, a $-topic's flag
+    assert (lens == depth + 2).sum() == 64 - len(topics)
+    assert lens[len(topics) - 1] == depth + 1
+    assert is_sys[len(topics) - 2] and not is_sys[0]
+    packed = mcs_mod.pack_operands(words, lens, is_sys)
+    assert packed.shape == (64, depth + 2) and packed.dtype == np.int32
+    w2, l2, s2 = mcs_mod.unpack_operands(packed)
+    assert np.array_equal(w2, words) and w2.dtype == words.dtype
+    assert np.array_equal(l2, lens)
+    assert s2.dtype == np.bool_ and np.array_equal(s2, is_sys)
+
+
+@pytest.mark.parametrize("mesh", MESHES)
+@pytest.mark.parametrize("mode", MODES)
+def test_the_packed_step_answers_as_the_three_operand_call_did(mode, mesh):
+    """Rows and spilled indices of a seeded table and batch: equal to
+    the three-operand form of the call (kept here, not in the package:
+    three ``jnp.asarray`` operands on the default device, taken by a
+    step of the old signature) and to what the tree before the packed
+    operand answered (``PARENT_DIGESTS``)."""
+    import hashlib
+
+    import jax
+    import jax.numpy as jnp
+
+    filters, topics = seeded_case()
+    inc, mc, _pairs = four_device_matcher(
+        MESHES[mesh], filters=filters, **MODES[mode])
+    enc = mc.encode(topics, batch=64)
+    got = decoded(mc, mc.dispatch(enc), len(topics))
+    assert mc.ep_dispatches == (0 if mode == "replicated" else 1)
+    step = mc._step_for((64, 8), routed=mode != "replicated")
+
+    @jax.jit
+    def three_operand_step(words, lens, is_sys, *tables):
+        return step(jnp.concatenate(
+            [words, lens[:, None], is_sys[:, None].astype(jnp.int32)],
+            axis=1), *tables)
+
+    res = three_operand_step(*(jnp.asarray(a) for a in enc), *mc._arrs)
+    if mode != "replicated":
+        mc._routed_live.add(id(res))    # as dispatch marks a routed handle
+    assert decoded(mc, res, len(topics)) == got
+    blob = json.dumps([got[0], got[1]]).encode()
+    assert hashlib.sha1(blob).hexdigest() == PARENT_DIGESTS[mode, mesh]
+    rows, spilled = got
+    assert bool(spilled) == (PARENT_DIGESTS[mode, mesh] == _SPILL8)
+    for i, (t, row) in enumerate(zip(topics, rows)):
+        if i not in spilled:
+            assert row == sorted(inc.match_host(t)), t

@@ -450,22 +450,22 @@ def test_a_mesh_that_drops_out_is_not_covered_by_the_mirror():
 # (5) what the mesh adds to the books, and its name in a device trace
 # ---------------------------------------------------------------------------
 
+def hist_counts(dep) -> dict:
+    hs = dep.node.hists
+    return {n.split(".")[-1]: hs.hist(n).count for n in hs.names()}
+
+
 def test_one_batch_is_one_mesh_fetch_and_one_mesh_decode_inside_readback():
     async def main():
         async with Deployment(publishers=1) as dep:
             await dep.connect()
             await dep.publish_all(dep.fresh(1))       # compile the bucket
             await dep.received(1)
-            hs = dep.node.hists
-
-            def counts():
-                return {n.split(".")[-1]: hs.hist(n).count
-                        for n in hs.names()}
-
-            n0, seq0 = counts(), dep.ms._seq
+            n0, seq0 = hist_counts(dep), dep.ms._seq
             await dep.publish_all(dep.fresh(1))
             await dep.received(1)
-            d = {k: v - n0[k] for k, v in counts().items() if v != n0[k]}
+            d = {k: v - n0[k] for k, v in hist_counts(dep).items()
+                 if v != n0[k]}
             assert d["mesh_fetch"] == d["mesh_decode"] == 1 == \
                 d["match_readback"], d
             assert dep.ms._seq == seq0 + 1
@@ -485,6 +485,124 @@ def test_one_batch_is_one_mesh_fetch_and_one_mesh_decode_inside_readback():
     asyncio.run(main())
 
 
+def test_one_batch_is_one_mesh_put_and_one_mesh_launch_inside_dispatch():
+    async def main():
+        async with Deployment(publishers=1) as dep:
+            await dep.connect()
+            await dep.publish_all(dep.fresh(1))       # compile the bucket
+            await dep.received(1)
+            n0, seq0 = hist_counts(dep), dep.ms._seq
+            puts0 = dep.m.all()["tpu.mesh.operand_puts"]
+            c0 = dep.counters()
+            await dep.publish_all(dep.fresh(1))
+            await dep.received(1)
+            d = {k: v - n0[k] for k, v in hist_counts(dep).items()
+                 if v != n0[k]}
+            assert d["mesh_put"] == d["mesh_launch"] == 1 == \
+                d["match_dispatch"], d
+            assert dep.ms._seq == seq0 + 1
+            # one host array placed a dispatch (three before the packed
+            # operand): the ratio the result line's counters give
+            assert delta(dep.counters(), c0)[
+                "tpu.match.shard_dispatches"] == 1
+            assert dep.m.all()["tpu.mesh.operand_puts"] == puts0 + 1
+            by = {STAGES[e[0]]: e for r in
+                  dep.node.flightrec._rings.values()
+                  for e in r.snapshot() if e[-1] == dep.ms._seq}
+            disp, put, launch = (by[k] for k in (
+                "match_dispatch", "mesh_put", "mesh_launch"))
+            # (sid, start, dur, batch, gen, seq): the two tile the
+            # matcher's dispatch, inside the stage that calls it
+            assert disp[1] <= put[1]
+            assert put[1] + put[2] == launch[1]
+            assert launch[1] + launch[2] <= disp[1] + disp[2]
+            assert put[2] + launch[2] >= 0.5 * disp[2]
+            assert put[3:] == launch[3:] == disp[3:]
+
+    asyncio.run(main())
+
+
+def test_probes_warm_calls_and_canaries_place_an_operand_and_record_no_span():
+    async def main():
+        async with Deployment() as dep:
+            ms, mc = dep.ms, dep.ms.mc
+            n0 = hist_counts(dep)
+
+            def puts():
+                return dep.m.all()["tpu.mesh.operand_puts"]
+
+            # the repartition's warm calls went through the helper
+            assert puts() >= len(mc.warm_depths)
+            p0 = puts()
+            ms._probe_dispatch()
+            assert puts() == p0 + 1
+            mc._warm_capacity((64, ms.depth), 1)
+            assert puts() == p0 + 2
+            topics = mc.canary_topics(0, cap=8)
+            rows, _spilled = mc.canary_rows(topics, 64, readmit=0)
+            assert puts() == p0 + 3 and len(rows) == len(topics)
+            n1 = hist_counts(dep)
+            for stage in ("mesh_put", "mesh_launch", "mesh_fetch",
+                          "mesh_decode"):
+                assert n1[stage] == n0[stage] == 0, stage
+
+    asyncio.run(main())
+
+
+# what a rehearsal of the cell reads of the two dispatch spans: on this
+# tree; on a tree from before them (the parent of the PR that brought
+# them: ``read_layers`` leaves the two out by name and nothing else);
+# on one from before every mesh span (after the readback's two)
+MESH_SPAN_METRICS = ["mesh_fetch_p50_ms", "mesh_decode_p50_ms",
+                     "mesh_put_p50_ms", "mesh_launch_p50_ms"]
+_WITHOUT = """
+import sys
+sys.path.insert(0, {root!r})
+from cellbench import run as RUN
+good = RUN.Deployment.hist_counts
+RUN.Deployment.hist_counts = lambda self: {{
+    k: v for k, v in good(self).items()
+    if not k.startswith({prefixes!r})}}
+sys.exit(RUN.main(sys.argv[1:]))
+"""
+
+
+@pytest.mark.parametrize("without, gone", [
+    ((), []),
+    (("obs.stage.mesh_put", "obs.stage.mesh_launch"), MESH_SPAN_METRICS[2:]),
+    (("obs.stage.mesh_",), MESH_SPAN_METRICS),
+], ids=["this_tree", "parent", "no_mesh_span"])
+def test_a_traced_rehearsal_reads_the_dispatch_spans_or_names_them(without,
+                                                                    gone):
+    import subprocess
+
+    flag = "--xla_force_host_platform_device_count"
+    env = dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS=f"{flag}=4")
+    head = ([os.path.join(REPO, "cellbench", "run.py")] if not without else
+            ["-c", _WITHOUT.format(root=REPO, prefixes=without)])
+    p = subprocess.run(
+        [sys.executable, *head, "--workload", "wild1m_tp4.fanin_fresh",
+         "--rehearse", "--seconds", "3", "--trace", "1", "--seed",
+         "3700000021"], cwd=REPO, env=env, capture_output=True, text=True,
+        timeout=300)
+    assert p.returncode == 0, p.stderr[-2000:]
+    line = json.loads(p.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["device"]["count"] == 4
+    assert line["window"]["layers_left_out"] == gone
+    got = {k: v["value"] for k, v in line["metrics"].items()}
+    for name in MESH_SPAN_METRICS:
+        assert (name in got) == (name not in gone), name
+    if not without:
+        assert got["mesh_put_p50_ms"] > 0 and got["mesh_launch_p50_ms"] > 0
+    assert got["mesh_served_pct"] > 99.0 and got["ep_routed_pct"] == 100.0
+    # the window's edges may cut one batch between two of its counters
+    c = line["window"]["counters"]
+    assert abs(c["tpu.mesh.operand_puts"]
+               - c["tpu.match.shard_dispatches"]) <= 1
+    assert abs(c["tpu.match.shard_dispatches"]
+               - c["tpu.match.batches"]) <= 1
+
+
 def test_the_mesh_step_is_a_module_of_its_own_name_with_named_phases():
     async def main():
         async with Deployment() as dep:
@@ -492,7 +610,8 @@ def test_the_mesh_step_is_a_module_of_its_own_name_with_named_phases():
             enc = mc.encode(dep.fresh(4), batch=64)
             step = mc._step_for((64, int(enc[0].shape[1])), routed=True)
             assert "mesh_match" in step.__name__
-            text = step.lower(*enc, *mc._arrs).as_text(debug_info=True)
+            text = step.lower(mc._put_operands(enc), *mc._arrs).as_text(
+                debug_info=True)
             assert "module @jit_mesh_match" in text
             for scope in ("mesh.route", "mesh.walk", "mesh.micro",
                           "mesh.compact"):
