@@ -496,11 +496,10 @@ SCHEMA: Dict[str, Field] = {
     # micro-table (merged behind the owning shard's own matches)
     "match.multichip.ep.micro_matches": Field(
         8, int, lambda v: 1 <= v <= 256),
-    # count-compact the routed output on-mesh before d2h: the disjoint
-    # per-shard segments psum-collapse from (B, tp·W) to (B, W), so
-    # routed readback bytes drop ~tp× on literal-rooted tables.
-    # Identical decoded rows (parity-gated); off = the PR-16 routed
-    # segment layout, byte-identical.
+    # no effect: every routed step collapses its per-shard segments on
+    # the mesh and returns one packed answer in the one-chip served
+    # format (parallel/multichip_serve.py), whatever this says.  Still
+    # accepted so that configuration files naming it load.
     "match.multichip.ep.compact": Field(False, _bool),
     # routed overflow-rate EWMA threshold: a log-once warning (and the
     # tpu.match.ep_overflow_ewma gauge crossing it) flags a hot root

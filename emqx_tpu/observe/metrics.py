@@ -154,7 +154,8 @@ ROBUSTNESS_METRIC_NAMES: List[str] = [
 # match.pipeline.enable); readback_bytes accumulates the d2h bytes the
 # match readback path actually shipped (inc): the served program's one
 # packed array, 4·(B + SERVE_FLAT_MULT·B) per batch group in both serve
-# modes; the mesh its dense compact rows.  backend_join_dispatches
+# modes; the mesh's routed step the same format, a block a dp group
+# (its replicated step the dense compact rows).  backend_join_dispatches
 # counts kernel dispatches served by the relational-join backend (inc,
 # one per depth group; opt-in via match.backend) and autotune_picks the
 # per-shape hash-vs-join measurements the autotuner recorded (inc, one
@@ -162,9 +163,9 @@ ROBUSTNESS_METRIC_NAMES: List[str] = [
 # buffers the readback path fetched (inc, by amount per batch group),
 # each a d2h transfer of its own however many one device_get call
 # names (that call starts them together: not one latency each, PERF.md
-# §6 PR 31): 1 a batch group (the packed array; the mesh 1 too).  Over
-# tpu.match.batches it reads 1.0 where no batch split into depth
-# groups.
+# §6): 1 a batch group, whatever the plane (the mesh counts its
+# own buffers in tpu.mesh.answer_buffers).  Over tpu.match.batches it
+# reads 1.0 where no batch split into depth groups.
 MATCH_SERVE_METRIC_NAMES: List[str] = [
     "broker.match.deadline_dispatch", "broker.match.cpu_fallback",
     "broker.match.deadline_miss", "broker.match.breaker_state",
@@ -238,11 +239,16 @@ MULTICHIP_METRIC_NAMES: List[str] = [
 # placed from (inc; one a dispatch since the packed operand, so
 # operand_puts / tpu.match.shard_dispatches reads 1.0 over a served
 # window: warm calls and canaries place one each and dispatch nothing).
+# answer_buffers counts the device buffers a mesh readback fetched (inc,
+# by amount; a routed answer is one packed array, one buffer a dp group,
+# so answer_buffers / tpu.match.shard_dispatches reads 1.0 at dp 1 over
+# a served window, where the five-array answer before it read 11 at
+# tp 4; canaries fetch outside readback and count nothing).
 MESH_METRIC_NAMES: List[str] = [
     "tpu.mesh.state", "tpu.mesh.degraded_batches",
     "tpu.mesh.cpu_filled_rows", "tpu.mesh.rebuild_s",
     "tpu.mesh.readmit_canary_fails", "tpu.mesh.apply_failed",
-    "tpu.mesh.operand_puts",
+    "tpu.mesh.operand_puts", "tpu.mesh.answer_buffers",
 ]
 
 # -- streaming table lifecycle (broker/match_service.py, opt-in via

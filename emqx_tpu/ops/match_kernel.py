@@ -48,7 +48,7 @@ from .compiler import BUCKET_SLOTS, NfaTable, encode_topics
 __all__ = ["MatchResult", "SERVE_FLAT_MULT", "build_matcher",
            "decode_flat", "decode_packed", "decode_row_meta",
            "match_topics", "nfa_match", "nfa_match_packed", "nfa_walk",
-           "packed_twin"]
+           "packed_answer", "packed_twin"]
 
 # serving flat-output capacity per padded batch row (ids/topic): shared
 # by every serving engine so the fan-out tuning cannot drift between
@@ -84,6 +84,8 @@ def decode_packed(packed, n: int, k: int):
     back to back in row order (-1 behind the last).  :func:`packed_twin`
     builds it on the device; ``DeviceNfa.serve`` is the one way to ask
     for it; this function fetches it (ONE device buffer) and splits it.
+    The mesh step's routed answer is the same array a ``dp`` group
+    (:func:`packed_answer`), split here block by block.
     Spilled rows carry truncated segments: callers re-run those on the
     host trie (fail-open)."""
     packed = jax.device_get(packed)
@@ -165,6 +167,33 @@ def _compact(cand: jax.Array, width: int) -> jax.Array:
     return jnp.max(jnp.where(onehot, cand[..., None], -1), axis=1)
 
 
+def flat_scatter(rows, nk, flat_cap: int):
+    """The served format's id half, on the device: each row's first
+    ``nk`` entries of ``rows`` (B, w), valids first, laid back to back
+    in row order by a GLOBAL cumsum offset into one ``(flat_cap,)``
+    buffer, -1 behind the last.  Returns ``(flat, offs)``: a row with
+    ``offs + nk > flat_cap`` ran past the cap and was cut (fail-open)."""
+    offs = jnp.cumsum(nk) - nk                         # (B,)
+    col = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :]
+    valid = col < nk[:, None]
+    idx = jnp.where(valid, offs[:, None] + col, flat_cap)
+    out = jnp.full((flat_cap,), -1, jnp.int32)
+    flat = out.at[idx.reshape(-1)].set(
+        rows.reshape(-1), mode="drop")                 # OOB dropped
+    return flat, offs
+
+
+def packed_answer(rows, nk, spill, flat_cap: int):
+    """A WHOLE served answer built on the device from rows another
+    program compacted (the mesh step's collapsed owner segments): the
+    ``(B + flat_cap,)`` array :func:`decode_packed` reads.  ``spill``
+    (B,) bool is the caller's fail-open set; a row cut by the cap joins
+    it."""
+    flat, offs = flat_scatter(rows, nk, flat_cap)
+    spilled = (spill | (offs + nk > flat_cap)).astype(jnp.int32)
+    return jnp.concatenate([nk | (spilled << ROW_META_SPILL_SHIFT), flat])
+
+
 def flat_epilogue(flat, n, aover, max_matches: int, flat_cap: int):
     """The fused on-device compaction epilogue for flat serving mode:
     per-row top-K compaction, a GLOBAL cumsum-offset scatter into one
@@ -177,13 +206,7 @@ def flat_epilogue(flat, n, aover, max_matches: int, flat_cap: int):
     K = max_matches
     per_row = _compact(flat, K)                        # (B, K)
     nk = jnp.minimum(n, K)
-    offs = jnp.cumsum(nk) - nk                         # (B,)
-    col = jnp.arange(K, dtype=jnp.int32)[None, :]
-    valid = col < nk[:, None]
-    idx = jnp.where(valid, offs[:, None] + col, flat_cap)
-    out = jnp.full((flat_cap,), -1, jnp.int32)
-    matches = out.at[idx.reshape(-1)].set(
-        per_row.reshape(-1), mode="drop")              # OOB dropped
+    matches, offs = flat_scatter(per_row, nk, flat_cap)
     # truncated rows: count exceeded K, or the segment ran past the
     # global cap — both land in the fail-open set
     mover = ((n > K) | (offs + nk > flat_cap)).astype(jnp.int32)

@@ -27,16 +27,21 @@ root token hashes to it, so 8 chips hold 8× the filters:
   merged into the owning shard's answer segment (shard 0's in
   replicated mode), so EP answers stay complete and a hot wildcard
   set can't skew one shard;
-* per-shard matches map through a local→service accept-id table and
-  leave the mesh as the **dense compact contract**
-  (:class:`~emqx_tpu.parallel.sharded_match.CompactFanoutResult`):
-  per-row id segments in disjoint per-shard order, concat-no-dedup,
-  decoded by the same :func:`decode_compact_rows` the bitmap
-  compaction path uses — what crosses the wire is proportional to
-  MATCHES, never to table width (ROADMAP dispatch-tax residual (d));
+* per-shard matches map through a local→service accept-id table.  A
+  ROUTED answer leaves the mesh as ONE int32 array in the one-chip
+  served format (``row_meta`` then the flat ids, built by
+  :func:`~emqx_tpu.ops.match_kernel.packed_answer`, read by
+  :func:`~emqx_tpu.ops.match_kernel.decode_packed`), a block a ``dp``
+  group; the replicated step's leaves as the **dense compact
+  contract** (:class:`~emqx_tpu.parallel.sharded_match.
+  CompactFanoutResult`): per-row id segments in disjoint per-shard
+  order, concat-no-dedup, decoded by :func:`decode_compact_rows`.
+  Either way what crosses the wire is proportional to MATCHES, never
+  to table width (ROADMAP dispatch-tax residual (d));
 * per-row truncation/active-set/bucket-overflow spills are ``psum``'d
   over ``tp`` (the fail-open set — the host re-runs exactly those rows
-  on the CPU trie, the single-chip spill contract unchanged).
+  on the CPU trie, the single-chip spill contract unchanged); a routed
+  row the flat buffer cannot hold joins that set.
 
 Shard subtables are **native** (``native/nfa.cpp``) when the toolchain
 built the .so — per-shard capacity then matches the single-chip native
@@ -112,7 +117,7 @@ import os
 import threading
 import time
 import zlib
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -123,6 +128,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import faultinject as _fi
 from .. import topic as T
+from ..ops.match_kernel import (SERVE_FLAT_MULT, decode_packed, nfa_match,
+                                packed_answer)
 from .sharded_match import CompactFanoutResult, decode_compact_rows
 
 log = logging.getLogger(__name__)
@@ -197,6 +204,18 @@ def unpack_operands(packed):
     return packed[:, :d], packed[:, d], packed[:, d + 1] != 0
 
 
+@lru_cache(maxsize=64)
+def _distinct_blocks(sharding, shape) -> int:
+    return len({tuple((i.start, i.stop) for i in idx) for idx in
+                sharding.devices_indices_map(shape).values()})
+
+
+def _blocks(arr) -> int:
+    """Device buffers a ``jax.device_get`` of ``arr`` copies: one a
+    distinct block (a block replicated over ``tp`` is fetched once)."""
+    return _distinct_blocks(arr.sharding, arr.shape)
+
+
 @partial(jax.jit, donate_argnums=(0,))
 def _scatter_stacked(tab, tvec, idx, rows):
     """stacked[t, idx] = rows, in place (donated) — the per-shard
@@ -209,10 +228,11 @@ def _scatter_stacked(tab, tvec, idx, rows):
 def build_multichip_step(mesh, active_slots: int = 16,
                          max_matches: int = 32, micro_matches: int = 8,
                          routed: bool = False, capacity: int = 0,
-                         compact: bool = False, micro_owner: int = 0):
+                         micro_owner: int = 0):
     """Return a jitted ``step(packed, node_stk, edge_stk, seeds_stk,
     aid_stk, micro_node, micro_edge, micro_seeds, micro_amap,
-    word_owner) -> CompactFanoutResult``.
+    word_owner)``: a :class:`CompactFanoutResult` from the replicated
+    step, ONE packed int32 array from the routed one.
 
     Input layouts: the batch's one operand ``packed (B, D + 2)``
     (:func:`pack_operands`: words, lens, is_sys) sharded over ``dp``
@@ -221,10 +241,10 @@ def build_multichip_step(mesh, active_slots: int = 16,
     ``seeds_stk (tp, 2)`` and the local→service accept-id map
     ``aid_stk (tp, A)`` sharded over ``tp``; the wildcard-root
     micro-table arrays and the root-token ``word_owner`` routing map
-    fully replicated.  Output ``ids`` is the dense compact contract:
-    (B, tp·(K+Km)) service accept ids, -1 padded, per-shard segments
-    disjoint by partition construction; ``counts`` (B, tp); the spill
-    vectors psum over ``tp``.
+    fully replicated.  The replicated step's output ``ids`` is the
+    dense compact contract: (B, tp·(K+Km)) service accept ids, -1
+    padded, per-shard segments disjoint by partition construction;
+    ``counts`` (B, tp); the spill vectors psum over ``tp``.
 
     ``routed=True`` compiles the EP front end: each ``tp`` instance
     takes its 1/tp source slice of the dp-local batch, buckets rows
@@ -233,28 +253,28 @@ def build_multichip_step(mesh, active_slots: int = 16,
     root.  The owner merges its own + micro answers into ITS segment
     (other segments stay count-0 for that row), so no return
     ``all_to_all`` is needed.  Rows past ``capacity`` fail open
-    (match_overflow) at the source.
+    (match_overflow) at the source.  Exactly one owner writes each row,
+    so ONE ``psum`` over ``tp`` of the +1-biased ids (with the count
+    and the spill flags beside them) collapses the per-owner segments
+    into one (Bl, K+Km) row plane, and
+    :func:`~emqx_tpu.ops.match_kernel.packed_answer` lays it out in the
+    one-chip served format: the step's whole answer is one
+    ``(dp·(Bl + SERVE_FLAT_MULT·Bl),)`` int32 array, a block a ``dp``
+    group (``P("dp")``, replicated over ``tp``) that
+    :func:`~emqx_tpu.ops.match_kernel.decode_packed` splits.  Every
+    buffer a call returns is one more the runtime allocates on each
+    device and one more the readback fetches (PERF.md §6).
 
     ``micro_owner`` names the shard that merges the replicated
     micro-table's answers in replicated mode (default 0; the degraded
     mesh migrates it to the lowest LIVE shard when shard 0 dies, so
-    wildcard-root answers never go dark with their merge point).
-
-    ``compact=True`` (routed only) applies the count-compact contract
-    to the ROUTED output: exactly one owner writes each row, so a
-    psum over ``tp`` of the bias-encoded segments collapses the
-    (B, tp·W) id plane to (B, W) and counts to (B, 1) — routed d2h
-    drops ~tp× with identical decoded rows (the owner's segment is
-    already contiguous from 0)."""
-    from ..ops.match_kernel import nfa_match
-
+    wildcard-root answers never go dark with their merge point)."""
     K = max_matches
     Km = micro_matches
     W = K + Km
     tp = mesh.shape["tp"]
     C = capacity
-    compact = bool(compact) and bool(routed)
-    seg_spec = P("dp", None) if compact else P("dp", "tp")
+    seg_spec = P("dp", "tp")
 
     def merge_micro(gids, cnt_own, mg, mcnt):
         """Pack ``mcnt`` micro ids behind each row's ``cnt_own`` own
@@ -329,7 +349,7 @@ def build_multichip_step(mesh, active_slots: int = 16,
             P(None),              # micro_amap
             P(None),              # word_owner
         ),
-        out_specs=CompactFanoutResult(
+        out_specs=P("dp") if routed else CompactFanoutResult(
             ids=seg_spec,
             counts=seg_spec,
             overflow=seg_spec,
@@ -420,40 +440,26 @@ def build_multichip_step(mesh, active_slots: int = 16,
                 merged, mode="drop")
             cnt_out = jnp.zeros((Bl,), jnp.int32).at[safe].set(
                 merged_cnt, mode="drop")
-            seg_ov = jnp.zeros((Bl,), jnp.int32).at[safe].set(
-                res.match_overflow + mres.match_overflow, mode="drop")
-            nm = jnp.zeros((Bl,), jnp.int32).at[safe].set(
-                res.n_matches + mres.n_matches, mode="drop")
-            ao = jnp.zeros((Bl,), jnp.int32).at[safe].set(
-                res.active_overflow + mres.active_overflow, mode="drop")
-            # source-side bucket overflow flags MY slice's rows: psum folds
-            # them into the fail-open set alongside owner-side truncation
+            # a row's fail-open causes on its owner: active-set spill,
+            # truncation past K or Km
+            own_ov = jnp.zeros((Bl,), jnp.int32).at[safe].set(
+                res.active_overflow + mres.active_overflow
+                + res.match_overflow + mres.match_overflow, mode="drop")
+            # source-side bucket overflow flags MY slice's rows: the psum
+            # folds them into the fail-open set beside the owner's
             src_ov = jax.lax.dynamic_update_slice(
                 jnp.zeros((Bl,), jnp.int32), bucket_ov, (start,))
-            if compact:
-                # exactly ONE owner wrote each row (the partition makes
-                # segments disjoint; non-owners left -1/0), so a psum of
-                # the +1-biased ids collapses tp segments into one (B, W)
-                # plane — the contiguous-from-0 owner segment survives
-                # verbatim and routed d2h bytes drop ~tp×
-                ids_c = jax.lax.psum(
-                    jnp.where(ids_out >= 0, ids_out + 1, 0), "tp") - 1
-                return CompactFanoutResult(
-                    ids=ids_c,
-                    counts=jax.lax.psum(cnt_out, "tp")[:, None],
-                    overflow=jax.lax.psum(seg_ov, "tp")[:, None],
-                    n_matches=jax.lax.psum(nm, "tp"),
-                    active_overflow=jax.lax.psum(ao, "tp"),
-                    match_overflow=jax.lax.psum(seg_ov + src_ov, "tp"),
-                )
-            return CompactFanoutResult(
-                ids=ids_out,
-                counts=cnt_out[:, None],
-                overflow=seg_ov[:, None],
-                n_matches=jax.lax.psum(nm, "tp"),
-                active_overflow=jax.lax.psum(ao, "tp"),
-                match_overflow=jax.lax.psum(seg_ov + src_ov, "tp"),
-            )
+            # exactly ONE owner wrote each row (the partition makes
+            # segments disjoint; non-owners left -1 / 0), so one psum of
+            # the +1-biased ids, the count and the flags collapses tp
+            # segments into one (Bl, W) plane: the contiguous-from-0
+            # owner segment survives verbatim
+            both = jax.lax.psum(jnp.concatenate(
+                [jnp.where(ids_out >= 0, ids_out + 1, 0),
+                 cnt_out[:, None], (own_ov + src_ov)[:, None]], axis=1),
+                "tp")
+            return packed_answer(both[:, :W] - 1, both[:, W],
+                                 both[:, W + 1] > 0, SERVE_FLAT_MULT * Bl)
 
     return jax.jit(mesh_match)
 
@@ -544,9 +550,9 @@ class MultichipMatcher:
         self.ep = bool(ep)
         self.ep_slack = float(ep_slack)
         self.ep_micro_matches = int(ep_micro_matches)
-        # count-compact the routed output before d2h (ISSUE 17): the
-        # (B, tp·W) segment plane collapses to (B, W) on-mesh, so
-        # routed readback bytes drop ~tp× on literal-rooted tables
+        # selects nothing: the routed answer is always collapsed on the
+        # mesh and packed (build_multichip_step); kept so that a
+        # configuration naming match.multichip.ep.compact still loads
         self.ep_compact = bool(ep_compact)
         # degraded-mesh serving (ISSUE 18): scoped shard failover +
         # the health ladder; flag off every dead shard fails the
@@ -1295,36 +1301,29 @@ class MultichipMatcher:
 
     def readback(self, res, n: int, seq: Optional[int] = None,
                  gen: int = 0):
-        """Block on the dense compact readback and decode to per-topic
-        SERVICE accept-id rows: per-shard segments concatenate (the
-        partition makes them disjoint — no dedup), rows flagged by the
-        psum'd spill vectors go back to the host tables.  Degraded
-        serving masks the dead shards' replicated answer segments and
-        appends the dead-owned routed rows to the spill set (the
-        scoped CPU-fill contract).  Returns ``(rows, spilled row
-        indices, d2h bytes)``.  A served batch hands in its ``seq``
-        (and table ``gen``) and gets one ``mesh_fetch`` and one
-        ``mesh_decode`` sample; probes and canaries do not."""
+        """Block on the answer and decode it to per-topic SERVICE
+        accept-id rows: a routed handle is ONE packed array
+        (:meth:`_decode`), a replicated one the dense compact contract,
+        whose per-shard segments concatenate (the partition makes them
+        disjoint — no dedup); rows flagged by the spill bits go back to
+        the host tables.  Degraded serving masks the dead shards'
+        replicated answer segments and appends the dead-owned routed
+        rows to the spill set (the scoped CPU-fill contract).  Returns
+        ``(rows, spilled row indices, d2h bytes)``;
+        ``tpu.mesh.answer_buffers`` counts the device buffers fetched.
+        A served batch hands in its ``seq`` (and table ``gen``) and gets
+        one ``mesh_fetch`` and one ``mesh_decode`` sample; probes and
+        canaries do not."""
         t0 = _now_ns()
         routed = id(res) in self._routed_live
         self._routed_live.discard(id(res))
         meta = self._degraded_meta.pop(id(res), None)
-        ids, counts, nm, ao, mo = jax.device_get(
-            (res.ids, res.counts, res.n_matches,
-             res.active_overflow, res.match_overflow))
+        answer = self._answer_arrays(res, routed)
+        host = jax.device_get(answer)
         t1 = _now_ns()
-        if meta is not None and not routed \
-                and counts.shape[1] == self.tp:
-            # replicated scoped failover: zero the dead shards'
-            # per-row counts so their (stale) segments decode empty —
-            # the service CPU-fills exactly those shards' filters
-            counts = np.array(counts)
-            counts[:, sorted(meta[0])] = 0
-        cap_row = ids.shape[1] // counts.shape[1]
-        rows = decode_compact_rows(ids, counts, cap_row)[:n]
-        out = [[int(a) for a in row if a >= 0] for row in rows]
-        sp = (ao > 0) | (mo > 0)
-        spilled = np.flatnonzero(sp[:n]).tolist()
+        out, spilled = self._decode(
+            host, n, routed,
+            meta[0] if meta is not None and not routed else None)
         if routed and spilled and self.metrics is not None:
             # the routed fail-open set: bucket overflow + truncation
             # rows the CPU trie re-runs
@@ -1352,20 +1351,62 @@ class MultichipMatcher:
             if self.ep_autotune:
                 self._maybe_resize()
         if meta is not None and routed:
-            extra = [r for r in meta[1] if r < n and not sp[r]]
+            sp = set(spilled)
+            extra = [r for r in meta[1] if r < n and r not in sp]
             if extra:
                 self.cpu_filled_rows += len(extra)
                 if self.metrics is not None:
                     self.metrics.inc("tpu.mesh.cpu_filled_rows",
                                      len(extra))
-                spilled = sorted(set(spilled).union(extra))
-        nbytes = 4 * int(ids.size + counts.size + nm.size
-                         + ao.size + mo.size)
+                spilled = sorted(sp.union(extra))
+        nbytes = 4 * sum(int(a.size) for a in host)
+        if self.metrics is not None:
+            self.metrics.inc("tpu.mesh.answer_buffers",
+                             sum(_blocks(a) for a in answer))
         if seq is not None and self._sp_fetch is not None:
             # both handles or neither (one stage_span rule for the two)
             self._sp_fetch.rec(t0, t1, n, gen, seq)
             self._sp_decode.rec(t1, _now_ns(), n, gen, seq)
         return out, spilled, nbytes
+
+    @staticmethod
+    def _answer_arrays(res, routed: bool) -> Tuple[Any, ...]:
+        """What a readback fetches of a step's answer: the routed one
+        packed array, or the replicated step's five."""
+        if routed:
+            return (res,)
+        return (res.ids, res.counts, res.n_matches, res.active_overflow,
+                res.match_overflow)
+
+    def _decode(self, host, n: int, routed: bool,
+                dead: Optional[frozenset] = None):
+        """``(rows, spilled row indices)`` of the first ``n`` rows of a
+        fetched answer.  Routed: one ``decode_packed`` a ``dp`` block,
+        block ``j`` holding rows ``j·Bl …``.  Replicated: the dense
+        compact segments, the ``dead`` shards' counts zeroed so that
+        their stale segments decode empty."""
+        if routed:
+            (packed,) = host
+            blk = packed.size // self.dp
+            bl = blk // (1 + SERVE_FLAT_MULT)
+            w = self.max_matches + self.ep_micro_matches
+            rows: List[List[int]] = []
+            spilled: List[int] = []
+            for j in range(min(self.dp, -(-n // bl))):
+                r, sp = decode_packed(packed[j * blk:(j + 1) * blk],
+                                      min(bl, n - j * bl), w)
+                rows += r
+                spilled += [j * bl + i for i in sp]
+            return rows, spilled
+        ids, counts, _nm, ao, mo = host
+        if dead:
+            counts = np.array(counts)
+            counts[:, sorted(dead)] = 0
+        cap_row = ids.shape[1] // counts.shape[1]
+        rows = decode_compact_rows(ids, counts, cap_row)[:n]
+        out = [[int(a) for a in row if a >= 0] for row in rows]
+        sp = (ao > 0) | (mo > 0)
+        return out, np.flatnonzero(sp[:n]).tolist()
 
     def _dead_row_indices(self, words, lens, depth: int,
                           dead: frozenset) -> List[int]:
@@ -1382,10 +1423,10 @@ class MultichipMatcher:
     def _step_for(self, batch_shape: Tuple[int, int], routed: bool, *,
                   micro_owner: int = 0, block_compile: bool = True):
         cap = self.ep_capacity(batch_shape[0]) if routed else 0
-        # mesh-key ``kind``: 0 = replicated, 1 = routed, 2 = routed
-        # with the count-compact output contract
-        compact = routed and self.ep_compact
-        kind = (2 if compact else 1) if routed else 0
+        # mesh-key ``kind``: 0 = replicated, 1 = routed (2 named the
+        # count-compact routed output, which every routed step has had
+        # since its answer is packed)
+        kind = 1 if routed else 0
         kc = self.kernel_cache
         if kc is not None and self._stacked_shape is not None:
             smax, hbmax, acap, sm, hbm, am, wcap = self._stacked_shape
@@ -1417,7 +1458,7 @@ class MultichipMatcher:
             fn = self._steps[key] = build_multichip_step(
                 self.mesh, self.active_slots, self.max_matches,
                 micro_matches=self.ep_micro_matches,
-                routed=routed, capacity=cap, compact=compact,
+                routed=routed, capacity=cap,
                 micro_owner=int(micro_owner))
         return fn
 
@@ -1434,8 +1475,7 @@ class MultichipMatcher:
         owner = int(mk[10]) if len(mk) > 10 else 0
         step = build_multichip_step(
             self.mesh, key[4], key[5], micro_matches=km,
-            routed=kind >= 1, capacity=cap, compact=kind == 2,
-            micro_owner=owner)
+            routed=kind >= 1, capacity=cap, micro_owner=owner)
         sd = jax.ShapeDtypeStruct
         i32 = jnp.int32
         return step.lower(
@@ -1561,8 +1601,7 @@ class MultichipMatcher:
         cache is hot."""
         b, d = int(batch_shape[0]), int(batch_shape[1])
         cap = self._capacity_at(b, cap_class)
-        compact = self.ep_compact
-        kind = 2 if compact else 1
+        kind = 1
         kc = self.kernel_cache
         if kc is not None and self._stacked_shape is not None:
             smax, hbmax, acap, sm, hbm, am, wcap = self._stacked_shape
@@ -1581,14 +1620,14 @@ class MultichipMatcher:
         fn = build_multichip_step(
             self.mesh, self.active_slots, self.max_matches,
             micro_matches=self.ep_micro_matches,
-            routed=True, capacity=cap, compact=compact)
+            routed=True, capacity=cap)
         with self._lock:
             arrs = self._arrs
         if arrs is not None:
             try:
                 res = fn(self._put_operands(
                     self.encode([], batch=b, depth=d)), *arrs)
-                jax.block_until_ready(res.counts)
+                jax.block_until_ready(res)
             except Exception:
                 # a concurrent apply donated the snapshot away: the
                 # compile simply happens at the first dispatch instead
@@ -1707,20 +1746,12 @@ class MultichipMatcher:
             if self._arrs is None:
                 raise RuntimeError("multichip mirror not synced yet")
             res = step(packed, *self._arrs)
-        ids, counts, ao, mo = jax.device_get(
-            (res.ids, res.counts, res.active_overflow,
-             res.match_overflow))
-        if dead and not routed and counts.shape[1] == self.tp:
-            counts = np.array(counts)
-            counts[:, sorted(dead)] = 0
-        cap_row = ids.shape[1] // counts.shape[1]
         n = len(topics)
-        rows = decode_compact_rows(ids, counts, cap_row)[:n]
-        out = [[int(a) for a in row if a >= 0] for row in rows]
-        sp = (ao > 0) | (mo > 0)
-        spilled = set(np.flatnonzero(sp[:n]).tolist())
-        spilled.update(r for r in dead_rows if r < n)
-        return out, sorted(spilled)
+        out, spilled = self._decode(
+            jax.device_get(self._answer_arrays(res, routed)), n, routed,
+            None if routed else dead)
+        return out, sorted(set(spilled).union(
+            r for r in dead_rows if r < n))
 
     def rebuild_shard(self, t: int, pairs: List[Tuple[str, int]],
                       segments_dir: Optional[str] = None,

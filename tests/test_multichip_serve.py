@@ -12,6 +12,7 @@ single-chip path is byte-identical — no matcher is even constructed).
 """
 
 import asyncio
+import itertools
 import json
 import os
 
@@ -665,11 +666,11 @@ def test_node_ep_routed_serves_and_shard_kill_holds_delivery():
 # ---------------------------------------------------------------------------
 
 def test_ep_compact_parity_and_bytes_reduction():
-    """The routed step's tp·K-wide segment answers collapse to one
-    K-wide segment per row under ``ep_compact``: rows stay bit-equal
-    to the routed AND replicated contracts (and the host walk) while
-    the routed d2h bytes drop ~tp× — exactly one owner wrote each
-    row, so the psum-merge loses nothing."""
+    """``ep_compact`` selects nothing any more: every routed step
+    collapses its per-owner segments on the mesh and answers with one
+    packed array, so rows are bit-equal with the key on and off (and to
+    the replicated contract and the host walk), and the routed d2h bytes
+    are the packed array's, far under the replicated step's five."""
     inc, mc_rep, pairs = build_pair()
     mc_ep = MultichipMatcher(depth=8, ep=True, ep_slack=4.0)
     mc_ep.rebuild(pairs)
@@ -689,9 +690,11 @@ def test_ep_compact_parity_and_bytes_reduction():
     for t, rr, re_, rc in zip(topics, rows_r, rows_e, rows_c):
         assert sorted(rc) == sorted(re_) == sorted(rr) \
             == sorted(inc.match_host(t)), t
-    # the compact contract ships (B, K) ids instead of (B, tp·K)
-    assert nb_c < nb_e, (nb_c, nb_e)
-    assert nb_c <= nb_e // 2, (nb_c, nb_e)
+    # one (B + SERVE_FLAT_MULT·B,) int32 array, the key on or off
+    from emqx_tpu.ops.match_kernel import SERVE_FLAT_MULT
+
+    assert nb_c == nb_e == 4 * (64 + SERVE_FLAT_MULT * 64)
+    assert nb_e <= nb_r // 4, (nb_e, nb_r)
 
 
 def test_ep_compact_overflow_fails_open():
@@ -1422,19 +1425,26 @@ def test_ep_rebalance_fault_injection_noop():
 # the step's one packed operand, placed where the step reads it (ISSUE 37)
 # ---------------------------------------------------------------------------
 
-MESHES = {"dp1xtp4": 4, "dp2xtp2": 2}        # tp, over four devices
+MESHES = {"dp1xtp4": (4, 4), "dp2xtp2": (2, 4),    # (tp, devices)
+          "dp2xtp4": (4, 8)}
 MODES = {"replicated": {}, "routed": {"ep": True},
          "compact": {"ep": True, "ep_compact": True}}
 # sha1 of json.dumps([sorted rows, spilled]) of ``seeded_case`` as the
 # tree BEFORE the packed operand answered it (commit 27c9d81: three
 # ``jnp.asarray`` operands, a twelve-argument step), per mode and mesh;
-# where tp is 2 no bucket overflows and the three modes agree
+# where tp is 2 no bucket overflows and the three modes agree.  The
+# routed answers of that tree were five arrays decoded segment by
+# segment, with ``ep_compact`` off (routed) and on (compact)
 _ALL = "a7623736c8b0a5d4eaf713fc4f3bea833ce94159"
 _SPILL8 = "ec1985d2df9881d30b19feccbd7786b12101e83b"
+_SPILL_DP2 = "b6f8e0aaaf679ddec17bc593c199a4ac8ff8e7cd"
 PARENT_DIGESTS = {
     ("replicated", "dp1xtp4"): _ALL, ("replicated", "dp2xtp2"): _ALL,
+    ("replicated", "dp2xtp4"): _ALL,
     ("routed", "dp1xtp4"): _SPILL8, ("routed", "dp2xtp2"): _ALL,
+    ("routed", "dp2xtp4"): _SPILL_DP2,
     ("compact", "dp1xtp4"): _SPILL8, ("compact", "dp2xtp2"): _ALL,
+    ("compact", "dp2xtp4"): _SPILL_DP2,
 }
 
 
@@ -1463,10 +1473,11 @@ def seeded_case(seed=3700000042, n_filters=400, n_topics=56):
     return sorted(filters), topics
 
 
-def four_device_matcher(tp, filters=FILTERS, **mc_kw):
+def mesh_matcher(mesh, filters=FILTERS, **mc_kw):
     import jax
 
-    return build_pair(filters=filters, tp=tp, devices=jax.devices()[:4],
+    tp, n = MESHES[mesh]
+    return build_pair(filters=filters, tp=tp, devices=jax.devices()[:n],
                       **mc_kw)
 
 
@@ -1487,17 +1498,17 @@ def test_the_operand_is_placed_in_the_steps_own_input_sharding(mesh, routed):
 
     from emqx_tpu.ops.kernel_cache import MatchKernelCache
 
+    tp, n = MESHES[mesh]
     for kc in (MatchKernelCache(), None):
-        _inc, mc, _pairs = four_device_matcher(
-            MESHES[mesh], ep=routed, kernel_cache=kc)
-        assert (mc.dp, mc.tp) == (4 // MESHES[mesh], MESHES[mesh])
+        _inc, mc, _pairs = mesh_matcher(mesh, ep=routed, kernel_cache=kc)
+        assert (mc.dp, mc.tp) == (n // tp, tp)
         enc = mc.encode(topics_for(40), batch=64)
         assert mc._routed_for(64) is routed
         step = mc._step_for((64, 8), routed=routed)
         packed = mc._put_operands(enc)
         assert packed.committed
         assert packed.shape == (64, 8 + 2) and packed.dtype == np.int32
-        assert packed.sharding.device_set == set(jax.devices()[:4])
+        assert packed.sharding.device_set == set(jax.devices()[:n])
         compiled = step if kc is not None else \
             step.lower(packed, *mc._arrs).compile()
         reads = compiled.input_shardings[0]
@@ -1547,8 +1558,7 @@ def test_the_packed_step_answers_as_the_three_operand_call_did(mode, mesh):
     import jax.numpy as jnp
 
     filters, topics = seeded_case()
-    inc, mc, _pairs = four_device_matcher(
-        MESHES[mesh], filters=filters, **MODES[mode])
+    inc, mc, _pairs = mesh_matcher(mesh, filters=filters, **MODES[mode])
     enc = mc.encode(topics, batch=64)
     got = decoded(mc, mc.dispatch(enc), len(topics))
     assert mc.ep_dispatches == (0 if mode == "replicated" else 1)
@@ -1567,7 +1577,137 @@ def test_the_packed_step_answers_as_the_three_operand_call_did(mode, mesh):
     blob = json.dumps([got[0], got[1]]).encode()
     assert hashlib.sha1(blob).hexdigest() == PARENT_DIGESTS[mode, mesh]
     rows, spilled = got
-    assert bool(spilled) == (PARENT_DIGESTS[mode, mesh] == _SPILL8)
+    assert bool(spilled) == (PARENT_DIGESTS[mode, mesh] != _ALL)
     for i, (t, row) in enumerate(zip(topics, rows)):
         if i not in spilled:
             assert row == sorted(inc.match_host(t)), t
+
+
+# ---------------------------------------------------------------------------
+# the routed step's answer: one packed array in the served format
+# ---------------------------------------------------------------------------
+
+def deep_fanin(n_topics=24):
+    """Topics of one root that each match 23 filters (every literal or
+    ``+`` mask of its four levels, and ``#`` behind each prefix), so 24
+    of them hold 552 ids: past the flat buffer of a 64-row block (512)
+    and of a 32-row one (256)."""
+    filters, topics = set(), []
+    for i in range(n_topics):
+        ws = ["deep", f"a{i}", f"b{i}", f"c{i}"]
+        topics.append("/".join(ws))
+        for k in range(1, 5):
+            for mask in itertools.product((False, True), repeat=k - 1):
+                lv = [ws[0]] + ["+" if m else w
+                                for m, w in zip(mask, ws[1:k])]
+                filters.add("/".join(lv + ["#"]))
+                if k == 4:
+                    filters.add("/".join(lv))
+    return sorted(filters), topics
+
+
+def routed_case(case):
+    """``(filters, topics, matcher kwargs, shard killed)``: a volley on
+    one root past its buckets at ``ep_slack`` 1.0, scoped failover around
+    a dead shard, and a batch past the flat buffer."""
+    if case == "flat_cap":
+        return (*deep_fanin(), {"ep_slack": 4.0}, None)
+    filters, topics = seeded_case()
+    if case == "slack1":
+        return filters, topics, {"ep_slack": 1.0}, None
+    return filters, topics, {"ep_slack": 4.0, "degraded": True}, 1
+
+
+# ``[sorted rows, spilled]`` digests of the routed cases as the tree
+# before the packed answer decoded them (five arrays, segment by
+# segment; commit 69c279d), ``ep_compact`` off and on alike; the
+# flat-cap case has none: that tree had no flat buffer to run past
+PARENT_ROUTED = {
+    ("slack1", "dp1xtp4"): "3f8039ba3efd39fc81cddd7b6af4ac52cef6ff06",
+    ("slack1", "dp2xtp4"): "351bc923a242a03c7cf7910f35ef61688730e6a7",
+    ("degraded", "dp1xtp4"): "7eb54faa7c00278b0ce7097c227533c4a461c3fa",
+    ("degraded", "dp2xtp4"): "7eb54faa7c00278b0ce7097c227533c4a461c3fa",
+}
+
+
+@pytest.mark.parametrize("mesh", ["dp1xtp4", "dp2xtp4"])
+@pytest.mark.parametrize("case", ["slack1", "degraded", "flat_cap"])
+def test_the_routed_answer_is_one_packed_array_that_decodes_as_before(
+        case, mesh):
+    """The routed step answers with ONE int32 array in ``decode_packed``'s
+    format, a block a dp group, fetched as one buffer a block
+    (``tpu.mesh.answer_buffers``).  Its rows and spill set are the
+    parent tree's (with ``ep_compact`` off and on), every row it does
+    not spill is the host trie's and the replicated step's, and a row
+    the flat buffer cannot hold is spilled, never cut short."""
+    import hashlib
+
+    from emqx_tpu.ops.match_kernel import (SERVE_FLAT_MULT, decode_flat,
+                                           decode_row_meta)
+
+    filters, topics, kw, kill = routed_case(case)
+    n = len(topics)
+    got = []
+    for compact in (False, True):
+        met = Metrics()
+        inc, mc, pairs = mesh_matcher(mesh, filters=filters, ep=True,
+                                      ep_compact=compact, metrics=met, **kw)
+        if kill is not None:
+            mc.kill_shard(kill)
+        res = mc.dispatch(mc.encode(topics, batch=64))
+        raw = np.asarray(res)
+        got.append(decoded(mc, res, n))
+        assert met.get("tpu.mesh.answer_buffers") == mc.dp
+        assert met.get("tpu.match.shard_dispatches") == 1
+    assert got[0] == got[1]
+    rows, spilled = got[0]
+    if case in ("slack1", "degraded"):
+        blob = json.dumps([rows, spilled]).encode()
+        assert hashlib.sha1(blob).hexdigest() == PARENT_ROUTED[case, mesh]
+    want = [sorted(inc.match_host(t)) for t in topics]
+    dead = {r for r, t in enumerate(topics)
+            if kill is not None and shard_of_filter(t, mc.tp) == kill}
+    assert dead <= set(spilled) and (kill is None) == (not dead)
+
+    # the array, block by block: row_meta, then the flat ids back to
+    # back in row order and -1 behind them; the spill bit is the
+    # device's fail-open set (the dead owner's rows join on the host)
+    bl = 64 // mc.dp
+    cap = SERVE_FLAT_MULT * bl
+    assert raw.dtype == np.int32 and raw.shape == (mc.dp * (bl + cap),)
+    on_device = []
+    for j, blk in enumerate(raw.reshape(mc.dp, bl + cap)):
+        nk, sp = decode_row_meta(blk[:bl])
+        flat = blk[bl:]
+        assert (flat[min(int(nk.sum()), cap):] == -1).all()
+        offs = np.cumsum(nk) - nk
+        for i, seg in enumerate(decode_flat(flat, nk, 1 << 16)):
+            r = j * bl + i
+            if r >= n:      # a pad row (flagged as any row past the cap)
+                assert nk[i] == 0 and bool(sp[i]) == (offs[i] > cap)
+                continue
+            if sp[i]:
+                on_device.append(r)
+            elif r not in dead:
+                assert sorted(seg.tolist()) == want[r], topics[r]
+            # a row past the flat buffer is spilled, and only such a row
+            # of this corpus is
+            assert (offs[i] + nk[i] > cap) <= bool(sp[i])
+            if case == "flat_cap":
+                assert (offs[i] + nk[i] > cap) == bool(sp[i])
+                assert nk[i] == len(want[r]) == 23
+    assert sorted(set(on_device) | dead) == spilled
+    assert spilled and len(spilled) < n
+    # the replicated step and the host trie agree on every row kept
+    rep_met = Metrics()
+    _inc, rep, _pairs = mesh_matcher(mesh, filters=filters, metrics=rep_met)
+    rows_r, sp_r = decoded(rep, rep.dispatch(rep.encode(topics, batch=64)),
+                           n)
+    assert rep.ep_dispatches == 0 and not sp_r
+    assert rows_r == want
+    for r in range(n):
+        if r not in spilled:
+            assert rows[r] == want[r], topics[r]
+    # five arrays: ids and counts a block of (dp, tp) each, three vectors
+    assert rep_met.get("tpu.mesh.answer_buffers") == \
+        2 * rep.dp * rep.tp + 3 * rep.dp

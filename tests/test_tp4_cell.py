@@ -30,6 +30,8 @@ from emqx_tpu.client import Client                  # noqa: E402
 from emqx_tpu.config import Config                  # noqa: E402
 from emqx_tpu.node import BrokerNode                # noqa: E402
 from emqx_tpu.observe.flightrec import STAGES       # noqa: E402
+from emqx_tpu.ops.match_kernel import (SERVE_FLAT_MULT,  # noqa: E402
+                                       decode_packed, decode_row_meta)
 from emqx_tpu.parallel import multichip_serve as MC  # noqa: E402
 
 with open(os.path.join(REPO, "cellbench", "configs",
@@ -236,26 +238,32 @@ def test_each_topics_answer_is_its_owners_segment_and_the_micro_table_once():
             topics = dep.fresh(24)       # three source slices of a 64 batch
             owners = [MC.shard_of_filter(t, mc.tp) for t in topics]
             res = mc.dispatch(mc.encode(topics, batch=64))
-            ids, counts = jax.device_get((res.ids, res.counts))
-            _rows, spilled, _bytes = mc.readback(res, len(topics))
+            # the routed answer: one packed array, a served-format block
+            # (row_meta, then SERVE_FLAT_MULT·Bl flat ids) a dp group
+            raw = np.asarray(jax.device_get(res))
+            rows, spilled, nbytes = mc.readback(res, len(topics))
+            bl = 64 // mc.dp
+            assert raw.shape == (mc.dp * bl * (1 + SERVE_FLAT_MULT),)
+            assert nbytes == raw.nbytes
+            # the topics fill the first dp block; the others hold pads
+            blocks = raw.reshape(mc.dp, -1)
+            counts, sp = decode_row_meta(blocks[:, :bl].reshape(-1))
+            assert not counts[len(topics):].any()
+            assert (rows, spilled) == decode_packed(blocks[0], len(topics),
+                                                    mc.max_matches
+                                                    + mc.ep_micro_matches)
             # the fail-open set is the bucket rule's, nothing else
             assert spilled == bucket_overflows(mc, owners)
+            assert np.flatnonzero(sp).tolist() == spilled
             assert len(spilled) < len(topics) // 2
-            assert counts.shape == (64, mc.tp)      # not ep.compact
-            width = ids.shape[1] // mc.tp
             micro_hits = 0
             for r, t in enumerate(topics):
                 if r in spilled:        # the host trie re-runs the row
-                    assert not counts[r].any()
+                    assert counts[r] == 0
                     continue
                 want, owner = wildcard_matches(dep, t), owners[r]
-                aids = []
-                for s in range(mc.tp):
-                    seg = ids[r, s * width:s * width + counts[r, s]]
-                    assert (seg >= 0).all()
-                    if s != owner:      # routed: one shard sees the row
-                        assert counts[r, s] == 0, (t, s, owner)
-                    aids.extend(int(a) for a in seg)
+                aids = rows[r]
+                assert len(aids) == counts[r] and min(aids, default=0) >= 0
                 assert len(aids) == len(set(aids))      # counted once
                 got = set(ms._split_row(aids)[0])
                 assert got == want, (t, got ^ want)
@@ -599,8 +607,39 @@ def test_a_traced_rehearsal_reads_the_dispatch_spans_or_names_them(without,
     c = line["window"]["counters"]
     assert abs(c["tpu.mesh.operand_puts"]
                - c["tpu.match.shard_dispatches"]) <= 1
+    # one answer buffer a dp group: the rehearsal's mesh is tp 4 on four
+    # devices, so one a dispatch (eleven before the packed answer)
+    assert abs(c["tpu.mesh.answer_buffers"]
+               - c["tpu.match.shard_dispatches"]) <= 1
     assert abs(c["tpu.match.shard_dispatches"]
                - c["tpu.match.batches"]) <= 1
+
+
+# sha1 of the one-chip served program's lowered text, as the tree before
+# the mesh's routed answer shared its scatter (commit 69c279d), per
+# (B, D, S, Hb, active_slots, max_matches)
+SERVED_HLO = {
+    (64, 8, 256, 64, 8, 16): "c8aefda263ac71ae935330e82f6772070cd68295",
+    (16, 16, 1024, 256, 16, 32): "6fe2e962ebd5dc742d04a7c2540ef3e5c2e35c97",
+}
+
+
+@pytest.mark.parametrize("shape", sorted(SERVED_HLO))
+def test_the_one_chip_served_program_lowers_as_before(shape):
+    import hashlib
+
+    import jax.numpy as jnp
+
+    from emqx_tpu.ops import match_kernel as MK
+
+    B, D, S, Hb, A, K = shape
+    sd, i32 = jax.ShapeDtypeStruct, jnp.int32
+    low = MK.nfa_match_packed.lower(
+        sd((B, D), i32), sd((B,), i32), sd((B,), jnp.bool_),
+        sd((S, 4), i32), sd((Hb, MK.BUCKET_SLOTS * 4), i32), sd((2,), i32),
+        active_slots=A, max_matches=K, flat_cap=MK.SERVE_FLAT_MULT * B)
+    assert hashlib.sha1(low.as_text().encode()).hexdigest() == \
+        SERVED_HLO[shape]
 
 
 def test_the_mesh_step_is_a_module_of_its_own_name_with_named_phases():
