@@ -23,17 +23,36 @@ the stage from how long the current overload episode has lasted: level 1
 on entry, +1 per ``escalate`` seconds hot (default: the cooloff window),
 capped at 3.  De-escalation rides the existing cooloff — once reports go
 quiet the episode ends and the level drops straight to 0.
+
+**The loop's clock** (:class:`LoopClock`): while the probe runs, the
+running loop's selector sits behind a stopwatch.  ``_run_once`` calls
+``select`` once an iteration, so the time inside it is the loop's idle
+time and the time from one ``select`` to the next is one busy run of
+ready callbacks: ``runtime.loop.busy_ns`` / ``runtime.loop.idle_ns``,
+the histogram ``obs.stage.loop_run`` and, for a run of
+``LONG_RUN_NS`` or more, one ``loop_run`` event on the flight
+recorder's ``loop`` plane.  This is the asyncio counterpart of the
+scheduler utilisation ``emqx_vm`` reports.
 """
 
 from __future__ import annotations
 
 import asyncio
+import logging
 import time
 from typing import Any, Callable, Optional
 
 from ..observe.alarm import Alarms
+from ..observe.flightrec import STAGES
 
-__all__ = ["Olp", "LoopLagProbe"]
+__all__ = ["Olp", "LoopLagProbe", "LoopClock"]
+
+log = logging.getLogger(__name__)
+
+#: a busy run at least this long also goes into the flight recorder's
+#: ring (a 10-ms timer on the loop comes back that late); shorter ones
+#: feed the histogram alone
+LONG_RUN_NS = 10_000_000
 
 
 class Olp:
@@ -115,14 +134,68 @@ class Olp:
         return False
 
 
+class LoopClock:
+    """A loop's selector behind a stopwatch on ``perf_counter_ns``.
+
+    ``select`` stamps its entry and its exit: the time inside it is
+    idle, the time from the last exit to this entry one busy run (the
+    loop thread's waits for the interpreter lock inside the run
+    included).  Every other method is the selector's own.  Written by
+    the loop thread alone; any sink may be ``None``."""
+
+    def __init__(self, selector: Any, metrics: Any = None,
+                 hist: Any = None, ring: Any = None) -> None:
+        self.selector = selector
+        self._select = selector.select
+        self._metrics = metrics
+        self._hist = hist
+        self._ring = ring
+        self._sid = STAGES.index("loop_run")
+        self._out = time.perf_counter_ns()
+        # the selector's own methods, bound once (the rest: __getattr__)
+        self.register = selector.register
+        self.unregister = selector.unregister
+        self.modify = selector.modify
+        self.get_key = selector.get_key
+        self.get_map = selector.get_map
+        self.close = selector.close
+
+    def __getattr__(self, name: str) -> Any:
+        return getattr(self.selector, name)
+
+    def select(self, timeout: Optional[float] = None) -> Any:
+        t_in = time.perf_counter_ns()
+        busy = t_in - self._out
+        m = self._metrics
+        if m is not None:
+            m.inc("runtime.loop.busy_ns", busy)
+        if self._hist is not None:
+            self._hist.record(busy)
+        if busy >= LONG_RUN_NS and self._ring is not None:
+            self._ring.push(self._sid, self._out, busy)
+        events = self._select(timeout)
+        self._out = t_out = time.perf_counter_ns()
+        if m is not None:
+            m.inc("runtime.loop.idle_ns", t_out - t_in)
+        return events
+
+
+_no_selector_logged = False
+
+
 class LoopLagProbe:
-    """Sleep-drift sampler feeding :meth:`Olp.report`.
+    """Sleep-drift sampler feeding :meth:`Olp.report`, and the loop's
+    clock.
 
     Each tick schedules ``asyncio.sleep(interval)`` and measures how
     late it woke; an EWMA (``alpha``) smooths scheduler jitter so one
     GC pause doesn't trip overload, while sustained saturation does.
     Runs as a supervised child (``olp.lag_probe``); the clock and sleep
-    are injectable so tests drive it deterministically.
+    are injectable so tests drive it deterministically.  While it runs,
+    the running loop's selector is wrapped in a :class:`LoopClock`
+    feeding ``metrics``, ``hist`` and ``ring`` (one clock a loop: a
+    second probe on a loop that has one leaves it be; a loop with no
+    ``_selector``, as uvloop's or the proactor, gets none, logged once).
     """
 
     def __init__(
@@ -133,9 +206,13 @@ class LoopLagProbe:
         alpha: float = 0.3,
         clock: Optional[Callable[[], float]] = None,
         sleep: Optional[Callable[[float], Any]] = None,
+        hist: Any = None,
+        ring: Any = None,
     ) -> None:
         self.olp = olp
         self.metrics = metrics
+        self.hist = hist
+        self.ring = ring
         self.interval = interval
         self.alpha = alpha
         self._clock = clock if clock is not None else time.monotonic
@@ -160,11 +237,33 @@ class LoopLagProbe:
         return self.lag
 
     async def run(self) -> None:
-        """The supervised sampler loop."""
-        while True:
-            t0 = self._clock()
-            await self._sleep(self.interval)
-            self.observe(self._clock() - t0 - self.interval)
+        """The supervised sampler loop; the loop's clock is in place
+        for as long as it runs."""
+        loop = asyncio.get_running_loop()
+        clock = self._wrap(loop)
+        try:
+            while True:
+                t0 = self._clock()
+                await self._sleep(self.interval)
+                self.observe(self._clock() - t0 - self.interval)
+        finally:
+            if clock is not None and loop._selector is clock:
+                loop._selector = clock.selector
+
+    def _wrap(self, loop: Any) -> Optional[LoopClock]:
+        global _no_selector_logged
+        sel = getattr(loop, "_selector", None)
+        if sel is None:
+            if not _no_selector_logged:
+                _no_selector_logged = True
+                log.info("%s has no selector: the loop's busy time is "
+                         "not recorded", type(loop).__name__)
+            return None
+        if isinstance(sel, LoopClock):
+            return None
+        clock = LoopClock(sel, self.metrics, self.hist, self.ring)
+        loop._selector = clock
+        return clock
 
     def info(self) -> dict:
         return {

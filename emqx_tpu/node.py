@@ -131,17 +131,6 @@ class BrokerNode:
             max_queue_depth=cfg.get("overload_protection.max_queue_depth"),
             cooloff=cfg.get("overload_protection.cooloff"),
         )
-        # sleep-drift sampler: CPU saturation trips overload protection
-        # even when no queue grows (started as a supervised child)
-        from .broker.olp import LoopLagProbe
-
-        self.lag_probe = None
-        probe_interval = cfg.get("overload_protection.lag_probe_interval")
-        if probe_interval and probe_interval > 0:
-            self.lag_probe = LoopLagProbe(
-                self.olp, metrics=self.observed.metrics,
-                interval=probe_interval,
-            )
         # connection gauges come from the CM (a node-level table), so
         # they wire here rather than in observe(broker)
         self.observed.stats.provide(
@@ -256,12 +245,28 @@ class BrokerNode:
                 self.hists.hist("obs.stage.ingest_parse"),
                 self.hists.hist("obs.stage.ingest_queue"),
                 self.hists.hist("obs.stage.intercept"),
-                self.hists.hist("obs.stage.handle_publish"))
+                self.hists.hist("obs.stage.handle_publish"),
+                self.hists.hist("obs.stage.ack_in"))
         self.flightrec = FlightRecorder(
             self.tracing.dir,
             depth=cfg.get("obs.flightrec.depth"),
             metrics=self.observed.metrics,
         )
+        # sleep-drift sampler: CPU saturation trips overload protection
+        # even when no queue grows (started as a supervised child); while
+        # it runs it also clocks the loop's busy and idle time
+        from .broker.olp import LoopLagProbe
+
+        self.lag_probe = None
+        probe_interval = cfg.get("overload_protection.lag_probe_interval")
+        if probe_interval and probe_interval > 0:
+            self.lag_probe = LoopLagProbe(
+                self.olp, metrics=self.observed.metrics,
+                interval=probe_interval,
+                hist=(self.hists.hist("obs.stage.loop_run")
+                      if self.hists is not None else None),
+                ring=self.flightrec.ring("loop"),
+            )
         self.supervisor.flightrec = self.flightrec
         if self.admission is not None:
             # built above, before the recorder existed: escalation
@@ -647,7 +652,7 @@ class BrokerNode:
         )
         if self._conn_hists is not None:
             (proto._h_parse, proto._h_queue, proto._h_intercept,
-             proto._h_handle) = self._conn_hists
+             proto._h_handle, proto._h_ack) = self._conn_hists
         channel.conn = proto
         self._register_on_connect(channel, proto)
         self._all_conns.add(proto)
@@ -862,6 +867,10 @@ class BrokerNode:
         self._maybe_shard()
         await self.listeners.start_all()
         self._running = True
+        heap.keep_account(
+            self.observed.metrics,
+            self.hists.hist("obs.stage.gc_pause")
+            if self.hists is not None else None)
         self._jobs.append(self.supervisor.start_child(
             "node.housekeeping", self._housekeeping))
         if self.lag_probe is not None:
@@ -1276,6 +1285,7 @@ class BrokerNode:
 
     async def stop(self) -> None:
         self._running = False
+        heap.drop_account(self.observed.metrics)
         self.plugins.stop_all()
         if self.statsd is not None:
             await self.statsd.stop()

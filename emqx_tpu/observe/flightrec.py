@@ -67,6 +67,7 @@ STAGES = (
     "match_epilogue", "match_resume",
     "ingest_queue", "intercept", "handle_publish",
     "mesh_fetch", "mesh_decode", "mesh_put", "mesh_launch",
+    "loop_run", "gc_pause", "ack_in",
 )
 
 

@@ -53,15 +53,27 @@ they recur for as long as the node runs.  ``runtime.gc.frozen_objects``
 and the two counters show it.  The collector and its thresholds belong
 to the process, so the counts here do too: every node of a process
 reports the same.
+
+**The collector's account.**  One ``gc.callbacks`` entry, appended the
+first time a node keeps the account (:func:`keep_account`) and kept for
+the life of the process, times every collection on ``perf_counter_ns``:
+running totals ``runtime.gc.pause_ns`` / ``runtime.gc.collections``
+(``set`` into the table of every node that keeps the account; readers
+take deltas) and one ``obs.stage.gc_pause`` sample each.  Collections
+never overlap, so the callback is the only writer of both at any moment,
+whichever thread collects; that is also why it feeds no flight-recorder
+ring (a ring has one writer thread).
 """
 
 from __future__ import annotations
 
 import gc
 import logging
-from typing import Any, Dict
+import time
+from typing import Any, Dict, Optional
 
-__all__ = ["GROWTH_STEP", "grown", "settled", "report"]
+__all__ = ["GROWTH_STEP", "grown", "settled", "report", "keep_account",
+           "drop_account"]
 
 log = logging.getLogger(__name__)
 
@@ -72,6 +84,46 @@ GROWTH_STEP = 8192
 
 _freezes = {"growth": 0, "settled": 0}
 _frozen = 0         # the permanent generation as the last settle counted it
+
+# the collector's account: the process's totals since the callback went
+# in, and the (metrics, histogram) of every node keeping it (a tuple,
+# rebound whole, so the callback reads it in one load on any thread)
+_pause_ns = 0
+_collections = 0
+_gc_start = 0
+_accounts: tuple = ()
+
+
+def _on_gc(phase: str, _info: Dict[str, int]) -> None:
+    global _gc_start, _pause_ns, _collections
+    if phase == "start":
+        _gc_start = time.perf_counter_ns()
+        return
+    dur = time.perf_counter_ns() - _gc_start
+    _pause_ns += dur
+    _collections += 1
+    for metrics, hist in _accounts:
+        metrics.set("runtime.gc.pause_ns", _pause_ns)
+        metrics.set("runtime.gc.collections", _collections)
+        if hist is not None:
+            hist.record(dur)
+
+
+def keep_account(metrics: Any, hist: Optional[Any] = None) -> None:
+    """From now on every collection of the process is written into
+    ``metrics`` (the two totals) and ``hist`` (one pause each, where a
+    histogram is given), until :func:`drop_account`."""
+    global _accounts
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    metrics.set("runtime.gc.pause_ns", _pause_ns)
+    metrics.set("runtime.gc.collections", _collections)
+    _accounts = _accounts + ((metrics, hist),)
+
+
+def drop_account(metrics: Any) -> None:
+    global _accounts
+    _accounts = tuple(a for a in _accounts if a[0] is not metrics)
 
 
 def grown(size: int, mark: int) -> int:

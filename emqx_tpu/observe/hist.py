@@ -44,6 +44,26 @@ Per publish, on the connection's loop (histogram only, no ring event):
 ``obs.stage.flush``           ``emit`` of one publish (sync path); on the
                               fanout path stage 5, coalesced per chunk,
                               with a ring event
+``obs.stage.ack_in``          a subscriber's PUBACK / PUBREC / PUBCOMP
+                              taken off the connection's worker queue →
+                              ``handle_in`` + flush done (intercept mode;
+                              one per acknowledged delivery, not per
+                              publish)
+============================  ==============================================
+
+Per loop iteration and per collection (the host runtime; counters
+``runtime.loop.busy_ns`` / ``runtime.loop.idle_ns`` and
+``runtime.gc.pause_ns`` / ``runtime.gc.collections`` beside them):
+
+============================  ==============================================
+``obs.stage.loop_run``        one busy run of the node's event loop: the
+                              end of one ``select()`` → the start of the
+                              next (``broker/olp.py`` ``LoopClock``); a
+                              run of 10 ms or more is also one ring event
+                              on the ``loop`` plane
+``obs.stage.gc_pause``        one collection of the process's cyclic
+                              collector (``observe/heap.py``; no ring:
+                              it may run on any thread)
 ============================  ==============================================
 
 Per batch (histogram + one flight-recorder ring event; the events of
@@ -144,6 +164,9 @@ HIST_NAMES: List[str] = [
     "obs.stage.mesh_decode",
     "obs.stage.mesh_put",
     "obs.stage.mesh_launch",
+    "obs.stage.loop_run",
+    "obs.stage.gc_pause",
+    "obs.stage.ack_in",
 ]
 
 # -- bucket geometry --------------------------------------------------------

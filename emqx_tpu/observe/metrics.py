@@ -299,9 +299,18 @@ ADMISSION_METRIC_NAMES: List[str] = [
 # last settle read it (the call walks what it counts): what no full
 # pass walks any more.  All three are the PROCESS's (the collector is),
 # sampled into the table by the node's housekeeping (set).
+# loop.busy_ns / loop.idle_ns: the node's event loop, as the lag probe's
+# clock on its selector reads it (broker/olp.py LoopClock; inc, by the
+# loop thread alone): idle is the time inside select(), busy the time
+# from one select() to the next, one run of ready callbacks.
+# gc.pause_ns / gc.collections: running totals of the process's
+# collections (observe/heap.py; set, from the collector's callback, on
+# whichever thread collected).  All four are read as deltas.
 RUNTIME_METRIC_NAMES: List[str] = [
     "runtime.gc.freezes.growth", "runtime.gc.freezes.settled",
     "runtime.gc.frozen_objects",
+    "runtime.loop.busy_ns", "runtime.loop.idle_ns",
+    "runtime.gc.pause_ns", "runtime.gc.collections",
 ]
 
 

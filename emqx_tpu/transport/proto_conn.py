@@ -44,6 +44,9 @@ log = logging.getLogger(__name__)
 
 __all__ = ["MqttProtocol"]
 
+# the acks a subscriber sends for a delivery (obs.stage.ack_in)
+_ACKS = frozenset((P.PUBACK, P.PUBREC, P.PUBCOMP))
+
 
 class MqttProtocol(asyncio.Protocol):
     TICK_S = 1.0
@@ -59,11 +62,14 @@ class MqttProtocol(asyncio.Protocol):
     # Parser.feed per transport read.  The other three are the
     # intercept-mode worker's, PUBLISH only: the packet's wait in the
     # ordered queue, the async advisory stage, and the handle_in +
-    # actions + flush that follow it.
+    # actions + flush that follow it.  _h_ack is the same worker's, for
+    # a subscriber's PUBACK / PUBREC / PUBCOMP: taken off the queue →
+    # handle_in + flush done.
     _h_parse = None
     _h_queue = None
     _h_intercept = None
     _h_handle = None
+    _h_ack = None
 
     def __init__(
         self,
@@ -377,6 +383,11 @@ class MqttProtocol(asyncio.Protocol):
         while not self._closed:
             pkt = await self._queue.get()
             is_pub = pkt.type == P.PUBLISH
+            h_ack = self._h_ack
+            if h_ack is not None and not is_pub and pkt.type in _ACKS:
+                t_ack = time.perf_counter_ns()
+            else:
+                h_ack = None
             if is_pub and self._h_queue is not None:
                 t_q = getattr(pkt, "_queued_ns", 0)
                 if t_q:
@@ -429,6 +440,8 @@ class MqttProtocol(asyncio.Protocol):
                     self._flush_writes()
                 if h is not None:
                     h.record(time.perf_counter_ns() - t_h)
+                elif h_ack is not None:
+                    h_ack.record(time.perf_counter_ns() - t_ack)
             except asyncio.CancelledError:
                 return  # connection closing: the worker exits with it
             except Exception:

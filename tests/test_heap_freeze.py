@@ -381,7 +381,7 @@ def test_a_small_heap_keeps_cpythons_third_threshold():
 
 @pytest.mark.parametrize("name", RUNTIME_METRIC_NAMES)
 def test_runtime_names_are_registered(name):
-    assert name.startswith("runtime.gc.")
+    assert name.startswith(("runtime.gc.", "runtime.loop."))
     assert Metrics().get(name) == 0
 
 
